@@ -298,25 +298,27 @@ def _powerlaw_params(num_nodes, num_edges, feature_dim, label_dim,
                      alpha, multilabel, num_partitions, seed,
                      placement="hash") -> str:
     """The cache-identity string build_powerlaw's done marker records —
-    one constructor so external gates (scripts/tpu_checks.sh's
-    heavytail step) and the builder can never disagree on it."""
+    one constructor so external gates (powerlaw_cache_ready's callers:
+    bench.py's default config list, the batch sweep) and the builder
+    can never disagree on it."""
     d = dict(kind="powerlaw", num_nodes=num_nodes, num_edges=num_edges,
              feature_dim=feature_dim, label_dim=label_dim, alpha=alpha,
              multilabel=multilabel, num_partitions=num_partitions,
              seed=seed, gen="unique-fill-v3-gumbel-hubs")
     if placement != "hash":
-        # keyed only when non-default so every pre-PR done marker (and
-        # the tpu_checks gate's reconstruction of it) stays valid
+        # keyed only when non-default so every done marker written
+        # before placement existed stays valid
         d["placement"] = placement
     return json.dumps(d, sort_keys=True)
 
 
 def heavytail_cache_dir() -> str:
     """Default build_powerlaw cache dir for the Reddit-scale graph —
-    ONE resolver shared by bench.py's reddit_heavytail config,
-    scripts/reddit_heavytail.py --full, and scripts/tpu_checks.sh's
-    gate (a third hard-coded copy of the path is how the gate ends up
-    checking a different directory than the bench builds in).
+    ONE resolver shared by bench.py's reddit_heavytail config and its
+    default-config gate, scripts/batch_sweep.py and
+    scripts/reddit_heavytail.py --full (a hard-coded copy of the path is
+    how a gate ends up checking a different directory than the bench
+    builds in).
     EULER_TPU_HEAVYTAIL_CACHE overrides; else <repo>/.data/reddit_ht."""
     return os.environ.get(
         "EULER_TPU_HEAVYTAIL_CACHE",
@@ -342,8 +344,8 @@ def powerlaw_cache_ready(
     EXACTLY these params (the done marker records them). A bare
     existence check is not enough: _cache_begin wipes and regenerates
     on any params mismatch, so a gate that only tests the marker file
-    would wave through a stale cache and pay the full rebuild anyway —
-    on a chip window, if the caller is scripts/tpu_checks.sh."""
+    would wave through a stale cache and pay the full rebuild anyway,
+    inside a benchmark's time budget."""
     marker = os.path.join(out_dir, "done")
     if not os.path.exists(marker):
         return False

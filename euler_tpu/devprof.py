@@ -21,12 +21,11 @@ scripts/metrics_dump.py report the device plane with zero new plumbing:
 Compile COUNTS ride ``device_compiles`` / ``device_recompiles`` /
 ``serve_recompiles`` (eg_stats.h), compile LATENCY rides the
 ``phase:compile`` histogram (eg_phase.h), memory gauges ride the
-blackbox resource section (eg_blackbox.h + eg_devprof.h). The primary
-compile detector is a ``jax.monitoring`` event listener (exact backend
-compile durations); where events are unavailable the wrapped-jit
-fallback in :class:`Watched` feeds the same counters from cache-size
-deltas. Attribution (WHICH function recompiled, WHAT drifted) always
-comes from :class:`Watched`'s per-function shape-signature registry.
+blackbox resource section (eg_blackbox.h + eg_devprof.h). The compile
+detector is a ``jax.monitoring`` event listener (exact backend compile
+durations; a persistent-cache hit fires it too, with the retrieval
+time). Attribution (WHICH function recompiled, WHAT drifted) comes from
+:class:`Watched`'s jit-cache-size delta plus the arg shape signature.
 """
 
 from __future__ import annotations
@@ -43,15 +42,13 @@ log = logging.getLogger("euler_tpu.devprof")
 
 # The jax.monitoring event key of one XLA backend compile (fires once
 # per compile, duration in seconds). Pinned by tests against the live
-# jax in the image; a jax without it simply leaves the listener idle
-# and the wrapped-jit fallback owns the counters.
+# jax in the image.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 _LEDGER_CAP = 256
 
 _enabled = True
 _installed = False
-_listener_ok = False
 _lock = threading.Lock()
 _ledger: list = []
 _sampler_stop = None
@@ -77,13 +74,6 @@ def set_devprof(on: bool) -> None:
     _enabled = bool(on)
 
 
-def monitoring_active() -> bool:
-    """True when the jax.monitoring compile listener is registered (it
-    then owns device_compiles + the compile histogram; the wrapped-jit
-    fallback only attributes)."""
-    return _listener_ok
-
-
 def _on_event_duration(event: str, duration: float, **kw) -> None:
     # Called from inside jax's compile path — must never raise.
     try:
@@ -95,61 +85,34 @@ def _on_event_duration(event: str, duration: float, **kw) -> None:
         pass
 
 
-def install(sample_ms: int = 0) -> bool:
+def install(sample_ms: int = 0) -> None:
     """Arm the device plane (idempotent): register the jax.monitoring
     compile listener; with ``sample_ms > 0`` also start the background
-    device-memory sampler. Returns True when the listener registered
-    (False = fallback mode: Watched owns the counters too)."""
-    global _installed, _listener_ok
+    device-memory sampler."""
+    global _installed
     with _lock:
         if not _installed:
-            try:
-                import jax.monitoring as _mon
+            import jax.monitoring
 
-                _mon.register_event_duration_secs_listener(
-                    _on_event_duration
-                )
-                _listener_ok = True
-            except Exception as e:  # noqa: BLE001 - fallback mode
-                log.info("devprof: jax.monitoring unavailable (%s); "
-                         "wrapped-jit fallback owns compile counters", e)
-                _listener_ok = False
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_event_duration
+            )
             _installed = True
     if sample_ms > 0:
         start_sampler(sample_ms)
-    return _listener_ok
 
 
-def setup(enabled: bool = True, compile_cache: bool | None = None,
-          model_dir: str | None = None, sample_ms: int = 0) -> bool:
+def setup(enabled: bool = True, sample_ms: int = 0) -> bool:
     """CLI-startup arming shared by `python -m euler_tpu.run_loop` and
-    `python -m euler_tpu.serve` (their --devprof / --compile_cache
-    flags land here). Disarms the plane when ``enabled`` is False;
-    otherwise installs the compile listener, optionally starts the
-    memory sampler, and points JAX's persistent compilation cache at
-    $JAX_COMPILATION_CACHE_DIR / <model_dir>/jax_cache —
-    ``compile_cache=None`` means auto: on for TPU/GPU backends (where a
-    program compile costs 20-40 s), off on CPU. Returns devprof_enabled().
-    """
+    `python -m euler_tpu.serve` (their --devprof flag lands here).
+    Disarms the plane when ``enabled`` is False; otherwise installs the
+    compile listener and optionally starts the memory sampler. Returns
+    devprof_enabled(). The persistent compile cache is NOT decided here
+    (parallel.enable_compile_cache — on with or without --devprof)."""
     if not enabled:
         set_devprof(False)
         return False
     install(sample_ms=sample_ms)
-    on = compile_cache
-    if on is None:
-        import jax
-
-        on = jax.default_backend() != "cpu"
-    if on:
-        import os
-
-        from euler_tpu.parallel import enable_compile_cache
-
-        d = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-            model_dir or ".", "jax_cache"
-        )
-        enable_compile_cache(default_dir=d)
-        log.info("devprof: persistent compile cache at %s", d)
     return True
 
 
@@ -225,10 +188,10 @@ def devprof_reset() -> None:
 
 
 class Watched:
-    """A jitted callable with a shape-signature registry: detects every
-    compile the call triggered (cache-size delta; signature-registry
-    fallback), and journals any compile AFTER warmup as a recompile
-    with the exact arg-shape/dtype diff that caused it.
+    """A jitted callable with recompile attribution: detects every
+    compile the call triggered (jit cache-size delta) and journals any
+    compile AFTER warmup as a recompile with the exact arg-shape/dtype
+    diff that caused it.
 
     ``on_recompile(entry)`` is the serve compile-storm hook;
     ``strict=True`` raises :class:`RecompileError` (the result is
@@ -242,20 +205,10 @@ class Watched:
         self.strict = strict
         self._counter = counter
         self._on_recompile = on_recompile
-        self._sigs: dict = {}
         self._last_sig = None
         self.warm = False
         self.compiles = 0
         self.recompiles = 0
-
-    def _cache_size(self):
-        cs = getattr(self._fn, "_cache_size", None)
-        if cs is None:
-            return None
-        try:
-            return cs()
-        except Exception:  # noqa: BLE001 - jit internals moved
-            return None
 
     def mark_warm(self) -> None:
         """Declare warmup done: the NEXT compile is a recompile even if
@@ -265,37 +218,19 @@ class Watched:
     def __call__(self, *args, **kwargs):
         if not _enabled:
             return self._fn(*args, **kwargs)
-        before = self._cache_size()
+        before = self._fn._cache_size()
         t0 = time.monotonic()
         out = self._fn(*args, **kwargs)
         wall_us = int((time.monotonic() - t0) * 1e6)
-        after = self._cache_size()
-        if after is not None and before is not None:
-            if after == before:
-                # steady state — in-bucket dispatch, nothing compiled,
-                # so the arg signature (the expensive half of
-                # attribution) is never built; _last_sig stays at the
-                # sig that triggered the last compile, which is exactly
-                # the "previous" side a future recompile diffs against
-                return out
-            compiled = True
-            sig = _signature(args, kwargs)
-        else:
-            # no _cache_size on this callable: signature-registry
-            # fallback has to price the signature on every call
-            sig = _signature(args, kwargs)
-            compiled = sig not in self._sigs
-        self._sigs.setdefault(sig, 0)
-        self._sigs[sig] += 1
-        if not compiled:
-            self._last_sig = sig
+        if self._fn._cache_size() == before:
+            # steady state — in-bucket dispatch, nothing compiled, so
+            # the arg signature (the expensive half of attribution) is
+            # never built; _last_sig stays at the sig that triggered the
+            # last compile, which is exactly the "previous" side a
+            # future recompile diffs against
             return out
+        sig = _signature(args, kwargs)
         self.compiles += 1
-        if not _listener_ok:
-            # fallback mode: the wrapper owns the count + latency too
-            # (call wall time — compile dominates a compiling call)
-            native.counter_add("device_compiles")
-            telemetry.record_phase("compile", wall_us)
         if self.warm:
             self.recompiles += 1
             entry = {

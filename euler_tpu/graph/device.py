@@ -35,14 +35,15 @@ sharded TCP-registry cluster (tests/test_remote.py).
 
 from __future__ import annotations
 
+import contextlib
+import functools
+import logging
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-try:  # imported lazily in most callers; keep module importable without jax
-    import jax
-    import jax.numpy as jnp
-except Exception:  # pragma: no cover
-    jax = None
-    jnp = None
+log = logging.getLogger("euler_tpu")
 
 
 def _fetch_flat_csr(graph, edge_types, max_id: int, chunk: int,
@@ -440,15 +441,63 @@ _KERNEL_MESH = None  # (Mesh, data_axis) set by set_kernel_mesh
 def set_kernel_mesh(mesh, axis: str = "data") -> None:
     """Route eligible packed-slab draws through the Pallas kernel PER
     SHARD of ``mesh`` (shard_map over ``axis``) — the SPMD composition
-    plain pjit cannot express. Call with None to clear. run_loop wires
-    this automatically when --device_sampling runs on a multi-device TPU
-    mesh (pallas_sampling.sharded_available())."""
+    plain pjit cannot express. Call with None to clear. Callers normally
+    go through kernel_mesh_scope."""
     global _KERNEL_MESH
     _KERNEL_MESH = None if mesh is None else (mesh, axis)
 
 
 def kernel_mesh():
     return _KERNEL_MESH
+
+
+@contextlib.contextmanager
+def kernel_mesh_scope(mesh, axis: str = "data"):
+    """Register ``mesh`` for per-shard kernel draws while the block runs
+    and restore the previous registration after. Only a multi-device
+    TPU mesh registers (a single device calls the kernel directly;
+    other backends have no kernel), anything else CLEARS — so a mesh
+    left by an outer or earlier caller can never route this block's
+    draws. run_loop.main and train.train/evaluate/save_embedding wrap
+    their work in it: init_state (the pack decision) and the first call
+    of each jitted step (the routing decision) must both happen
+    inside."""
+    from euler_tpu.graph import pallas_sampling
+
+    global _KERNEL_MESH
+    prev = _KERNEL_MESH
+    use = (
+        mesh is not None
+        and mesh.size > 1
+        and pallas_sampling.sharded_available()
+    )
+    set_kernel_mesh(mesh if use else None, axis)
+    try:
+        yield
+    finally:
+        _KERNEL_MESH = prev
+
+
+@functools.lru_cache(maxsize=256)
+def _log_route(draw: str, path: str, why: str) -> None:
+    """One line per distinct draw shape and outcome: which path a draw
+    took and why. Called while tracing, so a jitted step says once
+    whether it holds the chained kernel, the per-hop kernel, the XLA
+    chain or the alias draw — a shape that misses a kernel budget or a
+    slab that was never packed is visible, not silent."""
+    log.info("draw path: %s -> %s (%s)", draw, path, why)
+
+
+def no_kernel_why() -> str:
+    """Why pallas_sampling.available() said no, for the route log."""
+    from euler_tpu.graph import pallas_sampling
+
+    if pallas_sampling._force_flag() is False:
+        return "EULER_TPU_PALLAS_SAMPLING=0"
+    return (
+        f"backend {jax.default_backend()}, {jax.device_count()} "
+        "device(s), no kernel mesh registered"
+    )
 
 
 def sample_neighbor(adj: dict, nodes, key, count: int):
@@ -471,10 +520,13 @@ def sample_neighbor(adj: dict, nodes, key, count: int):
     """
     from euler_tpu.graph import pallas_sampling
 
+    m = int(np.prod(jnp.shape(nodes)))
+    draw = f"neighbor draw {m}x{count}"
     if "off" in adj:
+        _log_route(draw, "alias draw", "flat-CSR alias adjacency")
         return _alias_sample_neighbor(adj, nodes, key, count)
 
-    m = int(np.prod(jnp.shape(nodes)))
+    why = "adjacency has no packed slab"
     if "packed" in adj:
         # kernel seed, shared by both routes: two independent int31
         # words -> 62 bits of the key's entropy reach the core PRNG (a
@@ -488,20 +540,33 @@ def sample_neighbor(adj: dict, nodes, key, count: int):
         if _KERNEL_MESH is not None:
             mesh, axis = _KERNEL_MESH
             n_sh = mesh.shape[axis]
-            if m > 0 and m % n_sh == 0 and pallas_sampling.eligible(
-                m // n_sh, count
-            ):
+            if m == 0 or m % n_sh:
+                why = f"{m} rows do not divide {n_sh} '{axis}' shards"
+            elif not pallas_sampling.eligible(m // n_sh, count):
+                why = (
+                    f"per-shard draw {m // n_sh}x{count} exceeds the "
+                    "kernel budgets (pallas_sampling.eligible)"
+                )
+            else:
+                _log_route(draw, "per-hop Pallas kernel",
+                           f"per shard over {n_sh} '{axis}' shards")
                 return pallas_sampling.sample_neighbor_sharded(
                     adj, nodes, kernel_seed(), count, mesh, axis
                 )
-        elif pallas_sampling.eligible(m, count) and pallas_sampling.available():
+        elif not pallas_sampling.eligible(m, count):
+            why = "draw exceeds the kernel budgets (pallas_sampling.eligible)"
+        elif not pallas_sampling.available():
             # available() (single-device unless force-flagged) guards
             # consts that carry a packed slab from a multi-device build:
-            # after set_kernel_mesh(None) the unsharded pallas_call under
-            # pjit would be the exact composition the module warns about
+            # outside a kernel mesh the unsharded pallas_call under pjit
+            # would be the exact composition the module warns about
+            why = no_kernel_why()
+        else:
+            _log_route(draw, "per-hop Pallas kernel", "single device")
             return pallas_sampling.sample_neighbor(
                 adj, nodes, kernel_seed(), count
             )
+    _log_route(draw, "XLA draw chain", why)
     nodes = jnp.asarray(nodes, dtype=jnp.int32)
     # unknown ids sample the default node: negatives and past-the-slab
     # ids map to the default row on BOTH paths (the kernel clamps the
@@ -958,23 +1023,29 @@ def sample_fanout(adjs, roots, key, counts):
 
 def _sample_fanout2_route(adjs, roots, key, counts):
     """[roots, hop1, hop2] via the chained kernel when this fanout
-    qualifies, else None (caller keeps the per-hop loop). Mirrors
-    sample_neighbor's routing: direct kernel on a single device
-    (available()), shard_map per-shard when a kernel mesh is
-    registered."""
+    qualifies, else None (caller keeps the per-hop loop, whose draws
+    log their own path). Mirrors sample_neighbor's routing: direct
+    kernel on a single device (available()), shard_map per-shard when a
+    kernel mesh is registered."""
     from euler_tpu.graph import pallas_sampling
 
+    m = int(roots.shape[0])
+    draw = f"fanout {m}x{'x'.join(map(str, counts))}"
+
+    def per_hop(why):
+        """Say why the chained kernel is not taken; the route is None."""
+        _log_route(draw, "per-hop draws", why)
+
     if len(adjs) != 2:
-        return None
+        return per_hop(f"{len(adjs)} hops (the chained kernel fuses 2)")
     a1, a2 = adjs
     if "packed" not in a1 or "packed" not in a2:
-        return None
+        return per_hop("an adjacency has no packed slab")
     if a1["nbr"].shape[0] != a2["nbr"].shape[0]:
-        return None
+        return per_hop("the hops' slabs cover different id spaces")
     f1, f2 = counts
-    m = int(roots.shape[0])
     if m == 0:
-        return None
+        return per_hop("no roots")
     n_rows = a1["nbr"].shape[0]
     k1 = a1["packed"].shape[0] // (2 * n_rows)
     k2 = a2["packed"].shape[0] // (2 * n_rows)
@@ -982,21 +1053,26 @@ def _sample_fanout2_route(adjs, roots, key, counts):
     def kernel_seed():
         return jax.random.randint(key, (2,), 0, jnp.iinfo(jnp.int32).max)
 
+    over = "exceeds the chained kernel's budgets (pallas_sampling.eligible2)"
     if _KERNEL_MESH is not None:
         mesh, axis = _KERNEL_MESH
         n_sh = mesh.shape[axis]
-        if m % n_sh == 0 and pallas_sampling.eligible2(
-            m // n_sh, f1, f2, k1, k2
-        ):
-            h1, h2 = pallas_sampling.sample_fanout2_sharded(
-                a1, a2, roots, kernel_seed(), f1, f2, mesh, axis
-            )
-            return [roots, h1.reshape(-1), h2.reshape(-1)]
-    elif pallas_sampling.eligible2(
-        m, f1, f2, k1, k2
-    ) and pallas_sampling.available():
+        if m % n_sh:
+            return per_hop(f"{m} roots do not divide {n_sh} '{axis}' shards")
+        if not pallas_sampling.eligible2(m // n_sh, f1, f2, k1, k2):
+            return per_hop(f"per-shard fanout {m // n_sh}x{f1}x{f2} {over}")
+        _log_route(draw, "chained two-hop Pallas kernel",
+                   f"per shard over {n_sh} '{axis}' shards")
+        h1, h2 = pallas_sampling.sample_fanout2_sharded(
+            a1, a2, roots, kernel_seed(), f1, f2, mesh, axis
+        )
+    elif not pallas_sampling.eligible2(m, f1, f2, k1, k2):
+        return per_hop(f"fanout {over}")
+    elif not pallas_sampling.available():
+        return per_hop(no_kernel_why())
+    else:
+        _log_route(draw, "chained two-hop Pallas kernel", "single device")
         h1, h2 = pallas_sampling.sample_fanout2(
             a1, a2, roots, kernel_seed(), f1, f2
         )
-        return [roots, h1.reshape(-1), h2.reshape(-1)]
-    return None
+    return [roots, h1.reshape(-1), h2.reshape(-1)]

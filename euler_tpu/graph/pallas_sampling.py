@@ -35,9 +35,12 @@ sequences differ for the same seed while distributions match
 (statistically pinned against the host engine in
 tests/test_pallas_sampling.py, TPU-only).
 
-SPMD note: pallas_call does not partition under pjit, so the kernel
-auto-activates only on a single-device TPU (``available()``); meshes
-keep the XLA path. Force on/off with EULER_TPU_PALLAS_SAMPLING=1/0.
+SPMD note: pallas_call does not partition under pjit, so the kernel is
+called directly only on a single-device TPU (``available()``); on a
+mesh it runs per shard inside shard_map once the mesh is registered
+(device.kernel_mesh_scope — run_loop.main and train.train do).
+EULER_TPU_PALLAS_SAMPLING=0 forces the XLA chain. Which path a draw
+took, and why, is logged at trace time (device.py).
 
 Chained two-hop variant: ``sample_fanout2`` fuses BOTH fanout hops into
 one program — each stage of root rows draws its hop-1 picks, async-
@@ -67,12 +70,24 @@ import os
 import numpy as np
 
 LANES = 128
+# Kernel budgets. Each is a promise that a shape eligible()/eligible2()
+# admits is one Mosaic compiles — checked on the chip at every corner by
+# tests/test_pallas_sampling.py::test_eligible*_corners_compile. They
+# are EMPIRICAL (TPU v5e, libtpu 0.0.34, PR 21; PERF.md "Kernel
+# budgets"): where XLA places a kernel's whole-array VMEM outputs, and
+# what else is in VMEM beside them, is not a simple function of their
+# bytes. What the chip runs did establish: (1) the outputs are tiled
+# (8, 128), 512 B a row whatever the draw count, and 129 MB of them
+# exceed a v5e's 128 MB; (2) a stage's draw holds its `count` [rows, 1]
+# pick columns live until the final concat, each lane-sparse (one vreg
+# per 8 rows), so rows*count — not output bytes — is what fills VMEM
+# with spills: a 512-row stage ran out of VMEM at 32 draws and, after a
+# 341 s compile, at 128.
 MAX_COUNT = 128  # larger per-node draw counts keep the XLA path: the
-# count loop is unrolled in the kernel and the [M, count] output lives
-# whole in VMEM, both of which scale linearly with count; every model
-# draw (fanouts, walks, negatives) is far below this
-MAX_OUT_ELEMS = 1 << 20  # [M, count] output cap (4 MB VMEM): bigger
-# draws keep the XLA path — see eligible()
+# count loop is unrolled in the kernel; every model draw (fanouts,
+# walks, negatives) is far below this
+MAX_OUT_ELEMS = 1 << 20  # [M, count] output cap: bigger draws keep the
+# XLA path. Compiled at 2x this (32768 x 64), refused at 4x (32768 x 128)
 MAX_M = 1 << 15  # source-node cap: ids ride scalar prefetch (SMEM, far
 # smaller than VMEM — 128 KB of ids at this cap), so M needs its own
 # bound even when M*count fits the output budget (e.g. count=1 walks)
@@ -83,20 +98,33 @@ MAX_PACKED_BYTES = 2 << 30  # pack_adjacency opt-out: the packed slab is
 # over nbr+cum that it is ADDED to; beyond this budget the kernel is not
 # worth the HBM
 _MAX_R = 512  # rows per pipeline stage (2 DMA semaphores regardless)
+_MAX_STAGE_PICKS = 8192  # rows x count one single-hop stage may draw
+# (fact 2); counts <= 16 keep the full 512-row stage
+# The chained kernel holds both hops' code and scratch at once and is
+# tighter. Refused on the chip: a 512-row hop-2 stage (f1 = 64), a hop-2
+# stage of 64 rows x 128 draws, 256 rows x 16 draws over K = 4 slabs,
+# 262144 hop-2 rows (129 MB), and hop-2 outputs of 1.19M elements and up
+# at several shapes (1.05M compiled at every shape tried).
+MAX_F1 = 32  # hop-2 stages are rows*f1 source rows, rows >= 8
+_MAX_STAGE_ROWS2 = 256  # rows x f1 of one hop-2 stage
+_MAX_STAGE_PICKS2 = 4096  # rows x f1 x f2 x K2 of one hop-2 stage
+MAX_OUT_ROWS2 = 1 << 17  # m x (1 + f1), the rows of both hop outputs:
+# 64 MB of VMEM at this cap (fact 1)
 
 
 def _backend_ok(require_single_device: bool) -> bool:
-    try:
-        import jax
+    import jax
 
-        if jax.default_backend() != "tpu":
-            return False
-        if require_single_device and len(jax.devices()) != 1:
-            return False
-        from jax.experimental import pallas  # noqa: F401
-        from jax.experimental.pallas import tpu  # noqa: F401
-    except Exception:  # pragma: no cover - import/backend probing
+    if jax.default_backend() != "tpu":
         return False
+    if require_single_device and len(jax.devices()) != 1:
+        return False
+    # Not caught: on a TPU backend a Pallas import or API error is a
+    # broken installation, and swallowing it would route every draw to
+    # the XLA chain without a word.
+    from jax.experimental import pallas  # noqa: F401
+    from jax.experimental.pallas import tpu  # noqa: F401
+
     return True
 
 
@@ -124,19 +152,19 @@ def _force_flag():
 
 
 def available() -> bool:
-    """True when the kernel path should auto-activate: TPU backend, one
-    device (see SPMD note above), imports work, not overridden by env.
+    """True when the kernel should be called DIRECTLY: TPU backend, one
+    device (see SPMD note above), not overridden by env.
     EULER_TPU_PALLAS_SAMPLING=1 skips the single-device heuristic —
-    but only once a kernel mesh is registered
-    (device.set_kernel_mesh, which run_loop calls on the
-    --device_sampling path): on a multi-device backend with NO mesh
-    registered the flag warns and still returns False, because the
-    direct (non-shard_map) route would run an unsharded pallas_call
-    under pjit — silently wrong per-shard draws. Experts composing
-    their own shard_map call pallas_sampling.sample_neighbor directly,
-    which never consults this gate. The flag still requires a TPU
-    backend with pallas importable — the kernel's primitives exist
-    nowhere else; =0 forces the XLA path."""
+    but only once a kernel mesh is registered (device.kernel_mesh_scope,
+    which run_loop.main and train.train enter): on a multi-device
+    backend with NO mesh registered the flag warns and still returns
+    False, because the direct (non-shard_map) route would run an
+    unsharded pallas_call under pjit — silently wrong per-shard draws.
+    Experts composing their own shard_map call
+    pallas_sampling.sample_neighbor directly, which never consults this
+    gate. The flag still requires a TPU backend — the kernel's
+    primitives exist nowhere else; =0 forces the XLA path. On a TPU
+    backend a Pallas that does not import raises (_backend_ok)."""
     force = _force_flag()
     if force is not None:
         if not force:
@@ -154,10 +182,9 @@ def available() -> bool:
                     "EULER_TPU_PALLAS_SAMPLING=1 with "
                     f"{len(jax.devices())} devices but no kernel mesh:"
                     " pallas_call does not partition under pjit, so the"
-                    " force flag is ignored (XLA path) — register the"
-                    " mesh with device.set_kernel_mesh, as run_loop's"
-                    " --device_sampling path does, to wire the kernel"
-                    " per-shard",
+                    " force flag is ignored (XLA path) — run inside"
+                    " device.kernel_mesh_scope(mesh), as run_loop.main"
+                    " and train.train do, to wire the kernel per-shard",
                     stacklevel=2,
                 )
                 return False
@@ -167,8 +194,8 @@ def available() -> bool:
 
 def sharded_available() -> bool:
     """True when the kernel can run PER-SHARD inside shard_map on this
-    backend: TPU with pallas importable, any device count. This is the
-    mesh-path activation check (device.set_kernel_mesh wires it);
+    backend: TPU, any device count. This is the mesh-path activation
+    check (device.kernel_mesh_scope consults it);
     available() stays the single-device auto-activation check —
     pallas_call does not partition under plain pjit."""
     if _force_flag() is False:
@@ -183,11 +210,21 @@ def interpret_params():
     detector. Test-only — interpretation is orders of magnitude slower
     than both the compiled kernel and the XLA chain, so nothing
     auto-activates it; available() is unaffected (the interpret knob
-    changes how an explicit kernel call executes, not routing)."""
+    changes how an explicit kernel call executes, not routing). Refused
+    on a TPU backend, where it would quietly run the emulator in place
+    of the compiled kernel."""
     raw = os.environ.get("EULER_TPU_PALLAS_INTERPRET")
     if raw not in ("1", "races"):
         return False
+    import jax
     from jax.experimental.pallas import tpu as pltpu
+
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"EULER_TPU_PALLAS_INTERPRET={raw} on a TPU backend: the "
+            "kernels compile for real here — unset it (interpret mode "
+            "is the CPU tests' emulator)"
+        )
 
     return pltpu.InterpretParams(detect_races=(raw == "races"))
 
@@ -195,8 +232,8 @@ def interpret_params():
 def eligible(m: int, count: int) -> bool:
     """True when a draw of ``m`` source nodes x ``count`` fits the
     kernel's on-core budgets (ids in scalar prefetch / SMEM, [M, count]
-    output whole in VMEM); callers fall back to the XLA chain
-    otherwise."""
+    output whole in VMEM — see the budget notes at the top); callers
+    fall back to the XLA chain otherwise."""
     return (
         count <= MAX_COUNT
         and m <= MAX_M
@@ -311,7 +348,7 @@ def _kernel(ids_ref, seed_ref, pk_hbm, *rest,
         u_ref, (out_ref, pk_s, sem) = None, rest
 
     # both words seed the core PRNG: 62 bits of caller entropy (a lone
-    # int31 word collides across long runs — ADVICE r2)
+    # int31 word collides across long runs)
     pltpu.prng_seed(seed_ref[0], seed_ref[1])
 
     def dma(slot, r, row):
@@ -402,7 +439,9 @@ def sample_neighbor(adj: dict, nodes, seed, count: int, u=None):
     flat = jnp.where(flat < 0, n_rows - 1, jnp.minimum(flat, n_rows - 1))
     # power-of-two stage size (sublane-aligned dynamic slices), floored
     # at 8, scaled down by K to keep the 2-slot scratch K-independent
-    max_r = max(8, 1 << ((_MAX_R // k).bit_length() - 1))
+    # and by count to keep the stage's live picks in budget
+    max_r = max(1, min(_MAX_R // k, _MAX_STAGE_PICKS // count))
+    max_r = max(8, 1 << (max_r.bit_length() - 1))
     rows = max_r if m >= max_r else max(8, 1 << (m - 1).bit_length())
     mp = ((m + rows - 1) // rows) * rows
     ids = jnp.pad(flat, (0, mp - m))
@@ -439,40 +478,22 @@ def sample_neighbor(adj: dict, nodes, seed, count: int, u=None):
     return out[:m].reshape(*shape, count)
 
 
-def _shard_map():
-    """jax's shard_map across the 0.7 rename (check_rep -> check_vma);
-    callers pass check_rep and get whichever kwarg this jax expects."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.7 (check_vma kwarg)
-
-        def shard_map(f, **kw):
-            kw["check_vma"] = kw.pop("check_rep")
-            return _sm(f, **kw)
-
-        return shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map
-
-
 def eligible2(m: int, f1: int, f2: int, k1: int = 1, k2: int = 1) -> bool:
     """True when a chained two-hop fanout of ``m`` roots x f1 x f2 over
-    K1/K2-row-pair slabs fits the fused kernel's budgets: root ids in
-    scalar prefetch (SMEM), both hop outputs whole in VMEM, and the
-    hop-2 scratch within its ~3 MB budget even at the MINIMUM stage
-    size of 8 rows (k2 * f1 * 8 <= 1536 — without this check a wide
-    hop-2 slab x large f1 would pass and then fail VMEM allocation at
-    compile time instead of falling back). Callers fall back to the
-    per-hop path (which may still use the single-hop kernel)
-    otherwise."""
+    K1/K2-row-pair slabs fits the fused kernel's budgets (notes at the
+    top): root ids in scalar prefetch (SMEM), both hop outputs whole in
+    VMEM, and a hop-2 stage that still fits at the MINIMUM stage size of
+    8 rows — without that a wide fanout would pass and then fail VMEM
+    allocation at compile time instead of falling back. Callers fall
+    back to the per-hop path (which may still use the single-hop
+    kernel) otherwise."""
     return (
-        f1 <= MAX_COUNT
+        f1 <= MAX_F1
         and f2 <= MAX_COUNT
         and m <= MAX_M
-        and m * f1 <= MAX_OUT_ELEMS
         and m * f1 * f2 <= MAX_OUT_ELEMS
-        and k2 * f1 * 8 <= 1536
+        and m * (1 + f1) <= MAX_OUT_ROWS2
+        and 8 * f1 * f2 * k2 <= _MAX_STAGE_PICKS2
         and k1 <= MAX_W // LANES
         and k2 <= MAX_W // LANES
     )
@@ -644,15 +665,16 @@ def sample_fanout2(adj1: dict, adj2: dict, roots, seed, f1: int, f2: int,
         roots < 0, n_rows - 1, jnp.minimum(roots, n_rows - 1)
     )
     # stage size: power-of-two (sublane-aligned out1 slices), sized so
-    # the hop-2 scratch (2 slots x 2*k2*R*f1 rows) stays ~<= 3 MB and
-    # the full-lane-width pick buffers (R x 128 ids in VMEM scratch and
-    # SMEM — full width because the VMEM->SMEM DMA must be 128-lane
-    # aligned) stay <= 8 KB, i.e. R <= 16
-    r_max = min(
+    # the hop-2 stage stays inside its row and pick budgets (eligible2
+    # guarantees 8 rows do) and the full-lane-width pick buffers (R x
+    # 128 ids in VMEM scratch and SMEM — full width because the
+    # VMEM->SMEM DMA must be 128-lane aligned) stay <= 8 KB, i.e. R <= 16
+    r_max = max(1, min(
         _MAX_R // k1,
-        max(1, 1536 // (k2 * f1)),
+        _MAX_STAGE_ROWS2 // f1,
+        _MAX_STAGE_PICKS2 // (f1 * f2 * k2),
         16,
-    )
+    ))
     r_max = max(8, 1 << (r_max.bit_length() - 1))
     rows = r_max if m >= r_max else max(8, 1 << (m - 1).bit_length())
     mp = ((m + rows - 1) // rows) * rows
@@ -738,8 +760,7 @@ def sample_fanout2_sharded(
         s = seed_l + (ai + 1) * jnp.int32(0x9E3779B1 - (1 << 32))
         return draw_fn(adj1_l, adj2_l, roots_l, s, f1, f2)
 
-    sm = _shard_map()
-    out1, out2 = sm(
+    out1, out2 = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -749,7 +770,7 @@ def sample_fanout2_sharded(
             P(),
         ),
         out_specs=(P(axis), P(axis)),
-        check_rep=False,
+        check_vma=False,
     )(adj1, adj2, roots, seed)
     return out1, out2
 
@@ -776,7 +797,6 @@ def sample_neighbor_sharded(
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    shard_map = _shard_map()
     if draw_fn is None:
         draw_fn = sample_neighbor
     nodes = jnp.asarray(nodes, jnp.int32)
@@ -791,7 +811,7 @@ def sample_neighbor_sharded(
         s = seed_l + (ai + 1) * jnp.int32(0x9E3779B1 - (1 << 32))
         return draw_fn(adj_l, nodes_l, s, count)
 
-    out = shard_map(
+    out = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -800,6 +820,6 @@ def sample_neighbor_sharded(
             P(),
         ),
         out_specs=P(axis),
-        check_rep=False,
+        check_vma=False,
     )(adj, flat, seed)
     return out.reshape(*shape, count)

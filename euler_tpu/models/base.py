@@ -14,6 +14,7 @@ sampler run ahead of the TPU.
 from __future__ import annotations
 
 import dataclasses
+import logging
 import os
 from typing import Any, Optional
 
@@ -24,6 +25,8 @@ import numpy as np
 import optax
 
 from euler_tpu.nn import metrics
+
+log = logging.getLogger("euler_tpu")
 
 
 @dataclasses.dataclass
@@ -328,6 +331,7 @@ class Model:
             device_graph.kernel_mesh() is not None
             and pallas_sampling.sharded_available()
         )
+        no_pallas_why = "" if use_pallas else device_graph.no_kernel_why()
         adj = consts.setdefault("adj", {})
         for et in edge_type_sets:
             k = self.adj_key(et, sorted=sorted)
@@ -382,13 +386,29 @@ class Model:
                 # host-side metadata, never part of the traced consts
                 slab.pop("truncated_rows", 0)
                 adj[k] = slab
-                if use_pallas and not sorted:
-                    # packed slab routes sample_neighbor through the
-                    # fused Pallas kernel (sorted slabs feed biased
-                    # walks, which read nbr/cum directly — no packing)
+                # packed slab routes sample_neighbor through the fused
+                # Pallas kernel (sorted slabs feed biased walks, which
+                # read nbr/cum directly — no packing). Said once per
+                # slab either way: the draw path a step takes is
+                # decided here as much as at trace time.
+                why = no_pallas_why
+                if sorted:
+                    why = "sorted slab: biased walks read nbr/cum directly"
+                elif use_pallas:
                     packed = pallas_sampling.pack_adjacency(adj[k])
                     if packed is not None:
                         adj[k]["packed"] = packed
+                    else:
+                        why = (
+                            f"slab width {slab['nbr'].shape[1]} over "
+                            f"MAX_W={pallas_sampling.MAX_W} or packed "
+                            "copy over MAX_PACKED_BYTES"
+                        )
+                log.info(
+                    "device sampling %s: %s", k,
+                    f"slab NOT packed for the Pallas kernel ({why})"
+                    if why else "slab packed for the Pallas kernel",
+                )
         if negs_type is not None:
             consts["negs"] = device_graph.build_node_sampler(
                 graph, negs_type, self.max_id

@@ -2,9 +2,6 @@ from euler_tpu.parallel.mesh import (
     batch_sharding,
     enable_compile_cache,
     force_cpu_devices,
-    honor_jax_platforms_env,
-    probe_backend_once,
-    probe_backend_or_die,
     make_mesh,
     pad_tables_for_mesh,
     put_global,
@@ -19,9 +16,6 @@ __all__ = [
     "batch_sharding",
     "enable_compile_cache",
     "force_cpu_devices",
-    "honor_jax_platforms_env",
-    "probe_backend_once",
-    "probe_backend_or_die",
     "make_mesh",
     "pad_tables_for_mesh",
     "put_global",

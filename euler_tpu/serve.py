@@ -364,23 +364,17 @@ def main(argv=None) -> int:
         level=logging.INFO, format="%(asctime)s %(name)s %(message)s"
     )
     logging.getLogger("absl").setLevel(logging.WARNING)
-    from euler_tpu.parallel import (
-        honor_jax_platforms_env,
-        make_mesh,
-        probe_backend_or_die,
-    )
+    from euler_tpu.parallel import enable_compile_cache, make_mesh
 
-    honor_jax_platforms_env()
     args = run_loop.define_flags().parse_args(argv)
     args.mode = "evaluate"  # inference sampling config (all_edge_type)
-    probe_backend_or_die()
     if not args.telemetry:
         T.set_telemetry(False)
-    # device plane + compile cache before the embed jit: the serve
+    # compile cache + device plane before the embed jit: the serve
     # forward is the program the cache saves a relaunch from
     # recompiling, and the compile-storm guard needs the listener live
-    devprof.setup(enabled=args.devprof, compile_cache=args.compile_cache,
-                  model_dir=args.model_dir, sample_ms=1000)
+    log.info("persistent compile cache: %s", enable_compile_cache() or "off")
+    devprof.setup(enabled=args.devprof, sample_ms=1000)
     graph, services = run_loop.build_graph(args)
     try:
         mesh = make_mesh(args.num_devices,

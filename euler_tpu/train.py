@@ -9,6 +9,8 @@ pipeline is the host sampler behind a prefetch queue.
 
 from __future__ import annotations
 
+import functools
+import inspect
 import logging
 import time
 from typing import Callable, Optional
@@ -17,6 +19,7 @@ import jax
 import numpy as np
 import optax
 
+from euler_tpu.graph import device as device_graph
 from euler_tpu.nn import metrics as metrics_lib
 from euler_tpu.parallel import (
     batch_sharding,
@@ -68,6 +71,26 @@ def _metric_zero(name: str):
     return np.zeros(2)
 
 
+def _kernel_mesh_scoped(fn):
+    """Run ``fn`` with its ``mesh`` argument (default: every device)
+    registered for per-shard Pallas draws (device.kernel_mesh_scope),
+    so a device-sampling model keeps the kernel on a multi-chip mesh
+    however the trainer is reached — run_loop, the examples, a direct
+    call."""
+    sig = inspect.signature(fn)
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        bound = sig.bind(*args, **kwargs)
+        if bound.arguments.get("mesh") is None:
+            bound.arguments["mesh"] = make_mesh()
+        with device_graph.kernel_mesh_scope(bound.arguments["mesh"]):
+            return fn(*bound.args, **bound.kwargs)
+
+    return scoped
+
+
+@_kernel_mesh_scoped
 def train(
     model,
     graph,
@@ -147,8 +170,6 @@ def train(
     first ~prefetch_depth profiled steps were issued before the trace
     starts and won't appear in it.
     """
-    if mesh is None:
-        mesh = make_mesh()
     n_mesh_devices = int(np.prod(mesh.devices.shape))
     cpu_virtual_mesh = (
         n_mesh_devices > 1
@@ -431,8 +452,6 @@ def make_scan_train(model, optimizer, inner_steps: int, batch_size: int):
     """
     import jax.numpy as jnp
 
-    from euler_tpu.graph import device as device_graph
-
     step = model.make_train_step(optimizer)
 
     def scan_fn(state, seed):
@@ -457,6 +476,7 @@ def make_scan_train(model, optimizer, inner_steps: int, batch_size: int):
     return scan_fn
 
 
+@_kernel_mesh_scoped
 def evaluate(
     model,
     graph,
@@ -473,8 +493,6 @@ def evaluate(
     1/process_count slice and shard_batch concatenates — the jitted
     metric is computed over the reassembled global batch, so the result
     is identical to single-process."""
-    if mesh is None:
-        mesh = make_mesh()
     rep = replicated_sharding(mesh)
     state = pad_tables_for_mesh(state, mesh)
     shardings = state_sharding(mesh, state)
@@ -507,6 +525,7 @@ def evaluate(
     return result
 
 
+@_kernel_mesh_scoped
 def save_embedding(
     model,
     graph,
@@ -522,8 +541,6 @@ def save_embedding(
     chunk; the output sharding is replicated there (XLA all-gathers over
     ICI) so every process returns the full matrix — a batch-sharded
     output would span non-addressable devices and be unfetchable."""
-    if mesh is None:
-        mesh = make_mesh()
     state = pad_tables_for_mesh(state, mesh)
     shardings = state_sharding(mesh, state)
     state = put_global(state, shardings)

@@ -1,38 +1,28 @@
 #!/usr/bin/env python3
-"""Performance regression gate over the bench trajectory.
+"""Control-flow regression gate over the CPU smoke benches.
 
-Two jobs (ISSUE 6 satellite; see PERF.md "Throughput trajectory"):
-
-1. **Trajectory** — parse the driver-recorded BENCH_r0*.json rounds
-   into one table (round, headline edges/s, platform) so the repo's
-   throughput history is a first-class artifact instead of five JSON
-   blobs (`--table` prints it as markdown for PERF.md).
-
-2. **Regression verdict** — run the cheap smoke benches
-   (`bench.py --smoke`, `scripts/remote_bench.py --smoke`), compare
-   each against the BEST prior smoke round recorded in
-   ``evidence/perf_gate/history.jsonl``, and print a verdict. Smoke
-   numbers are NOT comparable to the full-config BENCH trajectory
-   (different graph sizes), which is why the gate keeps its own
-   smoke-to-smoke history; every run appends to it.
+Runs the cheap smoke benches (`bench.py --smoke`,
+`scripts/remote_bench.py --smoke`) with JAX_PLATFORMS=cpu, compares
+each against the BEST prior smoke round recorded in
+``evidence/perf_gate/history.jsonl``, and prints a verdict; every run
+appends to that history. These are XLA-CPU numbers on a toy graph: they
+catch a host-path or remote-client slowdown in this container and say
+nothing about the chip.
 
 Warn-only by default — verify.sh calls it so a silent throughput
 regression is at least SHOUTED before it reaches a PR — `--strict`
 exits nonzero on a regression beyond ``--tolerance`` (default 25%,
-sized for this container's run-to-run noise; see PERF.md's
-measurement-noise notes).
+sized for this container's run-to-run noise).
 
 Usage:
     python scripts/perf_gate.py                 # run smokes + verdict
     python scripts/perf_gate.py --strict        # same, exit 1 on regress
-    python scripts/perf_gate.py --table         # trajectory markdown only
     python scripts/perf_gate.py --skip-bench    # remote smoke only
 """
 
 from __future__ import annotations
 
 import argparse
-import glob
 import json
 import os
 import subprocess
@@ -41,43 +31,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HISTORY = os.path.join(REPO, "evidence", "perf_gate", "history.jsonl")
-
-
-def load_trajectory(repo: str = REPO) -> list:
-    """BENCH_r0*.json -> [{round, value, unit, metric, platform}],
-    rounds with no parsed headline (a failed bench run) included with
-    value None so the table shows the gap honestly."""
-    rows = []
-    for path in sorted(glob.glob(os.path.join(repo, "BENCH_r0*.json"))):
-        try:
-            with open(path) as f:
-                d = json.load(f)
-        except ValueError:
-            continue
-        p = d.get("parsed") or {}
-        rows.append({
-            "round": d.get("n"),
-            "value": p.get("value"),
-            "unit": p.get("unit"),
-            "metric": p.get("metric"),
-            "platform": (p.get("detail") or {}).get("platform"),
-            "error": (p.get("error") or "")[:60] or None,
-        })
-    return rows
-
-
-def trajectory_markdown(rows: list) -> str:
-    out = ["| round | headline edges/s | platform | note |",
-           "|---|---|---|---|"]
-    best = max((r["value"] for r in rows if r["value"]), default=None)
-    for r in rows:
-        val = f"{r['value']:,.0f}" if r["value"] else "—"
-        if r["value"] and r["value"] == best:
-            val = f"**{val}**"
-        note = r["error"] or ""
-        out.append(f"| {r['round']} | {val} | {r['platform'] or '—'} "
-                   f"| {note} |")
-    return "\n".join(out)
 
 
 def _last_json_line(text: str) -> dict | None:
@@ -185,8 +138,6 @@ def main() -> int:
     ap.add_argument("--tolerance", type=float, default=0.25, help=(
         "allowed fractional drop below the best prior smoke round "
         "before the verdict says regression (container noise floor)"))
-    ap.add_argument("--table", action="store_true",
-                    help="print the BENCH trajectory markdown and exit")
     ap.add_argument("--skip-bench", action="store_true",
                     help="skip bench.py --smoke (remote smoke only)")
     ap.add_argument("--skip-remote", action="store_true",
@@ -198,17 +149,6 @@ def main() -> int:
     ap.add_argument("--history", default=HISTORY, help=argparse.SUPPRESS)
     args = ap.parse_args()
 
-    rows = load_trajectory()
-    if args.table:
-        print(trajectory_markdown(rows))
-        return 0
-
-    print("== bench trajectory (BENCH_r0*.json, full configs) ==")
-    for r in rows:
-        val = f"{r['value']:,.0f}" if r["value"] else "(no headline)"
-        print(f"  round {r['round']}: {val} {r['unit'] or ''} "
-              f"[{r['platform'] or '?'}]")
-
     current: dict = {}
     if not args.skip_bench:
         head = run_smoke_bench(args.timeout)
@@ -217,7 +157,7 @@ def main() -> int:
         head = run_smoke_remote(args.timeout)
         current["remote_smoke"] = head.get("value") if head else None
     if not current:
-        print("perf_gate: both smokes skipped; trajectory only")
+        print("perf_gate: both smokes skipped; nothing to judge")
         return 0
 
     history = load_history(args.history)

@@ -150,11 +150,11 @@ timeout -k 10 300 env JAX_PLATFORMS=cpu \
   python scripts/devprof_dump.py --smoke >/dev/null || fail=1
 
 step "perf gate (scripts/perf_gate.py — strict for bench_smoke, warn-only remote)"
-# Smoke-to-smoke throughput trajectory check (PERF.md "Throughput
-# trajectory"). The host-only bench.py --smoke config now GATES verify
-# (its history has a multi-round trajectory and it runs without the
-# remote path's 1-core container noise); the remote configs stay
-# warn-only. `perf_gate.py --strict` enforces everything.
+# Smoke-to-smoke control-flow check on XLA-CPU (says nothing about the
+# chip). The host-only bench.py --smoke config GATES verify (its history
+# has several rounds and it runs without the remote path's container
+# noise); the remote configs stay warn-only. `perf_gate.py --strict`
+# enforces everything.
 timeout -k 10 600 env JAX_PLATFORMS=cpu \
   python scripts/perf_gate.py --strict-configs bench_smoke || fail=1
 

@@ -1,12 +1,16 @@
 """Fused Pallas sampling kernels vs the host engine and the XLA path.
 
-The kernel-executing tests here require a real single-device TPU
-backend: they exercise the ON-CORE PRNG's stream (statistical pinning
-against the host engine) and the compiled kernels, which interpret
-mode cannot attest. Run manually on a chip (the env var keeps
-conftest.py from forcing the virtual CPU backend):
+The kernel-executing tests here require a real TPU backend: they
+exercise the ON-CORE PRNG's stream (statistical pinning against the
+host engine) and the compiled kernels, which interpret mode cannot
+attest. Run on the chip (the env var keeps conftest.py from forcing the
+virtual CPU backend):
 
     EULER_TPU_TESTS_ON_TPU=1 python -m pytest tests/test_pallas_sampling.py -v
+
+On one chip the single-device kernel tests run; on a host with >= 4
+chips those skip (the direct route is single-device by design) and the
+per-shard kernel tests at the end of the file run on a real mesh.
 
 Everything BELOW the PRNG — layout, DMA addressing, rank/select across
 registers, the chained kernel's data-dependent hop-2 DMAs, default/OOB
@@ -14,9 +18,9 @@ contracts — additionally runs on CPU in the default suite through
 pallas' TPU interpret mode with injected uniforms, as EXACT-equality
 tests: see tests/test_pallas_interpret.py.
 
-The recorded on-chip run for this round is in PERF.md (step anatomy
-section); the distribution check mirrors tests/test_device_graph.py's
-statistical pinning of the XLA path against the host engine.
+The recorded on-chip runs are in PERF.md (Findings); the distribution
+check mirrors tests/test_device_graph.py's statistical pinning of the
+XLA path against the host engine.
 """
 
 import numpy as np
@@ -32,6 +36,10 @@ tpu_only = pytest.mark.skipif(
     not pallas_sampling.available(),
     reason="needs a single-device TPU backend (pallas kernel path)",
 )
+tpu_mesh = pytest.mark.skipif(
+    not (pallas_sampling.sharded_available() and len(jax.devices()) >= 4),
+    reason="needs a TPU backend with >= 4 devices (per-shard kernel path)",
+)
 
 
 # ---- activation guards (pure host logic, run everywhere) ----
@@ -42,19 +50,27 @@ def test_eligible_budgets():
     assert ps.eligible(5120, 10)            # PPI hop-2 draw
     assert ps.eligible(1, ps.MAX_COUNT)
     assert not ps.eligible(1, ps.MAX_COUNT + 1)
-    assert not ps.eligible(204800, 10)      # [M, count] past the VMEM cap
+    assert not ps.eligible(204800, 10)      # [M, count] past the output cap
+    assert not ps.eligible(ps.MAX_M + 1, 1)  # ids past the SMEM cap
 
 
 def test_eligible2_budgets():
     ps = pallas_sampling
     assert ps.eligible2(512, 10, 10)            # the PPI recipe fanout
     assert ps.eligible2(1000, 4, 4, k1=4, k2=4)  # reddit recipe, wide slabs
-    assert not ps.eligible2(512, ps.MAX_COUNT + 1, 4)
-    assert not ps.eligible2(204800, 10, 10)     # hop-2 out past VMEM cap
-    # hop-2 scratch at the MINIMUM stage (8 rows) must fit: k2*f1 <= 192,
-    # else the kernel would fail VMEM allocation instead of falling back
-    assert not ps.eligible2(128, 128, 2, k1=1, k2=4)
-    assert ps.eligible2(128, 48, 2, k1=1, k2=4)
+    assert not ps.eligible2(512, ps.MAX_F1 + 1, 4)
+    assert not ps.eligible2(512, 4, ps.MAX_COUNT + 1)
+    assert ps.eligible2(10485, 10, 10)          # hop-2 output at its cap
+    assert not ps.eligible2(10486, 10, 10)
+    # both hop outputs are whole in VMEM at 512 B a row: m * (1 + f1) rows
+    assert ps.eligible2(ps.MAX_M, 3, 8)
+    assert not ps.eligible2(ps.MAX_M, 4, 8)
+    # a hop-2 stage at the MINIMUM stage (8 rows) must fit its pick
+    # budget — f1 * f2 * k2 <= 512 — else the kernel would fail VMEM
+    # allocation at compile time instead of falling back
+    assert ps.eligible2(128, 32, 16) and not ps.eligible2(128, 32, 17)
+    assert ps.eligible2(128, 8, 16, k1=1, k2=4)
+    assert not ps.eligible2(128, 16, 16, k1=1, k2=4)
 
 
 def test_pack_adjacency_hbm_budget():
@@ -353,6 +369,98 @@ def test_packed_consts_without_mesh_take_xla_chain_when_unavailable(
         adj, jnp.zeros((5,), jnp.int32), jax.random.PRNGKey(0), 6
     )
     assert kernel_calls and out is None  # the fake kernel was called
+
+
+@multi_device
+def test_kernel_mesh_scope_registers_and_restores(monkeypatch):
+    """kernel_mesh_scope registers a multi-device mesh only where the
+    per-shard kernel can run, CLEARS a stale registration otherwise, and
+    always restores what it found — so nothing an outer or earlier
+    caller left can route this block's draws."""
+    from jax.sharding import Mesh
+
+    from euler_tpu.graph import device as dg
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    stale = Mesh(np.array(jax.devices()[:2]), ("data",))
+    dg.set_kernel_mesh(stale, "data")
+    try:
+        monkeypatch.setattr(
+            pallas_sampling, "sharded_available", lambda: False
+        )
+        with dg.kernel_mesh_scope(mesh):  # no kernel to shard: cleared
+            assert dg.kernel_mesh() is None
+        assert dg.kernel_mesh() == (stale, "data")
+        monkeypatch.setattr(pallas_sampling, "sharded_available", lambda: True)
+        with dg.kernel_mesh_scope(mesh):
+            assert dg.kernel_mesh() == (mesh, "data")
+            one = Mesh(np.array(jax.devices()[:1]), ("data",))
+            with dg.kernel_mesh_scope(one):  # one device: direct call
+                assert dg.kernel_mesh() is None
+            assert dg.kernel_mesh() == (mesh, "data")
+        assert dg.kernel_mesh() == (stale, "data")
+    finally:
+        dg.set_kernel_mesh(None)
+
+
+@multi_device
+def test_trainer_entry_points_scope_their_mesh(monkeypatch):
+    """train()/evaluate()/save_embedding() called directly (the
+    examples, bench.py) register their mesh like run_loop.main does —
+    a multi-chip run no longer takes the XLA chain for want of a
+    registration — and default the mesh to every device."""
+    from euler_tpu import train as train_lib
+    from euler_tpu.graph import device as dg
+
+    monkeypatch.setattr(pallas_sampling, "sharded_available", lambda: True)
+    for fn in (train_lib.train, train_lib.evaluate, train_lib.save_embedding):
+        assert fn.__wrapped__  # all three are scoped
+
+    @train_lib._kernel_mesh_scoped
+    def probe(model, mesh=None):
+        return dg.kernel_mesh(), mesh
+
+    registered, mesh = probe("m")
+    assert mesh.size == len(jax.devices()) and registered == (mesh, "data")
+    assert dg.kernel_mesh() is None
+
+
+def test_draw_route_is_logged_once_with_its_reason(adj, caplog):
+    """Which path a draw took, and why, is said at trace time: a slab
+    that was never packed or a fanout the chained kernel cannot take is
+    visible in the log, not silent."""
+    import logging
+
+    import jax.numpy as jnp
+
+    from euler_tpu.graph import device as dg
+
+    plain = {k: v for k, v in adj.items() if k != "packed"}
+    roots = jnp.zeros((6,), jnp.int32)
+    dg._log_route.cache_clear()
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        dg.sample_fanout([plain] * 3, roots, jax.random.PRNGKey(0), [2, 2, 2])
+        dg.sample_fanout([plain] * 3, roots, jax.random.PRNGKey(1), [2, 2, 2])
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("draw path")]
+    assert lines == [
+        "draw path: fanout 6x2x2x2 -> per-hop draws (3 hops (the chained "
+        "kernel fuses 2))",
+        "draw path: neighbor draw 6x2 -> XLA draw chain (adjacency has no "
+        "packed slab)",
+        "draw path: neighbor draw 12x2 -> XLA draw chain (adjacency has no "
+        "packed slab)",
+        "draw path: neighbor draw 24x2 -> XLA draw chain (adjacency has no "
+        "packed slab)",
+    ]
+    if jax.default_backend() != "tpu":
+        # a packed slab with no kernel to run it says so too
+        caplog.clear()
+        with caplog.at_level(logging.INFO, logger="euler_tpu"):
+            dg.sample_neighbor(adj, roots, jax.random.PRNGKey(0), 3)
+        (line,) = [r.getMessage() for r in caplog.records]
+        assert "XLA draw chain (backend cpu" in line
+        assert "no kernel mesh registered" in line
 
 
 # ---- kernel tests (single-device TPU only) ----
@@ -684,3 +792,175 @@ def test_chained_sharded_kernel_executes_on_hardware(adj, graph):
         p = ww / total
         freq = (draws == n_).mean()
         assert abs(freq - p) < 6 * np.sqrt(p * (1 - p) / len(draws)) + 1e-3
+
+
+def _synthetic_adj(n, w):
+    """[n, w] slab whose node i draws uniformly among ids (i+1..i+w) % n;
+    the last row is the default row (draws itself)."""
+    import jax.numpy as jnp
+
+    nbr = (np.arange(n)[:, None] + 1 + np.arange(w)[None, :]) % (n - 1)
+    nbr = nbr.astype(np.int32)
+    cum = np.tile(
+        (np.arange(1, w + 1, dtype=np.float32) / w)[None, :], (n, 1)
+    )
+    cum[:, -1] = 1.0
+    nbr[-1] = n - 1
+    return {
+        "nbr": jnp.asarray(nbr),
+        "cum": jnp.asarray(cum),
+        "sampleable": jnp.ones((n,), bool),
+        "packed": jnp.asarray(
+            pallas_sampling.pack_adjacency({"nbr": nbr, "cum": cum})
+        ),
+    }
+
+
+@tpu_only
+@pytest.mark.parametrize("m,count,w", [
+    (pallas_sampling.MAX_M, 32, 16),      # MAX_M rows at MAX_OUT_ELEMS
+    (8192, pallas_sampling.MAX_COUNT, 16),  # MAX_COUNT at MAX_OUT_ELEMS
+    (pallas_sampling.MAX_M, 1, 16),       # a walk step: one lane per row
+    (8192, 32, pallas_sampling.MAX_W),    # widest slab (K=4)
+])
+def test_eligible_corners_compile(m, count, w):
+    """Every corner eligible() admits must be a shape Mosaic accepts:
+    the bound is a promise that routing to the kernel compiles."""
+    import jax.numpy as jnp
+
+    ps = pallas_sampling
+    assert ps.eligible(m, count)
+    n = 2048
+    adj = _synthetic_adj(n, w)
+    nodes = jnp.asarray(np.arange(m) % n, jnp.int32)
+    out = np.asarray(
+        jax.jit(lambda a, x: ps.sample_neighbor(a, x, jnp.int32(3), count))(
+            adj, nodes
+        )
+    )
+    assert out.shape == (m, count)
+    assert out.min() >= 0 and out.max() <= n - 1
+    assert (out[np.asarray(nodes) == n - 1] == n - 1).all()
+
+
+@tpu_only
+@pytest.mark.parametrize("m,f1,f2,w1,w2", [
+    (512, 10, 10, 32, 32),      # the PPI recipe
+    (1000, 4, 4, 60, 60),       # the Reddit recipe (synthetic slab width)
+    (1000, 4, 4, 512, 512),     # the Reddit recipe over the widest slabs
+    (10485, 10, 10, 16, 16),    # hop-2 output at MAX_OUT_ELEMS
+    (pallas_sampling.MAX_M, 3, 8, 16, 16),  # MAX_M roots at MAX_OUT_ROWS2
+    (26214, 4, 10, 16, 16),     # MAX_OUT_ELEMS and MAX_OUT_ROWS2 at once
+    (2048, 32, 16, 16, 16),     # MAX_F1 and stage picks at MAX_OUT_ELEMS
+    (2048, 4, 128, 16, 16),     # MAX_COUNT draws in hop 2
+    (1024, 8, 16, 16, 512),     # stage picks at the bound over K2 = 4
+    (1024, 32, 4, 512, 512),    # a 256-row hop-2 stage over K = 4 slabs
+])
+def test_eligible2_corners_compile(m, f1, f2, w1, w2):
+    """Same promise for the chained kernel, at the recipe shapes and at
+    every budget eligible2() checks."""
+    import jax.numpy as jnp
+
+    ps = pallas_sampling
+    k1, k2 = -(-w1 // ps.LANES), -(-w2 // ps.LANES)
+    assert ps.eligible2(m, f1, f2, k1, k2)
+    n = 2048
+    a1, a2 = _synthetic_adj(n, w1), _synthetic_adj(n, w2)
+    roots = jnp.asarray(np.arange(m) % n, jnp.int32)
+    h1, h2 = jax.jit(
+        lambda x, y, r: ps.sample_fanout2(
+            x, y, r, jnp.asarray([5, 7]), f1, f2
+        )
+    )(a1, a2, roots)
+    h1, h2 = np.asarray(h1), np.asarray(h2)
+    assert h1.shape == (m, f1) and h2.shape == (m * f1, f2)
+    for h in (h1, h2):
+        assert h.min() >= 0 and h.max() <= n - 1
+    # node i draws among (i+1..i+w) % (n-1): hop 2 really followed hop 1
+    src = h1.reshape(-1)[:, None]
+    delta = (h2 - src) % (n - 1)
+    real = (src != n - 1)[:, 0]
+    assert ((delta[real] >= 1) & (delta[real] <= w2)).all()
+    assert (h2[~real] == n - 1).all()
+
+
+# ---- per-shard kernel on a real mesh (TPU host with >= 4 chips) ----
+
+
+def _assert_matches_host_weights(out, graph, ids, tol=1e-3):
+    nb, w, _, cnt = graph.get_full_neighbor(ids, [0, 1])
+    total = out.shape[1]
+    off = 0
+    for i, c in enumerate(cnt):
+        c = int(c)
+        nbrs, ws = nb[off:off + c], w[off:off + c]
+        off += c
+        if c == 0 or ws.sum() <= 0:
+            assert (out[i] == MAX_ID + 1).all()
+            continue
+        for n_, p in zip(nbrs, ws / ws.sum()):
+            freq = (out[i] == n_).mean()
+            assert abs(freq - p) < 6 * np.sqrt(p * (1 - p) / total) + tol
+
+
+@tpu_mesh
+def test_sharded_kernel_on_a_real_mesh(adj, graph):
+    """The REAL kernel inside shard_map over four chips: every shard's
+    draws match the host engine's weights, and shards draw different
+    sequences for the same node (axis_index folded into the seed)."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
+    ids = np.arange(MAX_ID + 1)
+    nodes = jnp.asarray(np.tile(ids, 4), jnp.int32)     # 17 rows per shard
+    per_call, calls = 128, 16
+    f = jax.jit(
+        lambda n, s: pallas_sampling.sample_neighbor_sharded(
+            adj, n, s, per_call, mesh, "data"
+        )
+    )
+    out = np.concatenate(
+        [np.asarray(f(nodes, jnp.asarray([c, c + 9]))) for c in range(calls)],
+        axis=1,
+    )
+    per_shard = out.reshape(4, len(ids), -1)
+    for shard in per_shard:
+        _assert_matches_host_weights(shard, graph, ids)
+    busy = int(np.argmax([len(set(r.tolist())) for r in per_shard[0]]))
+    assert not (per_shard[0][busy] == per_shard[1][busy]).all()
+    assert not (per_shard[0][busy] == per_shard[2][busy]).all()
+
+
+@tpu_mesh
+def test_chained_sharded_kernel_on_a_real_mesh(adj, graph):
+    """sample_fanout2 per shard over four chips — through
+    device.sample_fanout's own routing under a registered kernel mesh,
+    on the (data, model) mesh layout run_loop builds."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from euler_tpu.graph import device as dg
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("data", "model"))
+    ids = np.arange(MAX_ID + 1)
+    roots = jnp.asarray(np.tile(ids, 2), jnp.int32)     # 17 roots per shard
+    f1, f2, calls = 16, 16, 24
+    with dg.kernel_mesh_scope(mesh):
+        assert dg.kernel_mesh() == (mesh, "data")
+        f = jax.jit(
+            lambda r, k: dg.sample_fanout([adj, adj], r, k, [f1, f2])
+        )
+        assert "tpu_custom_call" in f.lower(
+            roots, jax.random.PRNGKey(0)
+        ).as_text()
+        h1s = []
+        for c in range(calls):
+            r, h1, h2 = f(roots, jax.random.PRNGKey(c))
+            assert h1.shape == (len(roots) * f1,)
+            assert h2.shape == (len(roots) * f1 * f2,)
+            assert int(jnp.max(h2)) <= MAX_ID + 1
+            h1s.append(np.asarray(h1).reshape(len(roots), f1))
+    h1_all = np.concatenate(h1s, axis=1).reshape(2, len(ids), -1)
+    for shard in h1_all:
+        _assert_matches_host_weights(shard, graph, ids)
