@@ -1,0 +1,9 @@
+"""Device time per traced step under the program's ``draw`` scope: the
+key derivation, the routing and the draw kernel (``draw.kernel_ms`` is
+the kernel alone), fullest chip."""
+
+from benchmark import scopes
+
+
+def read(ctx):
+    return scopes.scopes_ms(ctx, "draw")

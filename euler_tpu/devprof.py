@@ -309,8 +309,14 @@ def start_sampler(period_ms: int = 1000) -> None:
 
         def loop():
             while not stop.wait(max(period_ms, 50) / 1000.0):
+                # stamped for the training loop's stall journal: the
+                # census below holds the interpreter lock
                 try:
-                    sample_device_mem()
+                    telemetry.job_tick("eg-devprof-sampler")
+                    try:
+                        sample_device_mem()
+                    finally:
+                        telemetry.job_tick("eg-devprof-sampler", end=True)
                 except Exception:  # pragma: no cover - keep sampling
                     pass
 

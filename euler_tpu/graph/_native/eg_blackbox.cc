@@ -17,6 +17,7 @@
 
 #include "eg_cache.h"
 #include "eg_devprof.h"
+#include "eg_phase.h"
 #include "eg_stats.h"
 
 namespace eg {
@@ -249,7 +250,10 @@ void Blackbox::AppendHistory(const ResourceSample& s) {
 
 void Blackbox::SamplerLoop() {
   while (true) {
+    // stamped for the training loop's stall journal (eg_phase.h)
+    PhaseStats::Global().Tick(kJobBlackboxSampler, false);
     AppendHistory(SampleResources());
+    PhaseStats::Global().Tick(kJobBlackboxSampler, true);
     int ms = sample_ms_.load(std::memory_order_relaxed);
     for (int slept = 0; slept < ms; slept += 50)
       std::this_thread::sleep_for(std::chrono::milliseconds(

@@ -873,6 +873,25 @@ void eg_phase_record(int phase, uint64_t us) {
   EG_API_GUARD()
 }
 
+// A periodic job's tick boundary (eg::PeriodicJob order, mirrored by
+// euler_tpu/telemetry.py PERIODIC_JOBS): end == 0 begins a tick.
+void eg_phase_tick(int job, int end) {
+  try {
+    eg::PhaseStats::Global().Tick(job, end != 0);
+  }
+  EG_API_GUARD()
+}
+
+// Begin/end µs of every job's last tick into out[2 * job_count];
+// returns the job count.
+int eg_phase_ticks(int64_t* out) {
+  try {
+    eg::PhaseStats::Global().Ticks(out);
+    return eg::kJobCount;
+  }
+  EG_API_GUARD(-1)
+}
+
 // One dimensionless prefetch-pipeline sample: which 0 = queue depth at
 // dequeue, 1 = workers busy at dequeue (eg::PrefetchGauge order).
 void eg_phase_gauge(int which, uint64_t value) {
@@ -971,6 +990,21 @@ void eg_telemetry_record_span(int side, int op, int outcome, int shard,
     s.handler_us = handler_us;
     s.wire_us = wire_us;
     s.total_us = total_us;
+    eg::Telemetry::Global().RecordSpan(s);
+  }
+  EG_API_GUARD()
+}
+
+// An app-level span with a detail text and its own end stamp
+// (CLOCK_MONOTONIC µs; 0 = now): what the training loop's stall journal
+// records through the same slowest-N journal (telemetry.py StallJournal).
+void eg_telemetry_record_detail_span(uint64_t total_us, int64_t end_us,
+                                     const char* detail) {
+  try {
+    eg::TelemetrySpan s;
+    s.total_us = total_us;
+    s.end_us = end_us;
+    if (detail) s.detail = detail;
     eg::Telemetry::Global().RecordSpan(s);
   }
   EG_API_GUARD()

@@ -59,6 +59,10 @@ void PhaseStats::Reset() {
   for (auto& b : serve_batch_.buckets)
     b.store(0, std::memory_order_relaxed);
   serve_batch_.total.store(0, std::memory_order_relaxed);
+  for (auto& t : ticks_) {
+    t.begin_us.store(0, std::memory_order_relaxed);
+    t.end_us.store(0, std::memory_order_relaxed);
+  }
 }
 
 void PhaseStats::HistJsonInto(std::string* out, bool* first) const {

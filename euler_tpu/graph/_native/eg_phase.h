@@ -12,8 +12,11 @@
 // Two recorders, both the same lock-free cell shape as eg_telemetry:
 //
 //   * per-phase µs HISTOGRAMS (input_stall / sample / h2d / device /
-//     host / step) — recorded by the Python training loop and prefetch
-//     pipeline through the eg_phase_record ABI;
+//     host / step, the training thread's LEAVES input_other /
+//     dispatch / fence / hook / log_flush / checkpoint / host_other
+//     that tile one iteration, and stall) — recorded by the Python
+//     training loop and prefetch pipeline through the eg_phase_record
+//     ABI;
 //   * prefetch pipeline VALUE histograms (queue depth at dequeue,
 //     workers busy at dequeue) — dimensionless log2 buckets, so
 //     count/sum give dequeues and mean depth and the bucket shape
@@ -48,11 +51,38 @@ enum StepPhase : int {
   kPhaseCompile,         // XLA backend compile (jax.monitoring via
                          // euler_tpu/devprof.py — NOT part of the
                          // step-sum identity; compiles overlap steps)
+  // Leaves of the training thread (OBSERVABILITY.md "Step phases"):
+  // with input_stall and h2d they do not overlap and together cover one
+  // iteration; device = dispatch + fence, host = hook + log_flush +
+  // checkpoint + host_other (telemetry.py PHASE_PARENT).
+  kPhaseInputOther,      // between two bodies, less input_stall
+  kPhaseDispatch,        // the jitted step call, to its return
+  kPhaseFence,           // block_until_ready on the step's loss
+  kPhaseHook,            // step_hook(step)
+  kPhaseLogFlush,        // metric materialisation every log_every steps
+  kPhaseCheckpoint,      // ckpt.save
+  kPhaseHostOther,       // the rest of the host tail
+  kPhaseStall,           // one sample per journalled stall: the step's
+                         // excess over the running median (never a span)
   kPhaseCount,
 };
 
 const char* const kPhaseNames[kPhaseCount] = {
-    "input_stall", "sample", "h2d", "device", "host", "step", "compile",
+    "input_stall", "sample",   "h2d",        "device",
+    "host",        "step",     "compile",    "input_other",
+    "dispatch",    "fence",    "hook",       "log_flush",
+    "checkpoint",  "host_other", "stall",
+};
+
+// The program's periodic jobs. Each stamps the begin and the end of its
+// last tick on CLOCK_MONOTONIC µs (TelemetryNowUs), so the stall journal
+// can say which of them was at work inside a slow step. The Python twin
+// (euler_tpu/telemetry.py PERIODIC_JOBS) indexes by this enum.
+enum PeriodicJob : int {
+  kJobDevprofSampler = 0,  // devprof.py eg-devprof-sampler thread
+  kJobBlackboxSampler,     // eg_blackbox SamplerLoop
+  kJobMetricsEvery,        // run_loop --metrics_every emitter
+  kJobCount,
 };
 
 // Prefetch pipeline gauges recorded as value histograms.
@@ -133,6 +163,26 @@ class PhaseStats {
     c.total.fetch_add(ids, std::memory_order_relaxed);
   }
 
+  // One periodic job's tick boundary: end == 0 stamps the begin of a
+  // tick, end != 0 its end. Not behind the kill-switch's histograms'
+  // cost contract (a job ticks about once a second), but gated all the
+  // same so `telemetry=0` writes nothing.
+  void Tick(int job, bool end) {
+    if (!Telemetry::Global().enabled()) return;
+    if (job < 0 || job >= kJobCount) return;
+    (end ? ticks_[job].end_us : ticks_[job].begin_us)
+        .store(TelemetryNowUs(), std::memory_order_relaxed);
+  }
+
+  // out[2*job] = begin µs, out[2*job+1] = end µs of each job's last
+  // tick (0 = never ticked); `out` holds 2*kJobCount values.
+  void Ticks(int64_t* out) const {
+    for (int j = 0; j < kJobCount; ++j) {
+      out[2 * j] = ticks_[j].begin_us.load(std::memory_order_relaxed);
+      out[2 * j + 1] = ticks_[j].end_us.load(std::memory_order_relaxed);
+    }
+  }
+
   void Reset();
 
   // Append this recorder's series to an in-progress JSON "hist" map
@@ -152,6 +202,12 @@ class PhaseStats {
   Cell gauges_[kGaugeCount] = {};
   Cell serve_[kServePhaseCount] = {};
   Cell serve_batch_ = {};
+
+  struct TickCell {
+    std::atomic<int64_t> begin_us;
+    std::atomic<int64_t> end_us;
+  };
+  TickCell ticks_[kJobCount] = {};
 };
 
 }  // namespace eg

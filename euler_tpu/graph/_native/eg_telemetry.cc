@@ -45,6 +45,21 @@ void AppendKey(std::string* out, const char* k) {
   out->append("\":");
 }
 
+// A caller-supplied text as a JSON string: quotes and backslashes
+// escaped, control bytes dropped.
+void AppendEscaped(std::string* out, const std::string& s) {
+  out->push_back('"');
+  for (char c : s) {
+    if (c == '"' || c == '\\') {
+      out->push_back('\\');
+      out->push_back(c);
+    } else if (static_cast<unsigned char>(c) >= 0x20) {
+      out->push_back(c);
+    }
+  }
+  out->push_back('"');
+}
+
 }  // namespace
 
 uint64_t NextTraceId() {
@@ -320,6 +335,11 @@ std::string Telemetry::Json(int shard, const TelemetryGauges* g) const {
     o.push_back('"');
     o.append(kSpanOutcomeNames[s.outcome < 6 ? s.outcome : 1]);
     o.push_back('"');
+    if (!s.detail.empty()) {
+      o.push_back(',');
+      AppendKey(&o, "detail");
+      AppendEscaped(&o, s.detail);
+    }
     o.push_back('}');
   }
   o.append("]}");

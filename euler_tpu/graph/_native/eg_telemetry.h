@@ -118,6 +118,10 @@ struct TelemetrySpan {
   // exporter (euler_tpu/trace.py) place client and shard spans from
   // different processes on one host onto a single Perfetto timeline.
   int64_t end_us = 0;
+  // App-level detail (the training loop's stall journal): a short JSON
+  // text the dump carries as an escaped string under "detail"; empty on
+  // every transport span.
+  std::string detail;
 };
 
 // Admission-layer gauges carried in the kStats scrape reply (the

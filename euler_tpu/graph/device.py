@@ -987,6 +987,7 @@ def multi_hop_neighbor(adjs, roots, node_caps):
     return hops
 
 
+@jax.named_scope("draw")
 def sample_fanout(adjs, roots, key, counts):
     """Fused multi-hop device fanout (host analog: graph.sample_fanout).
 

@@ -5,11 +5,17 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import threading
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libeuler_graph.so")
 
 _lib = None
+# The first caller builds and loads; a second thread that arrives
+# meanwhile (devprof's sampler ticks a second after start-up) must wait
+# for it, not start a second make over the same objects: the loser of
+# that race dlopen()ed a half-written library ("file too short").
+_lib_lock = threading.Lock()
 
 
 def build_native(force: bool = False) -> str:
@@ -53,6 +59,13 @@ def lib() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
+    with _lib_lock:
+        if _lib is None:
+            _lib = _load()
+    return _lib
+
+
+def _load() -> ctypes.CDLL:
     L = ctypes.CDLL(build_native())
     c = ctypes
     p = c.c_void_p
@@ -77,9 +90,20 @@ def lib() -> ctypes.CDLL:
     _sig(L.eg_counter_name, c.c_char_p, [c.c_int])
     _sig(L.eg_counters_snapshot, None, [u64p])
     _sig(L.eg_counters_reset, None, [])
+    # The per-step recorders are a few relaxed atomic adds: called
+    # through PyDLL they keep the interpreter lock. Through CDLL every
+    # call dropped it, and with prefetch workers waiting for it the
+    # training thread queued behind them a dozen times a step (PERF.md
+    # section 6, PR 27: 80 us a step on the v5e host).
+    held = c.PyDLL(L._name)
+    L._held = held
+    for fn in ("eg_counter_add", "eg_phase_record", "eg_phase_gauge"):
+        setattr(L, fn, getattr(held, fn))
     _sig(L.eg_counter_add, None, [c.c_int, c.c_uint64])
     _sig(L.eg_phase_record, None, [c.c_int, c.c_uint64])
     _sig(L.eg_phase_gauge, None, [c.c_int, c.c_uint64])
+    _sig(L.eg_phase_tick, None, [c.c_int, c.c_int])
+    _sig(L.eg_phase_ticks, c.c_int, [c.POINTER(c.c_int64)])
     _sig(L.eg_serve_record, None, [c.c_int, c.c_uint64])
     _sig(L.eg_serve_batch, None, [c.c_uint64])
     _sig(L.eg_devprof_set_mem, None, [c.c_int64, c.c_int64])
@@ -96,6 +120,8 @@ def lib() -> ctypes.CDLL:
         [c.c_int, c.c_int, c.c_int, c.c_int, c.c_uint64, c.c_uint64,
          c.c_uint64, c.c_uint64, c.c_uint64],
     )
+    _sig(L.eg_telemetry_record_detail_span, None,
+         [c.c_uint64, c.c_int64, c.c_char_p])
     _sig(L.eg_remote_ping, c.c_int, [p, c.c_int])
     _sig(L.eg_remote_scrape, c.c_int, [p, c.c_int, c.c_char_p, c.c_int])
     _sig(L.eg_remote_history, c.c_int, [p, c.c_int, c.c_char_p, c.c_int])
@@ -229,7 +255,6 @@ def lib() -> ctypes.CDLL:
     _sig(L.eg_result_size, c.c_int64, [p, c.c_int, c.c_int])
     _sig(L.eg_result_copy, None, [p, c.c_int, c.c_int, p])
     _sig(L.eg_result_free, None, [p])
-    _lib = L
     return L
 
 

@@ -37,6 +37,7 @@ class ModelOutput:
     metric: Any  # scalar (mrr/acc) or f1 counts [tp, fp, fn]
 
 
+@jax.named_scope("loss")
 def supervised_decoder(logits, labels, sigmoid_loss: bool):
     """Loss + hard predictions (reference models/base.py:207-221)."""
     if sigmoid_loss:
@@ -49,6 +50,7 @@ def supervised_decoder(logits, labels, sigmoid_loss: bool):
     return loss, predictions
 
 
+@jax.named_scope("loss")
 def unsupervised_decoder(emb, emb_pos, emb_negs, xent_loss: bool):
     """Negative-sampling decoder (reference models/base.py:82-95).
 
@@ -77,6 +79,7 @@ def jax_logsumexp(x):
     return jsp.logsumexp(x, axis=2, keepdims=True)
 
 
+@jax.named_scope("loss")
 def shared_negs_decoder(emb, emb_pos, emb_negs, xent_loss: bool):
     """UnsupervisedModelV2-style shared negatives
     (reference models/base.py:152-165): emb_negs [num_negs, d] shared by the
@@ -132,12 +135,13 @@ def gather_consts(feats: dict, consts: dict) -> dict:
         return feats
     feats = dict(feats)
     g = feats["gids"]
-    if "features" in consts:
-        feats["dense"] = consts["features"][g].astype(jnp.float32)
-    if "sparse" in consts and "sparse" not in feats:
-        feats["sparse"] = [
-            (t["ids"][g], t["mask"][g]) for t in consts["sparse"]
-        ]
+    with jax.named_scope("gather_features"):
+        if "features" in consts:
+            feats["dense"] = consts["features"][g].astype(jnp.float32)
+        if "sparse" in consts and "sparse" not in feats:
+            feats["sparse"] = [
+                (t["ids"][g], t["mask"][g]) for t in consts["sparse"]
+            ]
     return feats
 
 
@@ -152,7 +156,8 @@ def lookup_labels(batch: dict, consts: dict, root_ids):
             "device_features=True batch must be applied with "
             "state['consts'] (from Model.init_state)"
         )
-    return consts["labels"][root_ids]
+    with jax.named_scope("gather_labels"):
+        return consts["labels"][root_ids]
 
 
 def resolve_device_features(
@@ -548,7 +553,9 @@ class Model:
         """Pure (state, batch) -> (state, loss, metric); jitted by the
         trainer with params replicated and batch sharded over 'data'. The
         (donated) consts tables pass through unchanged, so XLA aliases
-        their buffers — zero copies per step."""
+        their buffers — zero copies per step. The layer boundaries carry
+        the ``jax.named_scope`` names of ``trace.STEP_SCOPES`` (metadata
+        only: the compiled program is what it was)."""
 
         def train_step(state, batch):
             consts = state.get("consts")
@@ -560,10 +567,11 @@ class Model:
             (loss, out), grads = jax.value_and_grad(
                 loss_fn, has_aux=True
             )(state["params"])
-            updates, opt_state = optimizer.update(
-                grads, state["opt_state"], state["params"]
-            )
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    grads, state["opt_state"], state["params"]
+                )
+                params = optax.apply_updates(state["params"], updates)
             new_state = {"params": params, "opt_state": opt_state}
             if consts:
                 new_state["consts"] = consts

@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import flax.linen as nn
+import jax
 import numpy as np
 
 from euler_tpu.models import base
@@ -54,15 +55,14 @@ class _SupervisedSageModule(nn.Module):
         on device from the HBM-resident adjacency ("roots" + "seed")."""
         if "hops" in batch:
             return batch["hops"]
-        import jax
-
         from euler_tpu.graph import device as device_graph
 
-        key = jax.random.PRNGKey(batch["seed"][0])
-        adjs = [consts["adj"][k] for k in self.hop_adj_keys]
-        ids = device_graph.sample_fanout(
-            adjs, batch["roots"], key, list(self.fanouts)
-        )
+        with jax.named_scope("draw"):
+            key = jax.random.PRNGKey(batch["seed"][0])
+            adjs = [consts["adj"][k] for k in self.hop_adj_keys]
+            ids = device_graph.sample_fanout(
+                adjs, batch["roots"], key, list(self.fanouts)
+            )
         if self.max_id >= 0:  # use_id: the gids double as embedding ids
             return [{"gids": i, "ids": i} for i in ids]
         return [{"gids": i} for i in ids]
@@ -79,16 +79,19 @@ class _SupervisedSageModule(nn.Module):
     def __call__(self, batch, consts=None):
         hops = self._hops(batch, consts)
         embedding = self._embed_hops(hops, consts)
-        logits = self.predict(embedding)
+        with jax.named_scope("dense"):
+            logits = self.predict(embedding)
         labels = base.lookup_labels(batch, consts, hops[0].get("gids"))
         loss, predictions = base.supervised_decoder(
             logits, labels, self.sigmoid_loss
         )
+        with jax.named_scope("loss"):
+            metric = metrics.f1_counts(labels, predictions)
         return base.ModelOutput(
             embedding=embedding,
             loss=loss,
             metric_name="f1",
-            metric=metrics.f1_counts(labels, predictions),
+            metric=metric,
         )
 
 

@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from euler_tpu.nn.layers import Dense
@@ -22,8 +23,10 @@ class GCNAggregator(nn.Module):
     @nn.compact
     def __call__(self, inputs):
         self_emb, neigh_emb = inputs
-        all_emb = jnp.concatenate([self_emb[:, None, :], neigh_emb], axis=1)
-        agg = all_emb.mean(axis=1)
+        with jax.named_scope("aggregate"):
+            all_emb = jnp.concatenate(
+                [self_emb[:, None, :], neigh_emb], axis=1)
+            agg = all_emb.mean(axis=1)
         return Dense(self.dim, self.activation, use_bias=False)(agg)
 
 
@@ -43,12 +46,14 @@ class _BaseAggregator(nn.Module):
             if dim % 2:
                 raise ValueError("dim must be even when concat=True")
             dim //= 2
-        agg = self.aggregate(neigh_emb)
-        from_self = Dense(dim, self.activation, use_bias=False)(self_emb)
-        from_neigh = Dense(dim, self.activation, use_bias=False)(agg)
-        if self.concat:
-            return jnp.concatenate([from_self, from_neigh], axis=1)
-        return from_self + from_neigh
+        # the Dense layers inside carry their own scope, "dense"
+        with jax.named_scope("aggregate"):
+            agg = self.aggregate(neigh_emb)
+            from_self = Dense(dim, self.activation, use_bias=False)(self_emb)
+            from_neigh = Dense(dim, self.activation, use_bias=False)(agg)
+            if self.concat:
+                return jnp.concatenate([from_self, from_neigh], axis=1)
+            return from_self + from_neigh
 
 
 class MeanAggregator(_BaseAggregator):

@@ -22,6 +22,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from euler_tpu.nn import aggregators as dense_aggs
@@ -106,7 +107,9 @@ class SageEncoder(nn.Module):
             next_hidden = []
             for hop in range(num_layers - layer):
                 d = hidden[hop].shape[-1]
-                neigh = hidden[hop + 1].reshape(-1, self.fanouts[hop], d)
+                with jax.named_scope("aggregate"):
+                    neigh = hidden[hop + 1].reshape(
+                        -1, self.fanouts[hop], d)
                 next_hidden.append(aggs[layer]((hidden[hop], neigh)))
             hidden = next_hidden
         return hidden[0]
@@ -267,7 +270,8 @@ class ScalableSageEncoder(nn.Module):
                    else {"concat": self.concat}),
             )
             d = node_emb.shape[-1]
-            neigh = neigh_emb.reshape(-1, self.fanout, d)
+            with jax.named_scope("aggregate"):
+                neigh = neigh_emb.reshape(-1, self.fanout, d)
             node_emb = agg((node_emb, neigh))
             node_embeddings.append(node_emb)
             if layer < self.num_layers - 1:

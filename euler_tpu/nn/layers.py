@@ -11,6 +11,7 @@ from __future__ import annotations
 from typing import Callable, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 
@@ -21,9 +22,10 @@ class Dense(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        y = nn.Dense(self.dim, use_bias=self.use_bias)(x)
-        if self.activation is not None:
-            y = self.activation(y)
+        with jax.named_scope("dense"):
+            y = nn.Dense(self.dim, use_bias=self.use_bias)(x)
+            if self.activation is not None:
+                y = self.activation(y)
         return y
 
 

@@ -34,6 +34,12 @@ import time
 from typing import Callable, Iterator
 
 
+def _now_us() -> int:
+    """CLOCK_MONOTONIC µs: the clock of every span's length and place
+    (trace.now_us, without importing the telemetry stack)."""
+    return time.monotonic_ns() // 1000
+
+
 def _profiler():
     """(record_phase, record_gauges, counter_add) when the telemetry
     stack is importable and enabled, else None — prefetch() stays
@@ -62,6 +68,7 @@ def prefetch(
     worker_init: Callable[[int], None] | None = None,
     profile: bool | None = None,
     record_sample: bool = True,
+    stall_out: list | None = None,
 ) -> Iterator[dict]:
     """Yield num_steps batches for steps start..start+num_steps, produced
     ahead of time by worker threads.
@@ -73,7 +80,10 @@ def prefetch(
 
     profile=None enables step-phase recording iff telemetry is enabled
     (the `telemetry=0` kill-switch reaches here too); False forces the
-    zero-instrumentation path.
+    zero-instrumentation path. While recording, the consumer writes the
+    CLOCK_MONOTONIC µs at which each step's queue wait began and ended
+    into ``stall_out[0:2]`` (train() places its ``input_other`` leaf on
+    both sides of that wait).
     """
     prof = _profiler() if profile in (None, True) else None
     if start:
@@ -86,14 +96,17 @@ def prefetch(
         if worker_init is not None:
             worker_init(0)
         for step in range(num_steps):
-            t0 = time.perf_counter()
+            t0 = _now_us() if prof is not None else 0
             batch = make_batch(step)
             if prof is not None:
-                dur_us = (time.perf_counter() - t0) * 1e6
+                t1 = _now_us()
                 record, gauges, count = prof
                 if record_sample:
-                    record("sample", dur_us, step=step + start)
-                record("input_stall", dur_us, step=step + start)
+                    record("sample", t1 - t0, step=step + start, end_us=t1)
+                record("input_stall", t1 - t0, step=step + start,
+                       end_us=t1)
+                if stall_out is not None:
+                    stall_out[0], stall_out[1] = t0, t1
                 gauges(0, 0)
                 count("prefetch_produced")
             yield batch
@@ -137,7 +150,7 @@ def prefetch(
                     return
                 next_step[0] = step + 1
                 busy[0] += 1
-            t0 = time.perf_counter()
+            t0 = _now_us()
             try:
                 batch = make_batch(step)
             except Exception as e:  # surface errors to the consumer
@@ -148,10 +161,7 @@ def prefetch(
                     try:
                         from euler_tpu.telemetry import record_span
 
-                        record_span(
-                            int((time.perf_counter() - t0) * 1e6),
-                            outcome=1,
-                        )
+                        record_span(_now_us() - t0, outcome=1)
                     except Exception:
                         pass
                 with cv:
@@ -160,10 +170,9 @@ def prefetch(
                 return
             if prof is not None:
                 if record_sample:
-                    prof[0](
-                        "sample", (time.perf_counter() - t0) * 1e6,
-                        step=step + start,
-                    )
+                    t1 = _now_us()
+                    prof[0]("sample", t1 - t0, step=step + start,
+                            end_us=t1)
                 prof[2]("prefetch_produced")
             with cv:
                 busy[0] -= 1
@@ -180,17 +189,17 @@ def prefetch(
     pending: dict[int, object] = {}
     try:
         for want in range(num_steps):
-            t_wait = time.perf_counter()
+            t_wait = _now_us() if prof is not None else 0
             while want not in pending:
                 step, item = out.get()
                 pending[step] = item
             if prof is not None:
                 record, gauges, _ = prof
-                record(
-                    "input_stall",
-                    (time.perf_counter() - t_wait) * 1e6,
-                    step=want + start,
-                )
+                t_got = _now_us()
+                record("input_stall", t_got - t_wait, step=want + start,
+                       end_us=t_got)
+                if stall_out is not None:
+                    stall_out[0], stall_out[1] = t_wait, t_got
                 # ready batches beyond the one about to be consumed
                 gauges(out.qsize() + len(pending) - 1, busy[0])
             item = pending.pop(want)
@@ -233,6 +242,7 @@ def pipeline(
     worker_init: Callable[[int], None] | None = None,
     profile: bool | None = None,
     record_sample: bool = True,
+    stall_out: list | None = None,
 ) -> Iterator[dict]:
     """Depth-N in-flight step ring over a SPLIT sampler (train.py
     ``sampler_depth=``): yield num_steps batches for steps
@@ -250,7 +260,8 @@ def pipeline(
     instrumentation contract as prefetch — the consumer loop, the
     ``input_stall`` histogram, the ``eg_prefetch_*`` gauges (queue depth
     + in-flight submits), and the produced/dropped/worker-error counters
-    all read identically, so train()'s consumer side is unchanged.
+    all read identically, so train()'s consumer side is unchanged
+    (``stall_out`` as in prefetch).
 
     Exceptions from either fn surface at the consumer's matching step,
     like prefetch; pending tokens submitted after a failure are dropped
@@ -301,15 +312,14 @@ def pipeline(
                     step += 1
                     busy[0] = len(inflight)
                 cur, pending = inflight.popleft()
-                t0 = time.perf_counter()
+                t0 = _now_us()
                 batch = finish_fn(cur, pending)
                 busy[0] = len(inflight)
                 if prof is not None:
                     if record_sample:
-                        prof[0](
-                            "sample", (time.perf_counter() - t0) * 1e6,
-                            step=cur + start,
-                        )
+                        t1 = _now_us()
+                        prof[0]("sample", t1 - t0, step=cur + start,
+                                end_us=t1)
                     prof[2]("prefetch_produced")
                 if not put(cur, batch):
                     return
@@ -330,15 +340,15 @@ def pipeline(
     t.start()
     try:
         for want in range(num_steps):
-            t_wait = time.perf_counter()
+            t_wait = _now_us() if prof is not None else 0
             _, item = out.get()  # driver produces strictly in order
             if prof is not None:
                 record, gauges, _ = prof
-                record(
-                    "input_stall",
-                    (time.perf_counter() - t_wait) * 1e6,
-                    step=want + start,
-                )
+                t_got = _now_us()
+                record("input_stall", t_got - t_wait, step=want + start,
+                       end_us=t_got)
+                if stall_out is not None:
+                    stall_out[0], stall_out[1] = t_wait, t_got
                 gauges(out.qsize(), busy[0])
             if isinstance(item, Exception):
                 raise item

@@ -735,7 +735,7 @@ def run_train(model, graph, args, mesh):
 
     step_hook = None
     if args.metrics_every > 0:
-        from euler_tpu.telemetry import append_metrics_line
+        from euler_tpu.telemetry import append_metrics_line, job_tick
 
         metrics_path = args.metrics_file or os.path.join(
             args.model_dir or ".", "metrics.jsonl"
@@ -744,7 +744,9 @@ def run_train(model, graph, args, mesh):
 
         def step_hook(step, _path=metrics_path):
             if step % args.metrics_every == 0:
+                job_tick("metrics_every")
                 append_metrics_line(_path, step)
+                job_tick("metrics_every", end=True)
 
     recorder = None
     if args.trace_file:

@@ -15,9 +15,11 @@ admission gauges. This module is the Python half:
 
 plus the step-phase profiler surface (native eg_phase.{h,cc}): the
 training loop and prefetch pipeline record per-step phase timers
-(input_stall / sample / h2d / device / host / step) and prefetch
-pipeline gauges through :func:`record_phase` /
-:func:`record_prefetch_gauges`; they land in the same native "hist" map
+(input_stall / sample / h2d / device / host / step, and the training
+thread's leaves of :data:`PHASE_PARENT`) and prefetch pipeline gauges
+through :func:`record_phase` / :func:`record_prefetch_gauges`, and
+:class:`StallJournal` journals the steps that took several times their
+median; they land in the same native "hist" map
 as the RPC latency histograms, so metrics_text(), snapshot(), the STATS
 scrape, and scripts/metrics_dump.py all report them with one renderer
 (OBSERVABILITY.md "Step phases"), and the percentile/bucket arithmetic
@@ -28,8 +30,13 @@ JSONL emitter used by run_loop.
 from __future__ import annotations
 
 import ctypes
+import gc
 import json
+import resource
+import statistics
+import threading
 import time
+from collections import deque
 
 from euler_tpu.graph.native import lib
 
@@ -42,7 +49,25 @@ NUM_BUCKETS = 28
 # "compile" is the device-plane add-on (euler_tpu/devprof.py): XLA
 # backend compile wall time, NOT part of the step-sum identity.
 PHASES = ("input_stall", "sample", "h2d", "device", "host", "step",
-          "compile")
+          "compile", "input_other", "dispatch", "fence", "hook",
+          "log_flush", "checkpoint", "host_other", "stall")
+_PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
+
+# The training thread's LEAVES (input_stall, input_other, h2d when it
+# runs there, and the keys below) do not overlap and together cover one
+# iteration; a parent is the sum of its leaves, kept as a histogram only
+# (OBSERVABILITY.md "Step phases"). Static, by name: a trace event needs
+# no parent field.
+PHASE_PARENT = {
+    "dispatch": "device", "fence": "device",
+    "hook": "host", "log_flush": "host", "checkpoint": "host",
+    "host_other": "host",
+}
+
+# The program's periodic jobs — MUST match eg_phase.h PeriodicJob. Each
+# stamps its last tick through :func:`job_tick`; the stall journal names
+# those that ticked inside a slow step.
+PERIODIC_JOBS = ("eg-devprof-sampler", "blackbox-sampler", "metrics_every")
 
 # Serve-request phase order — MUST match eg_phase.h ServePhase (the
 # serving layer records by index through the eg_serve_record ABI,
@@ -170,8 +195,9 @@ def set_slow_capacity(n: int) -> None:
 # ---------------------------------------------------------------------------
 
 # Optional per-event sink the trace recorder (euler_tpu/trace.py)
-# registers: fn(phase, us, step) called on every record_phase while a
-# trace capture is active. None (the default) costs one global read.
+# registers: fn(phase, us, step, end_us) called on every record_phase
+# while a trace capture is active. None (the default) costs one global
+# read.
 _trace_sink = None
 
 
@@ -182,15 +208,53 @@ def set_trace_sink(fn) -> None:
     _trace_sink = fn
 
 
-def record_phase(phase: str, us: float, step: int | None = None) -> None:
+def record_phase(phase: str, us: float, step: int | None = None,
+                 end_us: int | None = None) -> None:
     """One step-phase µs sample (train loop / prefetch pipeline call
     sites). Lands in the ``phase:<name>`` histogram of
     :func:`telemetry_json` (kill-switch honored natively) and, while a
-    trace capture is active, in the trace recorder's event buffer."""
-    lib().eg_phase_record(PHASES.index(phase), max(int(us), 0))
+    trace capture is active, in the trace recorder's event buffer.
+    ``end_us`` is the CLOCK_MONOTONIC µs reading taken where the span
+    ended (the clock ``us`` was measured on); without it the recorder
+    places the span as ending when it was recorded."""
+    lib().eg_phase_record(_PHASE_INDEX[phase], max(int(us), 0))
     sink = _trace_sink
     if sink is not None:
-        sink(phase, us, step)
+        sink(phase, us, step, end_us)
+
+
+def record_phase_hist(phase: str, us: float) -> None:
+    """A histogram-only sample: a parent of :data:`PHASE_PARENT` (the sum
+    of leaves that reached the sink themselves), a leaf whose span went
+    out in pieces (:func:`record_phase_span`), or ``stall``."""
+    lib().eg_phase_record(_PHASE_INDEX[phase], max(int(us), 0))
+
+
+def record_phase_span(phase: str, start_us: int, end_us: int,
+                      step: int | None = None) -> None:
+    """A trace-sink-only span [start_us, end_us) on CLOCK_MONOTONIC: one
+    piece of a leaf that another leaf interrupts (``input_other`` lies on
+    both sides of ``input_stall``); its histogram sample is recorded once,
+    with :func:`record_phase_hist`."""
+    sink = _trace_sink
+    if sink is not None and end_us > start_us:
+        sink(phase, end_us - start_us, step, end_us)
+
+
+def job_tick(job: str, end: bool = False) -> None:
+    """Stamp the begin (or, with ``end``, the end) of one tick of a
+    periodic job of :data:`PERIODIC_JOBS` on CLOCK_MONOTONIC."""
+    lib().eg_phase_tick(PERIODIC_JOBS.index(job), 1 if end else 0)
+
+
+def job_ticks() -> dict:
+    """{job: (begin_us, end_us)} of every periodic job's last tick
+    (0 = never)."""
+    n = len(PERIODIC_JOBS)
+    buf = (ctypes.c_int64 * (2 * n))()
+    lib().eg_phase_ticks(buf)
+    return {job: (buf[2 * i], buf[2 * i + 1])
+            for i, job in enumerate(PERIODIC_JOBS)}
 
 
 def record_prefetch_gauges(queue_depth: int, workers_busy: int) -> None:
@@ -261,7 +325,138 @@ def slow_spans(graph=None, shard: int | None = None) -> list:
     spans = data["slow_spans"]
     for s in spans:
         s["trace"] = int(s["trace"])
+        if "detail" in s:  # an app-level span's JSON text (StallJournal)
+            s["detail"] = json.loads(s["detail"])
     return spans
+
+
+# ---------------------------------------------------------------------------
+# stall journal (OBSERVABILITY.md "Step phases": "Stall journal")
+# ---------------------------------------------------------------------------
+
+
+class StallJournal:
+    """Journals the training thread's steps that took over
+    max(FACTOR x the running median step, FLOOR_US), with what can tell
+    the causes apart: the leaf that held the excess, the thread's CPU
+    time and context switches, the collections the Python collector ran
+    inside the step, and the periodic jobs that ticked inside it.
+    Entries go to the slow-span journal (:func:`slow_spans`,
+    ``detail["kind"] == "train_stall"``; :func:`stall_journal`), one
+    sample of the excess to the ``stall`` histogram.
+
+    The thread's CPU clock and ``getrusage`` are system calls (a dozen µs
+    each on a sandboxed host), so they are read once per REFRESH steps
+    and at the end of a journalled step: an entry's ``cpu_us`` / ``vcsw``
+    / ``ivcsw`` are over the ``since_steps`` steps since the last reading
+    (the stalled one is the last of them), beside what a step usually
+    takes of each (``usual_*``, per step, over the stretch before).
+
+    Owned by one ``train()`` call, used from its thread only; the
+    collector's callbacks may run on any thread (a collection holds the
+    interpreter lock, so it pauses the training thread wherever it
+    runs). ``close()`` removes the callback."""
+
+    FACTOR = 5
+    FLOOR_US = 50_000
+    WINDOW = 128      # steps the running median is over
+    REFRESH = 32      # steps between two readings of median and thread
+    FIRST = 8         # no median, and no entry, before this many steps
+
+    def __init__(self):
+        self._recent: deque = deque(maxlen=self.WINDOW)
+        self._n = 0
+        self._median_us = 0
+        self._limit_us = None
+        self._typical: dict = {}
+        self._gc: list = []
+        self._gc_t0 = 0
+        self._read_n = 0
+        self._read = self._thread_stats()
+        self._usual = (0.0, 0.0, 0.0)
+        gc.callbacks.append(self._on_gc)
+
+    def close(self) -> None:
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    @staticmethod
+    def _thread_stats():
+        ru = resource.getrusage(resource.RUSAGE_THREAD)
+        return time.thread_time_ns() // 1000, ru.ru_nvcsw, ru.ru_nivcsw
+
+    def _on_gc(self, phase, info):
+        if phase == "start":
+            self._gc_t0 = time.monotonic_ns()
+        else:
+            self._gc.append((
+                info["generation"],
+                (time.monotonic_ns() - self._gc_t0) // 1000,
+                threading.current_thread().name,
+            ))
+
+    def step(self, step: int, start_us: int, end_us: int,
+             leaves: dict) -> None:
+        """One finished step: [start_us, end_us) on CLOCK_MONOTONIC and
+        the µs of each of its leaves."""
+        dur = end_us - start_us
+        self._n += 1
+        if self._limit_us is not None and dur > self._limit_us:
+            self._journal(step, start_us, end_us, leaves)
+        else:
+            self._typical = leaves
+        if self._gc:
+            self._gc = []
+        self._recent.append(dur)
+        if self._n == self.FIRST or self._n % self.REFRESH == 0:
+            self._median_us = int(statistics.median(self._recent))
+            self._limit_us = max(self.FACTOR * self._median_us,
+                                 self.FLOOR_US)
+            steps = self._n - self._read_n
+            if steps:  # (none where this very step was journalled)
+                now = self._thread_stats()
+                self._usual = tuple(
+                    (a - b) / steps for a, b in zip(now, self._read))
+                self._read, self._read_n = now, self._n
+
+    def _journal(self, step, start_us, end_us, leaves):
+        dur = end_us - start_us
+        excess = dur - self._median_us
+        leaf = max(leaves, key=lambda k: leaves[k] - self._typical.get(k, 0))
+        ticked = []
+        for job, (t0, t1) in job_ticks().items():
+            if t0 and t0 <= end_us and (t1 < t0 or t1 >= start_us):
+                ticked.append(job)
+        now = self._thread_stats()
+        cpu_us, vcsw, ivcsw = (a - b for a, b in zip(now, self._read))
+        detail = {
+            "kind": "train_stall", "step": step, "median_us": self._median_us,
+            "excess_us": excess, "leaf": leaf, "leaf_us": leaves[leaf],
+            "leaf_typical_us": self._typical.get(leaf, 0),
+            "since_steps": self._n - self._read_n,
+            "cpu_us": cpu_us, "vcsw": vcsw, "ivcsw": ivcsw,
+            "usual_cpu_us": round(self._usual[0], 1),
+            "usual_vcsw": round(self._usual[1], 2),
+            "usual_ivcsw": round(self._usual[2], 2),
+            "gc": [list(c) for c in self._gc], "ticks": ticked,
+        }
+        lib().eg_telemetry_record_detail_span(
+            dur, end_us, json.dumps(detail, separators=(",", ":")).encode())
+        record_phase_hist("stall", excess)
+        # the next entry's stretch starts here
+        self._read, self._read_n = now, self._n
+
+
+def stall_journal() -> list:
+    """The ``train_stall`` entries of this process's slow-span journal,
+    slowest first: each the span's ``total_us``/``end_us`` over the
+    fields of :class:`StallJournal`'s detail."""
+    return [
+        {"total_us": s["total_us"], "end_us": s["end_us"], **s["detail"]}
+        for s in slow_spans()
+        if isinstance(s.get("detail"), dict)
+        and s["detail"].get("kind") == "train_stall"
+    ]
 
 
 # ---------------------------------------------------------------------------
@@ -285,7 +480,8 @@ _HIST_FAMILIES = {
                 "Retry backoff sleeps, microseconds", "op"),
     "phase": ("eg_step_phase_us",
               "Training step-phase wall time (input_stall/sample/h2d/"
-              "device/host/step, plus XLA compile), microseconds",
+              "device/host/step and the training thread's leaves, plus "
+              "XLA compile and journalled stall excess), microseconds",
               "phase"),
     "prefetch_depth": ("eg_prefetch_queue_depth",
                        "Ready batches in the prefetch queue at consumer "

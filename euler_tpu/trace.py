@@ -53,6 +53,21 @@ PID_DEVICE_BASE = 200  # jax.profiler device lanes render from pid 200
 # for the offset exactly instead of guessing from wall clocks.
 ALIGN_PREFIX = "eg_align:"
 
+# The ``jax.named_scope`` names at the layer boundaries of the jitted
+# train step (OBSERVABILITY.md "Device plane": "Step scopes"). Every
+# device op of the step carries the scope it was traced under in its HLO
+# ``op_name`` (the backward pass as ``transpose(jvp(...))/<scope>/...``),
+# which is how a capture's device time is split by what the program was
+# doing and not by XLA's instruction names. The benchmark keeps its own
+# copy (benchmark/scopes.py); a test pins the two equal.
+STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
+               "dense", "loss", "optimizer")
+
+# File ``train(profile_dir=)`` leaves the compiled step's HLO text in,
+# beside the capture: the map from a trace event's instruction name to
+# its scope.
+STEP_HLO_FILE = "train_step.hlo.txt"
+
 
 def now_us() -> int:
     """CLOCK_MONOTONIC µs — the exporter's one clock (matches the
@@ -64,9 +79,13 @@ class TraceRecorder:
     """Bounded in-memory buffer of step-phase events.
 
     ``start()`` registers the recorder as the telemetry phase sink;
-    every ``record_phase(phase, us, step)`` anywhere in the process
-    (train loop, prefetch consumer, prefetch workers) then lands here
-    with its thread identity, until ``stop()``. The buffer is a ring:
+    every ``record_phase(phase, us, step, end_us)`` anywhere in the
+    process (train loop, prefetch consumer, prefetch workers) then lands
+    here with its thread identity, until ``stop()``: placed to end at
+    the caller's ``end_us`` stamp (CLOCK_MONOTONIC µs, read where the
+    span was measured), or now when the caller gave none. Events are
+    (phase, start_us, dur_us, step, thread) tuples; a leaf's parent is
+    ``telemetry.PHASE_PARENT[phase]``. The buffer is a ring:
     beyond ``capacity`` events the oldest fall off (``dropped`` counts
     them) — a week-long run cannot OOM the trainer."""
 
@@ -85,8 +104,9 @@ class TraceRecorder:
         if _telemetry._trace_sink is self._on_phase:
             _telemetry.set_trace_sink(None)
 
-    def _on_phase(self, phase: str, us: float, step: int | None) -> None:
-        end = now_us()
+    def _on_phase(self, phase: str, us: float, step: int | None,
+                  end_us: int | None = None) -> None:
+        end = now_us() if end_us is None else end_us
         with self._lock:
             if len(self._events) == self._events.maxlen:
                 self.dropped += 1
@@ -194,6 +214,26 @@ def align_annotation(monotonic_us: int | None = None):
     return jax.profiler.TraceAnnotation(
         f"{ALIGN_PREFIX}{monotonic_us if monotonic_us is not None else now_us()}"
     )
+
+
+def stamp_alignment() -> int:
+    """Stamp the alignment marker into the ``jax.profiler`` capture that
+    has just started, and return the µs the stamp is good to: the time
+    between the clock reading the marker's name carries and the reading
+    after the marker closed (the marker's own start lies between them).
+
+    The first annotation after ``start_trace`` pays the tracer's set-up
+    (about a millisecond on the v5e host, PERF.md PR 27, which put every
+    host span that much late against the device lanes), so a throwaway
+    one goes first."""
+    import jax
+
+    with jax.profiler.TraceAnnotation("eg_align_warmup"):
+        pass
+    t0 = now_us()
+    with align_annotation(t0):
+        pass
+    return now_us() - t0
 
 
 def _latest_profiler_trace(profile_dir: str) -> str | None:

@@ -9,9 +9,11 @@ pipeline is the host sampler behind a prefetch queue.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import inspect
 import logging
+import os
 import time
 from typing import Callable, Optional
 
@@ -71,6 +73,39 @@ def _metric_zero(name: str):
     return np.zeros(2)
 
 
+@contextlib.contextmanager
+def _cache_keyed_with_metadata():
+    """Compile under a persistent-cache key that includes the program's
+    metadata. The usual key strips it, so a hit may hand back an
+    executable another commit compiled: the same code under that commit's
+    ``op_name`` paths and, since XLA names instructions after them, that
+    commit's instruction names. A profiled run has to execute, and read
+    the text of, a program that carries its own scopes."""
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    jax.config.update(flag, True)
+    try:
+        yield
+    finally:
+        jax.config.update(flag, before)
+
+
+def write_step_hlo(step_fn, state, batch, profile_dir: str) -> None:
+    """Leave the compiled train step's HLO text in
+    ``<profile_dir>/trace.STEP_HLO_FILE``: every instruction with the
+    ``op_name`` it was traced under, which is how a reader of the capture
+    gets from a device op (the capture names it by its instruction) to
+    its ``trace.STEP_SCOPES`` scope. A compile-cache hit: the profiled
+    run's first step compiled this program under the same key."""
+    from euler_tpu.trace import STEP_HLO_FILE
+
+    with _cache_keyed_with_metadata():
+        text = step_fn.lower(state, batch).compile().as_text()
+    os.makedirs(profile_dir, exist_ok=True)
+    with open(os.path.join(profile_dir, STEP_HLO_FILE), "w") as f:
+        f.write(text)
+
+
 def _kernel_mesh_scoped(fn):
     """Run ``fn`` with its ``mesh`` argument (default: every device)
     registered for per-shard Pallas draws (device.kernel_mesh_scope),
@@ -127,9 +162,14 @@ def train(
     block_until_ready — attribution needs the fence, so async dispatch
     no longer runs ahead; host sampling still overlaps through the
     prefetch workers), host (optimizer/bookkeeping tail), and the
-    whole-step wall. None (default) follows the telemetry kill-switch:
-    profiling on when telemetry is on, and `telemetry=0` restores the
-    fully-async unfenced loop.
+    whole-step wall. On this thread the leaves input_stall, input_other,
+    h2d, dispatch, fence, hook, log_flush, checkpoint and host_other
+    tile every iteration on one clock (device = dispatch + fence and
+    host = the last four are kept as histograms), and a StallJournal
+    journals the steps that took several times the running median. None
+    (default) follows the telemetry kill-switch: profiling on when
+    telemetry is on, and `telemetry=0` restores the fully-async
+    unfenced loop with none of this.
 
     source_fn(step) -> int64 root-node batch (fixed size, divisible by the
     mesh size). All sampling runs in the prefetch workers.
@@ -166,9 +206,11 @@ def train(
     checkpoint_dir enables MonitoredTrainingSession-style periodic save +
     resume-from-latest (reference run_loop.py:132-138); profile_dir captures
     a JAX profiler trace over profile_steps (the reference's ProfilerHook,
-    run_loop.py:124-126). Note with device_prefetch the copies for the
-    first ~prefetch_depth profiled steps were issued before the trace
-    starts and won't appear in it.
+    run_loop.py:124-126), and leaves the compiled step's HLO text beside
+    it (trace.STEP_HLO_FILE: the map from a device op of the capture to
+    the named scope it was traced under). Note with device_prefetch the
+    copies for the first ~prefetch_depth profiled steps were issued
+    before the trace starts and won't appear in it.
     """
     n_mesh_devices = int(np.prod(mesh.devices.shape))
     cpu_virtual_mesh = (
@@ -229,8 +271,30 @@ def train(
             phase_profile = telemetry_enabled()
         except Exception:
             phase_profile = False
+    stall_out = journal = None
     if phase_profile:
-        from euler_tpu.telemetry import record_phase
+        from euler_tpu.telemetry import (
+            StallJournal,
+            record_phase,
+            record_phase_hist,
+            record_phase_span,
+        )
+
+        from euler_tpu.trace import now_us as clock
+
+        # `clock` (CLOCK_MONOTONIC µs) is the one clock of every span
+        # here: a span's length and its place come from the same readings
+        stall_out = [0, 0]  # the consumer's queue wait, by prefetch
+        journal = StallJournal()
+
+        def leaf(name, start_us, step, leaves):
+            """Close this thread's leaf ``name`` that began at
+            ``start_us``: one reading is its end and the next leaf's
+            start. Returns it."""
+            end_us = clock()
+            leaves[name] = end_us - start_us
+            record_phase(name, end_us - start_us, step=step, end_us=end_us)
+            return end_us
     if device_prefetch and cpu_virtual_mesh:
         # XLA's CPU multi-device backend shares one in-process communicator:
         # device_put issued from prefetch worker threads can starve a
@@ -244,23 +308,28 @@ def train(
         # With device_prefetch, device_put runs here inside the prefetch
         # worker, so the host->device copy of batch k+1 overlaps device
         # compute of step k (the copy releases the GIL).
-        t0 = time.perf_counter()
-        batch = model.sample(graph, source_fn(step))
         if not phase_profile:
+            batch = model.sample(graph, source_fn(step))
             if device_prefetch:
                 batch = shard_batch(batch, mesh)
                 devprof.count_h2d(batch)
             return batch
         # prefetch applies the start offset before calling: step is
         # already the absolute step index here
-        t1 = time.perf_counter()
-        record_phase("sample", (t1 - t0) * 1e6, step=step)
+        t0 = clock()
+        batch = model.sample(graph, source_fn(step))
+        return staged(batch, step, t0)
+
+    def staged(batch, step, t0):
+        """A worker's spans of one produced batch: sample from t0, then
+        (with device_prefetch) its h2d."""
+        t1 = clock()
+        record_phase("sample", t1 - t0, step=step, end_us=t1)
         if device_prefetch:
             batch = shard_batch(batch, mesh)
             devprof.count_h2d(batch)
-            record_phase(
-                "h2d", (time.perf_counter() - t1) * 1e6, step=step
-            )
+            t2 = clock()
+            record_phase("h2d", t2 - t1, step=step, end_us=t2)
         return batch
 
     # Native async pipeline (remote graphs only): start_batch submits the
@@ -277,22 +346,15 @@ def train(
         return model.sample_start(graph, source_fn(step))
 
     def finish_batch(step, pending):
-        t0 = time.perf_counter()
-        batch = model.sample_finish(graph, pending)
         if not phase_profile:
+            batch = model.sample_finish(graph, pending)
             if device_prefetch:
                 batch = shard_batch(batch, mesh)
                 devprof.count_h2d(batch)
             return batch
-        t1 = time.perf_counter()
-        record_phase("sample", (t1 - t0) * 1e6, step=step)
-        if device_prefetch:
-            batch = shard_batch(batch, mesh)
-            devprof.count_h2d(batch)
-            record_phase(
-                "h2d", (time.perf_counter() - t1) * 1e6, step=step
-            )
-        return batch
+        t0 = clock()
+        batch = model.sample_finish(graph, pending)
+        return staged(batch, step, t0)
 
     name = model.metric_name
     history = []
@@ -337,7 +399,9 @@ def train(
         )
 
     profiling = False
-    t_step = time.perf_counter()
+    trace_began = None  # the step in which the profiler was started
+    if phase_profile:
+        t_step = clock()
     if use_pipeline:
         batches = pipeline(
             start_batch,
@@ -348,6 +412,7 @@ def train(
             worker_init=seed_worker,
             profile=phase_profile,
             record_sample=False,  # finish_batch above records sample/h2d
+            stall_out=stall_out,
         )
     else:
         batches = prefetch(
@@ -359,65 +424,108 @@ def train(
             worker_init=seed_worker,
             profile=phase_profile,
             record_sample=False,  # make_batch above records sample/h2d
+            stall_out=stall_out,
         )
-    for batch in batches:
-        # phase brackets (input_stall was recorded inside prefetch):
-        # h2d -> device (fenced) -> host tail; `step` spans body end to
-        # body end so the sum check includes the inter-step stall
-        cur = steps_done  # 0-based step index, matches prefetch labels
-        if profile_dir and steps_done - start_step == profile_steps[0]:
-            jax.profiler.start_trace(profile_dir)
-            # Stamp the monotonic-clock marker so the device lanes of
-            # this capture can be time-aligned with the host phase
-            # events in the merged trace export (trace.py ingestion).
-            from euler_tpu.trace import align_annotation
+    try:
+        for batch in batches:
+            # With phase_profile the leaves tile this thread's iteration,
+            # each ending where the next begins (`mark`): input_other
+            # (since the last body's end, around the queue wait that
+            # prefetch recorded as input_stall) | h2d | dispatch | fence |
+            # hook | log_flush | checkpoint | host_other. `step` spans
+            # body end to body end.
+            cur = steps_done  # 0-based step index, matches prefetch labels
+            if profile_dir and steps_done - start_step == profile_steps[0]:
+                jax.profiler.start_trace(profile_dir)
+                # Stamp the monotonic-clock marker so the device lanes of
+                # this capture can be time-aligned with the host phase
+                # events in the merged trace export (trace.py ingestion).
+                from euler_tpu.trace import stamp_alignment
 
-            with align_annotation():
-                pass
-            profiling = True
-        if not device_prefetch:
-            t_h2d = time.perf_counter()
-            batch = shard_batch(batch, mesh)
-            devprof.count_h2d(batch)
+                (log_fn or log.info)(
+                    "profiler clock aligned with CLOCK_MONOTONIC within "
+                    f"{stamp_alignment()} us")
+                profiling = True
+                if phase_profile:
+                    # the profiler's own start (and stop, below) lies
+                    # between two steps and belongs to neither; what of
+                    # its start-up still runs on under this step's fence
+                    # (seconds, at times) is no stall of the loop either
+                    t_step = clock()
+                    trace_began = cur
             if phase_profile:
-                record_phase(
-                    "h2d", (time.perf_counter() - t_h2d) * 1e6, step=cur
+                mark = clock()
+                w0, w1 = stall_out
+                if w0 < t_step:  # no wait recorded since the last body
+                    w0 = w1 = t_step
+                leaves = {"input_stall": w1 - w0,
+                          "input_other": mark - t_step - (w1 - w0)}
+                record_phase_hist("input_other", leaves["input_other"])
+                record_phase_span("input_other", t_step, w0, cur)
+                record_phase_span("input_other", w1, mark, cur)
+            if not device_prefetch:
+                batch = shard_batch(batch, mesh)
+                devprof.count_h2d(batch)
+                if phase_profile:
+                    mark = leaf("h2d", mark, cur, leaves)
+            if profile_dir and cur == start_step:
+                with _cache_keyed_with_metadata():  # this call compiles
+                    state, last_loss, metric = step_fn(state, batch)
+            else:
+                state, last_loss, metric = step_fn(state, batch)
+            if cur == start_step and devprof.devprof_enabled():
+                # Relaunch-cost visibility: with the persistent compile
+                # cache warm this line drops to ~0 ms on the second launch.
+                cs = devprof.compile_summary()
+                (log_fn or log.info)(
+                    f"first step dispatched: {cs['compile_events']} XLA "
+                    f"compile(s), {cs['compile_ms_total']:.0f} ms compile "
+                    "time"
                 )
-        t_dev = time.perf_counter()
-        state, last_loss, metric = step_fn(state, batch)
-        if cur == start_step and devprof.devprof_enabled():
-            # Relaunch-cost visibility: with the persistent compile
-            # cache warm this line drops to ~0 ms on the second launch.
-            cs = devprof.compile_summary()
-            (log_fn or log.info)(
-                f"first step dispatched: {cs['compile_events']} XLA "
-                f"compile(s), {cs['compile_ms_total']:.0f} ms compile "
-                "time"
-            )
-        if phase_profile:
-            jax.block_until_ready(last_loss)
-            t_host = time.perf_counter()
-            record_phase("device", (t_host - t_dev) * 1e6, step=cur)
-        window_metrics.append(metric)
-        steps_done += 1
-        if step_hook is not None:
-            step_hook(steps_done)
-        if sync_every and steps_done % sync_every == 0:
-            jax.block_until_ready(last_loss)
-        if profiling and steps_done - start_step >= profile_steps[1]:
-            jax.block_until_ready(last_loss)
-            jax.profiler.stop_trace()
-            profiling = False
-            (log_fn or log.info)(f"profiler trace written to {profile_dir}")
-        if len(window_metrics) == log_every:
-            flush()
-        if ckpt and steps_done % checkpoint_every == 0:
-            ckpt.save(steps_done, state)
-        if phase_profile:
-            now = time.perf_counter()
-            record_phase("host", (now - t_host) * 1e6, step=cur)
-            record_phase("step", (now - t_step) * 1e6, step=cur)
-            t_step = now
+            if phase_profile:
+                t_dev = mark
+                mark = leaf("dispatch", mark, cur, leaves)
+                jax.block_until_ready(last_loss)
+                mark = t_host = leaf("fence", mark, cur, leaves)
+                record_phase_hist("device", t_host - t_dev)
+            window_metrics.append(metric)
+            steps_done += 1
+            if step_hook is not None:
+                step_hook(steps_done)
+                if phase_profile:
+                    mark = leaf("hook", mark, cur, leaves)
+            if profile_dir and cur == start_step:
+                # after the first step's hook, which may read the compile
+                # ledger as of the first dispatch
+                write_step_hlo(step_fn, state, batch, profile_dir)
+            if sync_every and steps_done % sync_every == 0:
+                jax.block_until_ready(last_loss)
+            if len(window_metrics) == log_every:
+                flush()
+                if phase_profile:
+                    mark = leaf("log_flush", mark, cur, leaves)
+            if ckpt and steps_done % checkpoint_every == 0:
+                ckpt.save(steps_done, state)
+                if phase_profile:
+                    mark = leaf("checkpoint", mark, cur, leaves)
+            if phase_profile:
+                now = leaf("host_other", mark, cur, leaves)
+                record_phase_hist("host", now - t_host)
+                record_phase("step", now - t_step, step=cur, end_us=now)
+                if cur != trace_began:
+                    journal.step(cur, t_step, now, leaves)
+                t_step = now
+            if profiling and steps_done - start_step >= profile_steps[1]:
+                jax.block_until_ready(last_loss)
+                jax.profiler.stop_trace()
+                profiling = False
+                (log_fn or log.info)(
+                    f"profiler trace written to {profile_dir}")
+                if phase_profile:
+                    t_step = clock()
+    finally:
+        if journal is not None:
+            journal.close()
     if window_metrics:  # final partial window
         flush()
     if profiling:

@@ -82,8 +82,8 @@ def _wait_registered(idx: int, reg: str, timeout: float = 90.0) -> None:
             try:
                 with socket.create_connection((host, int(port)), 1.0):
                     return
-            except OSError:
-                continue
+            except (OSError, ValueError):
+                continue  # not up yet, or the entry's ".tmp" being written
         time.sleep(0.1)
     raise TimeoutError(f"shard {idx} never came up in {reg}")
 

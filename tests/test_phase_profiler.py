@@ -2,9 +2,9 @@
 the merged Perfetto trace export (OBSERVABILITY.md "Step phases").
 
 The determinism spine is the same as test_telemetry's: PR-2's seeded
-`handler_stall:delay@25` failpoint pins EXACT log2 bucket placement —
-a 25 ms stall in the sampler must land in `sample` and `input_stall`
-bucket 15 ([16384, 32768) µs) and NEVER in `device`.
+`handler_stall:delay@25` failpoint pins log2 bucket placement — a 25 ms
+stall in the sampler must land in `sample` and `input_stall` at bucket
+15 ([16384, 32768) µs) or above, never below, and NEVER in `device`.
 """
 
 import io
@@ -106,8 +106,9 @@ def test_seeded_stall_lands_in_sample_and_input_stall_never_device(data_dir):
             native.fault_config("handler_stall:delay@25", 7)
             T.telemetry_reset()
             # synchronous prefetch path: the consumer IS the producer,
-            # so each of the 3 steps is one full 25 ms stall — exact
-            # counts in bucket 15 on BOTH phase histograms
+            # so each of the 3 steps is one full 25 ms stall on BOTH
+            # phase histograms — bucket 15 or above (a loaded host adds
+            # to the stall, never takes from it), none below
             steps = 3
             for _ in prefetch(
                 lambda s: g.node_types(IDS), steps, depth=0, num_threads=1
@@ -118,7 +119,8 @@ def test_seeded_stall_lands_in_sample_and_input_stall_never_device(data_dir):
             for phase in ("sample", "input_stall"):
                 h = hists[phase]
                 assert h["count"] == steps, (phase, h)
-                assert h["b"][STALL_BUCKET] == steps, (phase, h["b"])
+                assert sum(h["b"][:STALL_BUCKET]) == 0, (phase, h["b"])
+                assert sum(h["b"][STALL_BUCKET:]) == steps, (phase, h["b"])
             assert hists["device"]["count"] == 0, hists["device"]
             # mean stall (the ROADMAP input_stall_ms metric) moved by
             # at least the injected 25 ms

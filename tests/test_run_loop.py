@@ -192,5 +192,8 @@ def test_metrics_every_writes_jsonl(fixture_dir, tmp_path):
     with open(tf) as f:
         events = validate_chrome_trace(json.load(f))
     names = {e["name"] for e in events if e.get("cat") == "phase"}
-    assert {"input_stall", "sample", "h2d", "device", "host",
-            "step"} <= names, names
+    assert {"input_stall", "input_other", "sample", "h2d", "dispatch",
+            "fence", "hook", "host_other", "step"} <= names, names
+    # the parents `device` and `host` are sums of those leaves, kept as
+    # histograms (asserted above): as slices they would lie over them
+    assert not {"device", "host", "stall"} & names, names

@@ -1,0 +1,275 @@
+"""What the train step names on both sides of the fence (OBSERVABILITY.md
+"Step phases", "Step scopes", "Stall journal"): the named scopes of the
+jitted step, the leaves that tile the training thread's iteration on one
+clock, the kill-switch, and the stall journal."""
+
+import gc
+import time
+
+import jax
+import pytest
+
+from euler_tpu import telemetry as T
+from euler_tpu import trace as TR
+from euler_tpu import train as train_lib
+from euler_tpu.graph import native
+from euler_tpu.models import SupervisedGraphSage
+
+MAX_ID = 16  # fixture ids go up to 16
+TRAIN_THREAD_LEAVES = {"input_stall", "input_other", "h2d", *T.PHASE_PARENT}
+
+
+@pytest.fixture(autouse=True)
+def _clean_slate():
+    T.telemetry_reset()
+    T.set_telemetry(True)
+    T.set_trace_sink(None)
+    yield
+    T.telemetry_reset()
+    T.set_telemetry(True)
+    T.set_trace_sink(None)
+
+
+def _model():
+    return SupervisedGraphSage(
+        label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]],
+        fanouts=[3, 2], dim=16, feature_idx=0, feature_dim=2,
+        max_id=MAX_ID, device_features=True, device_sampling=True,
+    )
+
+
+def _train(graph, steps, **kw):
+    kw.setdefault("log_every", 4)
+    return train_lib.train(
+        _model(), graph, lambda s: graph.sample_node(8, -1),
+        num_steps=steps, learning_rate=0.01, optimizer="adam", **kw)
+
+
+# ---------------------------------------------------------------------------
+# (a) device side: the scopes of the jitted step
+# ---------------------------------------------------------------------------
+
+
+def test_lowered_train_step_holds_every_step_scope(graph):
+    m = _model()
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    text = jax.jit(m.make_train_step(opt)).lower(
+        state, m.sample(graph, roots)).as_text(debug_info=True)
+    for scope in TR.STEP_SCOPES:
+        assert f"/{scope}/" in text, scope
+    # the backward pass rides its scope: no scope of its own is needed
+    assert "transpose(jvp(" in text
+
+
+def test_benchmark_keeps_the_same_scope_names():
+    from benchmark import scopes
+
+    assert scopes.STEP_SCOPES == TR.STEP_SCOPES
+    assert scopes.STEP_HLO_FILE == TR.STEP_HLO_FILE
+
+
+def test_profiled_run_leaves_the_compiled_step_text(graph, tmp_path):
+    _train(graph, 6, profile_dir=str(tmp_path), profile_steps=(2, 4))
+    text = (tmp_path / TR.STEP_HLO_FILE).read_text()
+    assert text.startswith("HloModule jit_train_step")
+    for scope in TR.STEP_SCOPES:
+        assert f"/{scope}/" in text, scope
+    # the flag the text's compile is keyed with is put back
+    assert not jax.config.jax_compilation_cache_include_metadata_in_key
+
+
+# ---------------------------------------------------------------------------
+# (b) host side: leaves tile the training thread, parents are their sums
+# ---------------------------------------------------------------------------
+
+
+def test_leaves_tile_every_step_and_parents_are_their_sums(graph, tmp_path):
+    rec = TR.TraceRecorder().start()
+    try:
+        _train(graph, 24, step_hook=lambda step: None,
+               checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=8)
+    finally:
+        rec.stop()
+    main = [e for e in rec.events() if e[4] == "MainThread"]
+    names = {e[0] for e in main}
+    # parents never reach the sink from the training thread, nor `stall`
+    assert not names & {"device", "host", "stall"}
+    # (h2d is this thread's on the tests' virtual CPU mesh, where train()
+    # takes the copy out of the prefetch workers)
+    assert names == {"step", *TRAIN_THREAD_LEAVES}
+    steps = {e[3]: e for e in main if e[0] == "step"}
+    assert sorted(steps) == list(range(24))
+    for k, (_, s0, dur, _, _) in steps.items():
+        leaves = sorted(
+            (ts, ts + d) for name, ts, d, step, _ in main
+            if name != "step" and step == k and d > 0)
+        assert leaves[0][0] >= s0 and leaves[-1][1] <= s0 + dur
+        for (_, e0), (s1, _) in zip(leaves, leaves[1:]):
+            assert s1 >= e0, (k, leaves)  # no two leaves overlap
+        covered = sum(e - s for s, e in leaves)
+        assert covered >= 0.99 * dur, (k, covered, dur)
+
+    h = T.phase_hists()
+    for name in ("h2d", "dispatch", "fence", "hook", "host_other",
+                 "input_other", "device", "host", "step"):
+        assert h[name]["count"] == 24, name
+    assert h["log_flush"]["count"] == 6 and h["checkpoint"]["count"] == 3
+    for parent in ("device", "host"):
+        kids = sum(h[c]["sum_us"] for c, p in T.PHASE_PARENT.items()
+                   if p == parent)
+        # the leaves are cut from the parent's own clock readings
+        assert kids == h[parent]["sum_us"], parent
+    # and the whole: step = every leaf of this thread
+    leaves = sum(h[n]["sum_us"] for n in TRAIN_THREAD_LEAVES)
+    assert leaves == pytest.approx(h["step"]["sum_us"], rel=0.01)
+
+
+def test_recorder_places_a_span_at_its_end_stamp():
+    rec = TR.TraceRecorder().start()
+    try:
+        T.record_phase("fence", 250, step=3, end_us=1_000_000)
+        T.record_phase_span("input_other", 10, 40, step=3)
+        T.record_phase_span("input_other", 40, 40, step=3)  # empty: dropped
+        T.record_phase_hist("device", 250)                  # no span
+        before = TR.now_us()
+        T.record_phase("sample", 100)                       # no stamp: now
+    finally:
+        rec.stop()
+    evs = rec.events()
+    assert evs[0][:4] == ("fence", 999_750, 250, 3)
+    assert evs[1][:4] == ("input_other", 10, 30, 3)
+    assert len(evs) == 3 and evs[2][0] == "sample"
+    assert before - 100 <= evs[2][1] <= TR.now_us()
+    h = T.phase_hists()
+    assert h["device"]["count"] == 1 and h["input_other"]["count"] == 0
+
+
+# ---------------------------------------------------------------------------
+# (c) the kill-switch: nothing of this runs
+# ---------------------------------------------------------------------------
+
+
+def test_no_phase_is_recorded_with_phase_profile_off(graph, monkeypatch):
+    calls = []
+    real = native.lib().eg_phase_record
+    monkeypatch.setattr(
+        T, "record_phase", lambda *a, **k: calls.append(a))
+    monkeypatch.setattr(
+        T, "record_phase_hist", lambda *a, **k: calls.append(a))
+    monkeypatch.setattr(
+        T, "record_phase_span", lambda *a, **k: calls.append(a))
+    T.set_trace_sink(lambda *a: calls.append(a))
+    callbacks = list(gc.callbacks)
+    _train(graph, 6, phase_profile=False, step_hook=lambda step: None)
+    assert calls == []
+    assert gc.callbacks == callbacks
+    assert real is native.lib().eg_phase_record
+    h = T.phase_hists()
+    assert all(h[n]["count"] == 0 for n in T.PHASES if n != "compile"), h
+
+
+def test_telemetry_off_means_phase_profile_off(graph):
+    T.set_telemetry(False)
+    seen = []
+    T.set_trace_sink(lambda *a: seen.append(a))
+    _train(graph, 4)
+    assert seen == []
+
+
+# ---------------------------------------------------------------------------
+# (d) the stall journal
+# ---------------------------------------------------------------------------
+
+
+def test_a_stalled_step_is_journalled_with_its_cause(graph):
+    stamps, slept = [], []
+
+    def hook(step):
+        stamps.append(time.monotonic())
+        if step == 30:
+            # 80 ms, or more where a loaded host makes the toy steps so
+            # slow that 80 ms would be under 5 x their median
+            usual = sorted(b - a for a, b in zip(stamps, stamps[1:]))
+            slept.append(max(0.08, 8 * usual[len(usual) // 2]))
+            time.sleep(slept[0])
+            gc.collect(0)  # a young collection: listed, and cheap
+
+    callbacks = list(gc.callbacks)
+    _train(graph, 40, step_hook=hook, log_every=100)
+    assert gc.callbacks == callbacks  # the collector's callback is gone
+    entries = T.stall_journal()
+    # (a host loaded enough to stall another step by itself adds entries)
+    (e,) = [x for x in entries if x["step"] == 29]  # 0-based, as the spans
+    assert e["leaf"] == "hook"
+    assert slept[0] * 1e6 <= e["leaf_us"] <= e["total_us"] < 4_000_000
+    assert e["total_us"] > 5 * e["median_us"] > 0
+    # the thread slept: over the stretch since the journal last read the
+    # thread's clocks, the CPU time beyond the usual is far under the
+    # excess, and it gave the CPU up
+    assert 1 <= e["since_steps"] <= T.StallJournal.REFRESH
+    on_cpu = e["cpu_us"] - e["usual_cpu_us"] * e["since_steps"]
+    assert on_cpu < 0.5 * e["excess_us"], e
+    assert e["vcsw"] >= 1
+    assert any(gen == 0 and thread == "MainThread"
+               for gen, _us, thread in e["gc"]), e["gc"]
+    assert e["ticks"] == []
+    assert e["excess_us"] == e["total_us"] - e["median_us"]
+    assert 0.9 * slept[0] * 1e6 <= e["excess_us"]
+    # one sample of the excess per entry in the `stall` histogram
+    h = T.phase_hists()["stall"]
+    assert h["count"] == len(entries)
+    assert h["sum_us"] == sum(x["excess_us"] for x in entries)
+
+
+def test_stall_journal_names_the_jobs_that_ticked_inside():
+    j = T.StallJournal()
+    try:
+        t = TR.now_us() - 1000 * T.StallJournal.FIRST  # eight past steps
+        for k in range(T.StallJournal.FIRST):
+            j.step(k, t, t + 1000, {"fence": 900, "host_other": 100})
+            t += 1000
+        T.job_tick("eg-devprof-sampler")
+        T.job_tick("eg-devprof-sampler", end=True)
+        ticks = T.job_ticks()
+        assert ticks["eg-devprof-sampler"][0] >= t
+        assert ticks["blackbox-sampler"] == (0, 0)
+        T.job_tick("metrics_every")  # begun, not ended: still at work
+        j.step(8, t, TR.now_us() + 60_000,
+               {"fence": 60_500, "host_other": 100})
+    finally:
+        j.close()
+    (e,) = T.stall_journal()
+    assert e["leaf"] == "fence" and e["leaf_typical_us"] == 900
+    assert e["ticks"] == ["eg-devprof-sampler", "metrics_every"]
+    assert e["median_us"] == 1000
+
+
+def test_a_stall_on_the_very_step_the_journal_rereads_its_clocks():
+    """A journalled step restarts the stretch the thread's clocks are read
+    over; where it is also a step on which the median is re-read, the
+    stretch is empty and must not be divided by."""
+    j = T.StallJournal()
+    try:
+        t = 1_000_000
+        for k in range(T.StallJournal.REFRESH - 1):
+            j.step(k, t, t + 1000, {"fence": 1000})
+            t += 1000
+        j.step(T.StallJournal.REFRESH - 1, t, t + 90_000, {"fence": 90_000})
+        j.step(T.StallJournal.REFRESH, t + 90_000, t + 91_000,
+               {"fence": 1000})
+    finally:
+        j.close()
+    (e,) = T.stall_journal()
+    assert e["step"] == T.StallJournal.REFRESH - 1 and e["leaf"] == "fence"
+    assert e["since_steps"] == T.StallJournal.REFRESH - T.StallJournal.FIRST
+
+
+def test_detail_span_is_escaped_in_the_dump():
+    native.lib().eg_telemetry_record_detail_span(
+        77, 0, b'{"kind":"x","s":"a\\"b\\\\c"}')
+    (s,) = T.slow_spans()
+    assert s["total_us"] == 77 and s["end_us"] > 0
+    assert s["detail"] == {"kind": "x", "s": 'a"b\\c'}
+    assert T.stall_journal() == []
