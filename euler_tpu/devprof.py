@@ -15,6 +15,7 @@ scripts/metrics_dump.py report the device plane with zero new plumbing:
     fn = devprof.watch(jitted, "loss_step")   recompile attribution
     devprof.recompile_ledger()       journaled recompiles, newest last
     devprof.sample_device_mem()      one-shot HBM/buffer gauge refresh
+    devprof.record_feature_table(w, stored)   feature-table width gauges
     devprof.count_h2d(batch)         transfer-byte bracketing
     devprof.set_devprof(False)       process-global kill-switch
 
@@ -294,6 +295,15 @@ def sample_device_mem() -> tuple:
         bytes_in_use = int(sum(getattr(a, "nbytes", 0) for a in arrs))
     lib().eg_devprof_set_mem(bytes_in_use, buffers)
     return (bytes_in_use, buffers)
+
+
+def record_feature_table(width: int, stored_width: int) -> None:
+    """The gauges ``feature_table_width`` / ``feature_table_stored_width``
+    of the resource section: a model's feature_dim and the lane-multiple
+    width its device-resident table stores rows at (models/base.py
+    build_consts calls this once per table it builds)."""
+    if _enabled:
+        lib().eg_devprof_set_feature_table(int(width), int(stored_width))
 
 
 def start_sampler(period_ms: int = 1000) -> None:

@@ -603,6 +603,12 @@ void Blackbox::ResourceJsonBody(std::string* out) {
   AppendKey(out, "device_buffers");
   AppendI64(out, s.device_buffers);
   out->push_back(',');
+  AppendKey(out, "feature_table_width");
+  AppendI64(out, Devprof::Global().feature_table_width());
+  out->push_back(',');
+  AppendKey(out, "feature_table_stored_width");
+  AppendI64(out, Devprof::Global().feature_table_stored_width());
+  out->push_back(',');
   AppendKey(out, "history_depth");
   uint64_t hh = hist_head_.load(std::memory_order_acquire);
   AppendU64(out, hh > kBbHistorySlots ? kBbHistorySlots : hh);

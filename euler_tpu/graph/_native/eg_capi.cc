@@ -935,6 +935,15 @@ void eg_devprof_set_mem(int64_t bytes, int64_t buffers) {
   EG_API_GUARD()
 }
 
+// The dense feature table's logical and stored widths
+// (models/base.py build_consts sets them once per table it builds).
+void eg_devprof_set_feature_table(int64_t width, int64_t stored_width) {
+  try {
+    eg::Devprof::Global().SetFeatureTable(width, stored_width);
+  }
+  EG_API_GUARD()
+}
+
 // Refresh the live serve-SLO gauges (µs): euler_tpu/serving/slo.py
 // pushes its windowed p50/p99 and lifetime violations every few
 // records, so a scrape reads serving latency without draining.

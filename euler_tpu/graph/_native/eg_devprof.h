@@ -42,6 +42,12 @@ class Devprof {
   void SetServeSlo(uint64_t p50_us, uint64_t p99_us, uint64_t violations,
                    uint64_t count);
 
+  // Widths of the device-resident dense feature table
+  // (models/base.py build_consts): the model's feature_dim and the
+  // lane-multiple width its rows are stored at. 0/0 until a table is
+  // built.
+  void SetFeatureTable(int64_t width, int64_t stored_width);
+
   int64_t mem_bytes() const {
     return mem_bytes_.load(std::memory_order_relaxed);
   }
@@ -50,6 +56,12 @@ class Devprof {
   }
   int64_t buffers() const {
     return buffers_.load(std::memory_order_relaxed);
+  }
+  int64_t feature_table_width() const {
+    return feature_width_.load(std::memory_order_relaxed);
+  }
+  int64_t feature_table_stored_width() const {
+    return feature_stored_width_.load(std::memory_order_relaxed);
   }
 
   // Append `,"serve_slo":{"p50_us":..,"p99_us":..,"violations":..,
@@ -65,6 +77,8 @@ class Devprof {
   std::atomic<int64_t> mem_bytes_{0};
   std::atomic<int64_t> mem_peak_bytes_{0};
   std::atomic<int64_t> buffers_{0};
+  std::atomic<int64_t> feature_width_{0};
+  std::atomic<int64_t> feature_stored_width_{0};
   std::atomic<uint64_t> slo_p50_us_{0};
   std::atomic<uint64_t> slo_p99_us_{0};
   std::atomic<uint64_t> slo_violations_{0};

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from euler_tpu import devprof
 from euler_tpu.nn import metrics
 
 log = logging.getLogger("euler_tpu")
@@ -124,20 +125,49 @@ def upload_sparse_tables(
     ]
 
 
-def gather_consts(feats: dict, consts: dict) -> dict:
+# The minor dimension of the TPU's (8, 128) memory tile. The runtime
+# lays a 2-D array out in whichever dimension order pads least under
+# that tile, so a [nodes, 602] table arrives column-major (602 -> 608
+# sublanes against 602 -> 640 lanes) and a row gather from it costs a
+# transpose of the whole table every step (PERF.md section 6, PR 28). A
+# width that is a multiple of the lanes pads nothing row-major, which
+# makes rows-contiguous the runtime's own choice in every jit that takes
+# the table, with no layout argument anywhere.
+TABLE_LANES = 128
+
+
+def stored_width(feature_dim: int) -> int:
+    """The width the dense feature table's rows are stored at:
+    ``feature_dim`` rounded up to a multiple of TABLE_LANES (a width that
+    already is one stores as it is)."""
+    return -(-feature_dim // TABLE_LANES) * TABLE_LANES
+
+
+def gather_rows(table, ids, feature_dim: int):
+    """Rows ``ids`` of the device-resident feature table as the
+    [..., feature_dim] float32 the modules compute on: gather stored
+    rows, slice the pad lanes off (they reach no matmul, loss or
+    gradient), then undo a reduced-precision table's cast."""
+    return table[ids][..., :feature_dim].astype(jnp.float32)
+
+
+def gather_consts(feats: dict, consts: dict, feature_dim: int) -> dict:
     """Materialize device-resident features for one node set: replace the
     host-side 'gids' indices with gathers from the HBM-resident tables
-    (dense rows, and padded sparse id+mask rows when configured). A
-    reduced-precision table (feature_dtype='bfloat16') is cast back to
-    float32 after the gather so the module math is unchanged — only the
-    HBM-resident bytes (and the gather traffic) shrink."""
+    (dense rows cut back from the stored width to ``feature_dim``, and
+    padded sparse id+mask rows when configured). A reduced-precision
+    table (feature_dtype='bfloat16') is cast back to float32 after the
+    gather so the module math is unchanged — only the HBM-resident bytes
+    (and the gather traffic) shrink."""
     if not consts or "gids" not in feats:
         return feats
     feats = dict(feats)
     g = feats["gids"]
     with jax.named_scope("gather_features"):
         if "features" in consts:
-            feats["dense"] = consts["features"][g].astype(jnp.float32)
+            feats["dense"] = gather_rows(
+                consts["features"], g, feature_dim
+            )
         if "sparse" in consts and "sparse" not in feats:
             feats["sparse"] = [
                 (t["ids"][g], t["mask"][g]) for t in consts["sparse"]
@@ -192,8 +222,13 @@ class Model:
     device_features=True switches dense feature/label delivery from
     host-gather-and-transfer to device-resident tables: init_state uploads
     the full feature (and label) table to HBM once (state['consts'],
-    replicated, aliased across steps via donation), sample() ships only
-    int32 node ids, and the module gathers rows on device. This is the
+    replicated, or row-sharded over a 'model' mesh axis), sample() ships
+    only int32 node ids, and the module gathers rows on device. The
+    feature table is stored ``stored_width(feature_dim)`` wide (zero lanes
+    past feature_dim), which is what keeps its rows contiguous in HBM so
+    the gather reads it in place; the gather hands the module
+    [rows, feature_dim]. The train step donates state, so the tables'
+    buffers are aliased input to output across steps. This is the
     TPU-native replacement for the reference's PS-side embedding gathers
     (tf_euler/python/utils/embedding.py) and cuts per-step host->device
     traffic by ~feature_dim x."""
@@ -511,11 +546,20 @@ class Model:
                         "feature_dtype kwarg or EULER_TPU_FEATURE_DTYPE; "
                         "use a numpy dtype name like 'bfloat16')"
                     ) from e
+            # the engine zero-fills a slot up to the width it is asked
+            # for, so the lane padding costs no second host copy
+            width = stored_width(self.feature_dim)
             consts["features"] = jnp.asarray(
                 graph.get_dense_feature(
-                    ids, [self.feature_idx], [self.feature_dim]
+                    ids, [self.feature_idx], [width]
                 ),
                 dtype=dt or None,
+            )
+            devprof.record_feature_table(self.feature_dim, width)
+            log.info(
+                "feature table: [%d, %d] %s stored [%d, %d], rows "
+                "contiguous", n, self.feature_dim,
+                consts["features"].dtype, n, width,
             )
         if getattr(self, "label_idx", -1) >= 0:
             consts["labels"] = jnp.asarray(
@@ -553,7 +597,11 @@ class Model:
         """Pure (state, batch) -> (state, loss, metric); jitted by the
         trainer with params replicated and batch sharded over 'data'. The
         (donated) consts tables pass through unchanged, so XLA aliases
-        their buffers — zero copies per step. The layer boundaries carry
+        each table's output buffer to its input. Aliasing alone does not
+        make the step copy-free: a table whose rows are not contiguous
+        in the layout it arrives in is re-laid-out before every gather,
+        which is why build_consts stores the feature table at a lane-
+        multiple width (stored_width). The layer boundaries carry
         the ``jax.named_scope`` names of ``trace.STEP_SCOPES`` (metadata
         only: the compiled program is what it was)."""
 

@@ -25,6 +25,7 @@ class _GATModule(nn.Module):
     sigmoid_loss: bool = True
     nb_num: int = 5
     adj_key: str = ""
+    feature_dim: int = 0  # width of the rows cut from the stored table
 
     def setup(self):
         self.encoder = AttEncoder(
@@ -38,7 +39,6 @@ class _GATModule(nn.Module):
             return batch["seq_ids"]
         # device sampling: draw the nb_num attention neighbors here
         import jax
-        import jax.numpy as jnp
 
         from euler_tpu.graph import device as device_graph
 
@@ -53,9 +53,8 @@ class _GATModule(nn.Module):
         if "seq" in batch:
             return self.encoder(batch["seq"])
         # device-resident features: gather [B, nb+1, fdim] from the table
-        # (cast restores float32 when the table is stored reduced-precision)
         return self.encoder(
-            consts["features"][seq_ids].astype(jnp.float32)
+            base.gather_rows(consts["features"], seq_ids, self.feature_dim)
         )
 
     def embed(self, batch, consts=None):
@@ -126,6 +125,7 @@ class GAT(base.Model):
             sigmoid_loss=sigmoid_loss,
             nb_num=nb_num,
             adj_key=self._adj_key,
+            feature_dim=feature_dim,
         )
 
     def build_consts(self, graph) -> dict:

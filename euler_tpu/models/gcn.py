@@ -83,7 +83,10 @@ class _SupervisedGCNModule(nn.Module):
     def _forward(self, batch, consts):
         hops, adjs = self._hops_adjs(batch, consts)
         hidden = [
-            self.node_encoder(base.gather_consts(f, consts)) for f in hops
+            self.node_encoder(
+                base.gather_consts(f, consts, self.feature_dim)
+            )
+            for f in hops
         ]
         return self.encoder(hidden, adjs), hops
 
@@ -244,10 +247,14 @@ class _ScalableGCNModule(nn.Module):
 
     def forward_train(self, batch, store_reads, consts=None):
         node_emb = self.node_encoder(
-            base.gather_consts(batch["node_feats"], consts)
+            base.gather_consts(
+                batch["node_feats"], consts, self.feature_dim
+            )
         )
         neigh_emb = self.node_encoder(
-            base.gather_consts(batch["neigh_feats"], consts)
+            base.gather_consts(
+                batch["neigh_feats"], consts, self.feature_dim
+            )
         )
         adj = batch["adj"]
         node_embeddings = []

@@ -69,7 +69,10 @@ class _SupervisedSageModule(nn.Module):
 
     def _embed_hops(self, hops, consts):
         hidden = [
-            self.node_encoder(base.gather_consts(f, consts)) for f in hops
+            self.node_encoder(
+                base.gather_consts(f, consts, self.feature_dim)
+            )
+            for f in hops
         ]
         return self.encoder(hidden)
 
@@ -256,10 +259,14 @@ class _ScalableSageModule(nn.Module):
 
     def forward_train(self, batch, store_reads, consts=None):
         node_feat = self.node_encoder(
-            base.gather_consts(batch["node_feats"], consts)
+            base.gather_consts(
+                batch["node_feats"], consts, self.feature_dim
+            )
         )
         neigh_feat = self.node_encoder(
-            base.gather_consts(batch["neigh_feats"], consts)
+            base.gather_consts(
+                batch["neigh_feats"], consts, self.feature_dim
+            )
         )
         emb, node_embeddings = self.encoder(node_feat, neigh_feat, store_reads)
         logits = self.predict(emb)
@@ -441,7 +448,9 @@ class _UnsupervisedSageModule(nn.Module):
         )
 
     def _encode(self, hops, context: bool, consts=None):
-        hops = [base.gather_consts(f, consts) for f in hops]
+        hops = [
+            base.gather_consts(f, consts, self.feature_dim) for f in hops
+        ]
         if context:
             hidden = [self.context_node_encoder(f) for f in hops]
             return self.context_encoder(hidden)

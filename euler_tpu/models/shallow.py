@@ -127,15 +127,18 @@ class _ShallowUnsupModule(nn.Module):
         )
         return self._feats(src), self._feats(pos), self._feats(negs)
 
+    def _gathered(self, feats, consts):
+        return base.gather_consts(feats, consts, self.feature_dim)
+
     def embed(self, batch, consts=None):
         src, _, _ = self._inputs(batch, consts)
-        return self.target(base.gather_consts(src, consts))
+        return self.target(self._gathered(src, consts))
 
     def __call__(self, batch, consts=None):
         src, pos, negs = self._inputs(batch, consts)
-        emb = self.target(base.gather_consts(src, consts))  # [B, d]
-        emb_pos = self._context(base.gather_consts(pos, consts))
-        emb_negs = self._context(base.gather_consts(negs, consts))
+        emb = self.target(self._gathered(src, consts))  # [B, d]
+        emb_pos = self._context(self._gathered(pos, consts))
+        emb_negs = self._context(self._gathered(negs, consts))
         B = emb.shape[0]
         loss, mrr = base.unsupervised_decoder(
             emb.reshape(B, 1, -1),

@@ -532,6 +532,13 @@ _RESOURCE_FAMILIES = {
                               "since start/reset"),
     "device_buffers": ("eg_device_buffers",
                        "Live device buffers at the last devprof sample"),
+    "feature_table_width": ("eg_feature_table_width",
+                            "Logical width (feature_dim) of the device-"
+                            "resident dense feature table; 0 = none built"),
+    "feature_table_stored_width": ("eg_feature_table_stored_width",
+                                   "Width the feature table's rows are "
+                                   "stored at: feature_dim rounded up to "
+                                   "128 lanes, so rows are contiguous"),
 }
 
 

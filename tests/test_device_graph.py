@@ -283,7 +283,7 @@ def test_device_sparse_tables_match_host_gather(graph):
     consts = m.build_consts(graph)
     ids = np.arange(MAX_ID + 1, dtype=np.int64)
     host = ops.get_sparse_feature(graph, ids, [0], 4, default_values=[41])
-    feats = gather_consts({"gids": ids.astype(np.int32)}, consts)
+    feats = gather_consts({"gids": ids.astype(np.int32)}, consts, 2)
     dev_ids, dev_mask = feats["sparse"][0]
     np.testing.assert_array_equal(np.asarray(dev_ids), host[0][0])
     np.testing.assert_array_equal(np.asarray(dev_mask), host[0][1])

@@ -184,6 +184,44 @@ def test_device_mem_gauges_reach_resource_section():
     assert res3["device_mem_peak_bytes"] == 0
 
 
+def test_feature_table_gauges_and_route_log(fixture_dir, caplog):
+    """build_consts says once what width the feature table is stored at
+    (the route-log line beside 'draw path: ...') and sets the two width
+    gauges, which outlive a reset of the measurements and reach
+    metrics_text()."""
+    import logging
+
+    import euler_tpu
+    from euler_tpu import telemetry as T
+    from euler_tpu.models import SupervisedGraphSage
+
+    model = SupervisedGraphSage(
+        label_idx=2, label_dim=3, metapath=[[0, 1]], fanouts=[2], dim=8,
+        feature_idx=0, feature_dim=50, max_id=16, device_features=True,
+    )
+    g = euler_tpu.Graph(directory=fixture_dir)
+    try:
+        with caplog.at_level(logging.INFO, logger="euler_tpu"):
+            model.build_consts(g)
+    finally:
+        g.close()
+    assert (
+        "feature table: [18, 50] float32 stored [18, 128], rows contiguous"
+        in caplog.text
+    )
+    T.telemetry_reset()
+    res = T.telemetry_json()["resource"]
+    assert res["feature_table_width"] == 50
+    assert res["feature_table_stored_width"] == 128
+    text = euler_tpu.metrics_text()
+    assert "eg_feature_table_width 50" in text
+    assert "eg_feature_table_stored_width 128" in text
+    # the kill-switch silences the device plane, this gauge included
+    devprof.set_devprof(False)
+    devprof.record_feature_table(7, 128)
+    assert T.telemetry_json()["resource"]["feature_table_width"] == 50
+
+
 # ------------------------------------------------------ serve guard drill
 
 

@@ -223,3 +223,115 @@ def test_feature_dtype_bfloat16(graph, monkeypatch):
     # a bogus dtype fails loudly, naming the knob
     with pytest.raises(ValueError, match="feature_dtype"):
         SupervisedGraphSage(**kw, feature_dtype="bf16").build_consts(graph)
+
+
+# ---- the stored feature table (PERF.md section 6, PR 28) ----
+
+
+def _sage_kw(feature_dim=2):
+    return dict(
+        label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]],
+        fanouts=[3, 2], dim=8, feature_idx=0, feature_dim=feature_dim,
+        max_id=16, device_features=True,
+    )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "feature_dim,stored", [(50, 128), (602, 640), (128, 128)]
+)
+def test_build_consts_stores_lane_multiple_width(
+    graph, feature_dim, stored, dtype
+):
+    """The feature table's rows are stored at the next multiple of 128
+    lanes (a lane multiple as it is), the pad lanes hold zeros, and the
+    lanes before them are the engine's rows, in either storage dtype."""
+    from euler_tpu.models import SupervisedGraphSage, base
+
+    assert base.stored_width(feature_dim) == stored
+    model = SupervisedGraphSage(
+        **_sage_kw(feature_dim), feature_dtype=dtype
+    )
+    table = model.build_consts(graph)["features"]
+    assert table.shape == (18, stored) and table.dtype == dtype
+    table = np.asarray(table, np.float32)
+    want = graph.get_dense_feature(
+        np.arange(18, dtype=np.int64), [0], [feature_dim]
+    )
+    np.testing.assert_array_equal(table[:, :feature_dim], want)
+    assert want[10:17, :2].any()  # the fixture's rows, not all zeros
+    assert not table[:, feature_dim:].any()
+
+
+def _stored_and_sliced(model, graph, roots, opt):
+    """One state with the table as build_consts stores it, and one whose
+    table is the same rows cut to feature_dim by hand (the pre-PR 28
+    form): same params, same batch."""
+    state = model.init_state(jax.random.PRNGKey(7), graph, roots, opt)
+    assert state["consts"]["features"].shape[1] == 128
+    sliced = dict(state)
+    sliced["consts"] = dict(state["consts"])
+    sliced["consts"]["features"] = state["consts"]["features"][
+        :, : model.feature_dim
+    ]
+    return state, sliced
+
+
+@pytest.mark.parametrize("family", ["graphsage_supervised", "gat"])
+def test_stored_table_is_bit_identical_to_sliced(graph, family):
+    """The pad lanes are cut off at the gather, so loss, gradients and
+    embeddings are the same bits with the stored [N, 128] table and with
+    the [N, feature_dim] table it was before."""
+    import optax
+
+    from euler_tpu.models import GAT, SupervisedGraphSage
+
+    if family == "gat":
+        model = GAT(
+            label_idx=2, label_dim=3, feature_idx=0, feature_dim=2,
+            max_id=16, head_num=2, hidden_dim=16, nb_num=4, edge_type=0,
+            device_features=True,
+        )
+    else:
+        model = SupervisedGraphSage(**_sage_kw())
+    roots = np.array([10, 12, 14, 16], dtype=np.int64)
+    opt = optax.adam(0.01)
+    state, sliced = _stored_and_sliced(model, graph, roots, opt)
+    batch = model.sample(graph, roots)
+
+    embed = model.make_embed_step()
+    step = jax.jit(model.make_train_step(opt))
+
+    def outputs(s):
+        loss, grads = jax.value_and_grad(
+            lambda p: model._apply(p, batch, s["consts"]).loss
+        )(s["params"])
+        return grads, loss, embed(s, batch), step(s, batch)[0]["params"]
+
+    got, want = outputs(state), outputs(sliced)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert any(np.asarray(g).any() for g in jax.tree.leaves(got[0]))
+
+
+def test_stored_table_row_shards_on_data2_model2(graph):
+    """data=2 x model=2: the stored table still shards by rows over
+    'model' (the lane padding is on dim 1, the mesh padding on dim 0)
+    and train() runs on it."""
+    from jax.sharding import PartitionSpec as P
+
+    from euler_tpu import train as train_lib
+    from euler_tpu.models import SupervisedGraphSage
+    from euler_tpu.parallel import make_mesh
+
+    model = SupervisedGraphSage(**_sage_kw(50))
+    state, hist = train_lib.train(
+        model, graph, lambda s: graph.sample_node(8, -1), num_steps=12,
+        mesh=make_mesh(4, model_parallel=2), learning_rate=0.05,
+        log_every=6,
+    )
+    table = state["consts"]["features"]
+    assert table.shape == (18, 128)
+    assert table.sharding.spec == P("model")
+    assert {s.data.shape for s in table.addressable_shards} == {(9, 128)}
+    assert len(hist) == 2 and np.isfinite(hist[-1]["loss"])

@@ -884,6 +884,55 @@ def test_eligible2_corners_compile(m, f1, f2, w1, w2):
     assert (h2[~real] == n - 1).all()
 
 
+@tpu_only
+@pytest.mark.parametrize("feature_dim,stored", [(602, 640), (50, 128)])
+def test_feature_table_stays_row_major(tmp_path, feature_dim, stored):
+    """The guard against the whole-table copy coming back (PERF.md
+    section 6, PR 28): in the compiled, donated train step the feature
+    table's entry layout is row-major and nothing but parameters (the
+    entry's, and those of the fusions that gather from it) has the
+    table's shape. At the logical widths the runtime's default layout is
+    column-major: 602 cost a 5.35 GB transpose a step, 50 a strided
+    gather."""
+    import re
+
+    import optax
+
+    import euler_tpu
+    from euler_tpu.models import SupervisedGraphSage
+    from tests.fixture_graph import write_fixture
+
+    write_fixture(str(tmp_path), num_partitions=2)
+    graph = euler_tpu.Graph(directory=str(tmp_path))
+    n = 200_000
+    model = SupervisedGraphSage(
+        label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]],
+        fanouts=[4, 4], dim=64, feature_idx=0, feature_dim=feature_dim,
+        max_id=n - 2, device_features=True,
+    )
+    opt = optax.adam(0.01)
+    roots = np.resize(np.arange(10, 17), 1000)
+    try:
+        state = model.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+        batch = model.sample(graph, roots)
+    finally:
+        graph.close()
+    assert state["consts"]["features"].shape == (n, stored)
+    text = (
+        jax.jit(model.make_train_step(opt), donate_argnums=(0,))
+        .lower(state, batch).compile().as_text()
+    )
+    shape = re.escape("f32[%d,%d]" % (n, stored))
+    made = re.findall(
+        r"^\s*(?:ROOT )?%\S+ = " + shape + r"\{([\d,]+)[^}]*\} (\S+?)\(",
+        text, re.MULTILINE,
+    )
+    assert made, "the feature table is not in the compiled step"
+    assert {op for _, op in made} == {"parameter"}, made
+    assert {layout for layout, _ in made} == {"1,0"}, made
+    assert "[%d,%d]" % (n, feature_dim) not in text
+
+
 # ---- per-shard kernel on a real mesh (TPU host with >= 4 chips) ----
 
 
