@@ -4,6 +4,12 @@ What the timed path produced in its first three steps (the same
 ``train()`` call whose later steps are the window) against the plain
 reference, on the rows those steps really used:
 
+This module knows no model family: the configuration's reference module
+(``sage_reference.py`` states the protocol) says which fan-outs a step
+draws, what the reference trains on and how a batch is cut to its first
+rows, and which named leaves of the program's state are compared. Here
+are the numbers, the two-reference bracket and the verdict:
+
 * ``draw_foreign``  drawn ids that are not neighbours of their parent in
   the benchmark's graph function (exact, limit 0);
 * ``draw_skew``     |mean quantile of the picked slot - 0.5| over all
@@ -11,12 +17,13 @@ reference, on the rows those steps really used:
 * ``loss_gap``      worst of the three steps' |loss - reference| over the
   reference's loss;
 * ``grad_gap``      worst leaf of the first gradient as the optimizer got
-  it (Adam's first moment after one step, over 1 - b1): gap between the
-  program's norm and the reference's, against the reference's norm of
-  that leaf or of the median leaf, whichever is larger;
-* ``change_gap``    the same measure on the parameters' change after the
-  three steps, over leaves whose reference gradient is not nought to
-  rounding (under a thousandth of the median leaf's).
+  it (for Adam: its first moment after one step, over 1 - b1): gap
+  between the program's norm and the reference's, against the reference's
+  norm of that leaf or of the median leaf, whichever is larger;
+* ``change_gap``    the same measure on the change of the compared
+  leaves (the parameters; a family's stores too) after the three steps,
+  leaving out leaves whose reference gradient is nought to rounding
+  (under a thousandth of the median leaf's).
 
 Two references bracket what the configuration states. The recipe is
 float32 and the program leaves its matmuls at the platform's default
@@ -44,21 +51,23 @@ def leaf_norms(tree: dict) -> dict:
     }
 
 
-def worst_leaf_gap(prog: dict, ref: dict, keep=None) -> float:
-    """max over leaves of |‖prog‖ - ‖ref‖| / max(‖ref‖, median ‖ref‖)."""
+def worst_leaf_gap(prog: dict, ref: dict, drop=()) -> float:
+    """max over the reference's leaves, but those in ``drop``, of
+    |‖prog‖ - ‖ref‖| / max(‖ref‖, median ‖ref‖)."""
     pn, rn = leaf_norms(prog), leaf_norms(ref)
-    names = [k for k in rn if keep is None or k in keep]
+    names = [k for k in rn if k not in drop]
     med = float(np.median([rn[k] for k in names]))
     return max(
         abs(pn[k] - rn[k]) / max(rn[k], med, 1e-30) for k in names
     )
 
 
-def moving_leaves(ref_grad: dict) -> set:
-    """Leaves whose reference gradient is not nought to rounding."""
+def still_leaves(ref_grad: dict) -> set:
+    """Leaves whose reference gradient is nought to rounding: they move
+    under Adam by round-off alone and are left out of the change."""
     rn = leaf_norms(ref_grad)
     med = float(np.median(list(rn.values())))
-    return {k for k, v in rn.items() if v >= 1e-3 * med}
+    return {k for k, v in rn.items() if v < 1e-3 * med}
 
 
 def draw_numbers(spec, hops: list, fanouts: list) -> tuple:
@@ -87,62 +96,52 @@ def draw_numbers(spec, hops: list, fanouts: list) -> tuple:
     return foreign, skew
 
 
-def reference_batch(spec, hops: list) -> tuple:
-    """Features of every hop and the roots' labels, from the graph
-    function (not from any table the program built)."""
-    x = [spec.features(np.asarray(h).reshape(-1)) for h in hops]
-    y = spec.labels(np.asarray(hops[0]).reshape(-1))
-    return x[0], x[1], x[2], y
-
-
 def compare(cfg: dict, spec, ref, captured: dict, dtype=None,
             batch_rows=None) -> dict:
     """The cell's numbers. ``captured`` holds what the hook took from the
-    timed path: ``params0`` (reference-named), ``hops`` (per step, per
-    hop), ``losses``, ``grad1`` and ``params3`` (reference-named).
+    timed path: ``start`` (the reference-named leaves the steps began
+    from), ``hops`` (per step, per hop), ``losses``, ``grad1`` and
+    ``end`` (the compared leaves after the last captured step).
 
     ``dtype`` puts the reference, computed in that type, in the
     program's place (the control); ``batch_rows`` puts the reference on
     the first rows only in its place (the planted faults)."""
     import jax.numpy as jnp
 
-    fanouts = list(cfg["fanouts"])
-    params0 = {k: jnp.asarray(v) for k, v in captured["params0"].items()}
+    start = {k: jnp.asarray(v) for k, v in captured["start"].items()}
+
+    def change_of(end: dict) -> dict:
+        return {k: np.asarray(end[k]) - np.asarray(start[k]) for k in end}
+
     if "_references" not in captured:
         # kept beside what was captured: the control and the faults of a
         # calibration are held against the same two references
-        batches = [reference_batch(spec, hops) for hops in captured["hops"]]
+        batches = [
+            ref.reference_batch(spec, hops) for hops in captured["hops"]]
         refs = []
         for precision in ("highest", None):
-            r_losses, r_grad, r_params = ref.train_steps(
-                cfg, params0, batches, precision=precision)
+            r_losses, r_grad, r_end = ref.train_steps(
+                cfg, start, batches, precision=precision)
             refs.append((
                 r_losses,
                 {k: np.asarray(v) for k, v in r_grad.items()},
-                {k: np.asarray(r_params[k]) - np.asarray(params0[k])
-                 for k in r_params},
+                change_of(r_end),
             ))
         captured["_references"] = (batches, refs)
     batches, refs = captured["_references"]
     if dtype is not None or batch_rows is not None:
         sub = batches
         if batch_rows is not None:
-            f1, f2 = fanouts
-            sub = [
-                (x0[:batch_rows], x1[:batch_rows * f1],
-                 x2[:batch_rows * f1 * f2], y[:batch_rows])
-                for x0, x1, x2, y in batches
-            ]
-        losses, grad, params = ref.train_steps(
-            cfg, params0, sub, dtype or jnp.float32)
+            sub = [ref.batch_rows(cfg, b, batch_rows) for b in batches]
+        losses, grad, end = ref.train_steps(
+            cfg, start, sub, dtype or jnp.float32)
         grad = {k: np.asarray(v) for k, v in grad.items()}
-        params = {k: np.asarray(v) for k, v in params.items()}
     else:
         losses = captured["losses"]
         grad = captured["grad1"]
-        params = captured["params3"]
-    change = {k: np.asarray(params[k]) - np.asarray(params0[k])
-              for k in params0}
+        end = captured["end"]
+    change = change_of(end)
+    fanouts = ref.drawn_fanouts(cfg)
     foreign, skews = 0, []
     for hops in captured["hops"]:
         f, s = draw_numbers(spec, hops, fanouts)
@@ -158,7 +157,7 @@ def compare(cfg: dict, spec, ref, captured: dict, dtype=None,
         )),
         "grad_gap": min(worst_leaf_gap(grad, r[1]) for r in refs),
         "change_gap": min(
-            worst_leaf_gap(change, r[2], keep=moving_leaves(r[1]))
+            worst_leaf_gap(change, r[2], drop=still_leaves(r[1]))
             for r in refs
         ),
     }

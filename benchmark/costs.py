@@ -1,47 +1,52 @@
-"""Operations and bytes one training step needs, from the cell's shapes.
+"""The work one training step needs by the cell's shapes, asked of the
+cost function that the configuration keeps with it.
 
-What the algorithm needs, whatever implements it: the same numbers for a
-fused kernel, an XLA chain or a host sampler. Per step and per chip, for
-a per-chip batch of ``b`` roots.
+A configuration's file names, under ``"costs"``, a Python file (relative
+to the manifest, under ``paths``) with one function
+
+    step_costs(cfg, per_chip_batch, device_sampling) -> dict
+
+per step and per chip, for what the algorithm needs whatever implements
+it. This module knows no model: it loads that file and holds the answer
+to the keys the harness and the readers ask for:
+
+* ``edges``         sampled edges of one step (``edges_per_s_chip``);
+* ``flops``, ``bytes``  the whole step (``step.mfu_roofline``);
+* ``draw_bytes``    what the draws read and write on the device, 0 where
+  the device draws nothing (``draw.kernel_roofline`` is then silent);
+* ``gather_bytes``, ``opt_bytes``  the gathers' and the optimizer's part
+  of ``bytes``.
 """
 
 from __future__ import annotations
 
+import functools
+import importlib.util
+import os
 
-def step_costs(cfg: dict, per_chip_batch: int, device_sampling: bool) -> dict:
-    b = int(per_chip_batch)
-    f1, f2 = cfg["fanouts"]
-    feat, dim, classes = cfg["feature_dim"], cfg["dim"], cfg["num_classes"]
-    half = dim // 2 if cfg["concat"] else dim
-    width = cfg["graph"]["max_degree"]  # slab width W the draws read
-    itemsize = 4  # float32 tables, int32 ids
+REQUIRED = ("edges", "flops", "bytes", "draw_bytes", "gather_bytes",
+            "opt_bytes")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-    n0, n1, n2 = b, b * f1, b * f1 * f2
-    # dense layers: 2*m*k*n a matmul, two branches (self, neighbour mean)
-    fwd0 = 2 * (n0 + n1) * feat * half * 2      # layer 0 on hops 0 and 1
-    fwd1 = 2 * n0 * dim * half * 2              # layer 1 on hop 0
-    fwd_out = 2 * n0 * dim * classes            # classifier
-    # backward: dW everywhere; dX only where the input has a gradient
-    # (layer 0 reads constant features)
-    flops = 2 * fwd0 + 3 * fwd1 + 3 * fwd_out
 
-    gather_bytes = (n0 + n1 + n2) * feat * itemsize + n0 * cfg["label_dim"] * itemsize
-    # a draw reads, for every row drawn from, W ids and W cumulative
-    # weights, and writes the picks
-    draw_bytes = (n0 + n1) * width * 2 * itemsize + (n1 + n2) * itemsize
-    params = (2 * feat * half + 2 * dim * half + dim * classes + classes)
-    # Adam: read p, m, v and the gradient, write p, m, v
-    opt_bytes = 7 * params * itemsize
-    id_bytes = 0 if device_sampling else (n0 + n1 + n2) * itemsize
-    step_bytes = gather_bytes + opt_bytes + (
-        draw_bytes if device_sampling else id_bytes
-    )
-    return {
-        "flops": float(flops),
-        "bytes": float(step_bytes),
-        "gather_bytes": float(gather_bytes),
-        "draw_bytes": float(draw_bytes if device_sampling else 0),
-        "opt_bytes": float(opt_bytes),
-        "params": int(params),
-        "edges": int(n1 + n2),
-    }
+@functools.lru_cache(maxsize=32)
+def _cost_module(path: str):
+    spec = importlib.util.spec_from_file_location(
+        "benchmark_costs_" + os.path.basename(path)[:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def step_costs(cfg: dict, per_chip_batch: int, device_sampling: bool,
+               root: str = ROOT) -> dict:
+    """``root`` is the directory the configuration's paths start from
+    (the manifest's)."""
+    mod = _cost_module(os.path.abspath(os.path.join(root, cfg["costs"])))
+    out = mod.step_costs(cfg, int(per_chip_batch), bool(device_sampling))
+    bad = [k for k in REQUIRED if not out.get(k, -1) >= 0]
+    if bad or out["edges"] <= 0:
+        raise ValueError(
+            f"{cfg['costs']}: step_costs must give {REQUIRED}, none "
+            f"negative and edges above 0; wrong or missing: {bad or 'edges'}")
+    return out

@@ -1,8 +1,13 @@
 """Runs one cell of BENCHMARK.json once and returns the contract's line.
 
-Knows no cell, configuration, traffic mix or metric by name: the cell
-names a configuration file and a traffic file, the per-layer metrics
-name a reader under ``layers/``, the configuration names its reference.
+Knows no cell, configuration, traffic mix, metric or model family by
+name: the cell names a configuration file and a traffic file, the
+per-layer metrics name a reader under ``layers/``, and the configuration
+names its reference module (the adapter: the state the program's step
+takes, which of its leaves are compared, the draws, the reference's
+batches; ``sage_reference.py`` states the protocol) and its cost function
+(edges, FLOPs and bytes a step). Of the program's ``state`` the harness
+knows one key, ``consts``: the tables it builds and hands over.
 
 What the window drives is the program's own entry: flags parsed by
 ``run_loop.define_flags()`` over the preset defaults, ``build_graph`` ->
@@ -152,6 +157,15 @@ class Hook:
             "compiles": devprof.compile_summary(data)["compile_events"],
         }
 
+    @staticmethod
+    def _stop_recorder():
+        """Take the recorder out as the program's span sink. Its own
+        ``stop()`` compares two bound methods by identity and so never
+        does (the program's to mend: PERF.md section 7)."""
+        from euler_tpu import telemetry
+
+        telemetry.set_trace_sink(None)
+
     def _capture(self, step: int, loc: dict):
         import jax
 
@@ -166,14 +180,9 @@ class Hook:
             [np.asarray(jax.device_get(h)) for h in hops]
         )
         if step == 1:
-            self.captured["grad1"] = self.ref.first_gradient(
-                jax.device_get(state["opt_state"])
-            )
+            self.captured["grad1"] = self.ref.first_gradient(state)
         if step == CAPTURED_STEPS:
-            self.captured["params3"] = {
-                k: np.asarray(v) for k, v in self.ref.from_program(
-                    jax.device_get(state["params"])).items()
-            }
+            self.captured["end"] = self.ref.compared_state(state)
 
     def __call__(self, step: int):
         import jax
@@ -194,11 +203,18 @@ class Hook:
             if self.recorder is not None:
                 self.recorder.start()
             self.t_open_wall = time.time()
+            self.cpu_open = (time.process_time(), time.thread_time())
             self.t_open = time.perf_counter()
             self.stamps.append(self.t_open)
             return
         now = time.perf_counter()
         self.stamps.append(now)
+        if self.recorder is not None and step == self.trace_span[1] + 2:
+            # the capture has ended and the spans of its steps are in.
+            # The recorder is a ring of 200,000 events, about ten a step:
+            # left on, a window of 20,000 steps pushes the traced steps'
+            # spans out of it (seen on the chip at 51 s, PR 29)
+            self._stop_recorder()
         if now - self.t_open < self.seconds:
             return
         if self.trace_span is not None and step <= self.trace_span[1] + 1:
@@ -206,11 +222,13 @@ class Hook:
         loc = self._locals(sys._getframe(1))
         jax.block_until_ready(loc["last_loss"])
         self.t_close = time.perf_counter()
+        self.cpu_s = (time.process_time() - self.cpu_open[0],
+                      time.thread_time() - self.cpu_open[1])
         self.consts = loc["state"].get("consts")
         self.stamps[-1] = self.t_close
         self.steps_in_window = step - self.warmup
         if self.recorder is not None:
-            self.recorder.stop()
+            self._stop_recorder()
         self.at_close = self._snapshot()
         raise _WindowClosed()
 
@@ -325,8 +343,9 @@ class Prepared:
 
     def drive(self, seed: int, seconds: float, trace_dir: str | None = None,
               first_steps_only: bool = False) -> Hook:
-        """One ``train()`` call from ``--seed``: weights made here in one
-        jitted call, the root stream, the hook. Returns the hook with
+        """One ``train()`` call from ``--seed``: the state the reference
+        makes from it (weights in one jitted call), the root stream, the
+        hook. Returns the hook with
         what it took; ``self.consts`` is what train() handed back."""
         import jax
 
@@ -335,11 +354,9 @@ class Prepared:
         cell, cfg, args, ref = self.cell, self.cfg, self.args, self.ref
         key = jax.random.fold_in(
             jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
-        params0 = jax.jit(lambda k: ref.init_params(cfg, k))(key)
         opt = train_lib.get_optimizer(args.optimizer, args.learning_rate)
-        tree = ref.to_program(params0)
-        state = {"params": tree, "opt_state": opt.init(tree),
-                 "consts": self.consts}
+        start, state = ref.init_state(cfg, key, opt)
+        state["consts"] = self.consts
         self.consts = None
         trace_span = recorder = None
         if trace_dir:
@@ -351,8 +368,8 @@ class Prepared:
             recorder = TraceRecorder()
         hook = Hook(cell, ref, self.model, seconds, trace_span, recorder,
                     first_steps_only=first_steps_only)
-        hook.captured["params0"] = {
-            k: np.asarray(v) for k, v in params0.items()}
+        hook.captured["start"] = {
+            k: np.asarray(v) for k, v in start.items()}
         num_nodes, batch = self.spec.num_nodes, cell.global_batch
 
         def source_fn(step):
@@ -453,15 +470,18 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
         "count": len(devices),
         "memory_peak_bytes": int(memory_peak),
     }
-    edges_per_step = cell.global_batch * sum(
-        int(np.prod(cfg["fanouts"][: h + 1]))
-        for h in range(len(cfg["fanouts"]))
-    )
+    # what a step needs by shape, per chip: the configuration's own
+    # cost function says (the sampled edges are its too)
+    step_costs = costs.step_costs(
+        cfg, cell.global_batch // cell.chips,
+        str(cell.traffic["flags"].get("device_sampling")) == "true",
+        root=cell.root)
     values = {
-        "edges_per_s_chip": steps * edges_per_step / window_s / cell.chips,
+        "edges_per_s_chip": steps * step_costs["edges"] / window_s,
         "setup_s": setup_s,
     }
-    step_ms = np.diff(np.asarray(hook.stamps)) * 1e3
+    stamps = np.asarray(hook.stamps)
+    step_ms = np.diff(stamps) * 1e3
     result = {
         "correct": bool(correct),
         "attempted": int(steps),
@@ -484,9 +504,7 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
             xplane_path=xplane.latest_xplane(profile_dir) if is_chip
             else None,
             trace_steps=hook.trace_span[1] - hook.trace_span[0],
-            costs=costs.step_costs(
-                cfg, cell.global_batch // cell.chips,
-                str(cell.traffic["flags"].get("device_sampling")) == "true"),
+            costs=step_costs,
             peaks=peaks.chip_peaks(dev0.device_kind) if is_chip else None,
             memory_peak_bytes=memory_peak,
             first_step_compile=hook.first_step_compile,
@@ -522,7 +540,33 @@ def run_cell(manifest_path: str, workload: str, seed: int, seconds: float,
                      ("max", 100))
     }
     result["window_s"] = window_s
+    # CPU seconds of the window: of the whole process, and of the thread
+    # train() runs on (the hook is called there). Where a run is slow and
+    # the thread's seconds are not, the thread waited; for reading the
+    # spread of a host-bound cell. No metric, and the driver ignores it
+    result["window_cpu_s"] = {
+        "process": hook.cpu_s[0], "train_thread": hook.cpu_s[1]}
     result["check_s"] = check_s
+    if is_chip:
+        # what a shorter window of this same run would have read: the
+        # rate up to the first step that ends past each length. For
+        # choosing ``run_seconds``; no metric, and the driver ignores it
+        elapsed = stamps - stamps[0]
+        result["edges_per_s_chip_by_window_s"] = {
+            str(t): float(n * step_costs["edges"] / elapsed[n])
+            for t in (5, 10, 20, 30, 40)
+            for n in [int(np.searchsorted(elapsed, t))]
+            if 0 < n < len(elapsed)
+        }
+    # the journalled stalls of this process (the program's own journal:
+    # leaf, thread CPU time, collections), each with where in the window
+    # it ended, for run.py to print on standard error
+    from euler_tpu import telemetry
+
+    result["stall_journal"] = [
+        dict(e, window_s_at_end=round(e["end_us"] * 1e-6 - hook.t_open, 3))
+        for e in telemetry.stall_journal()
+    ]
     if calibrate:
         result["calibration"] = calibration_numbers(prep, hook)
     # compared numbers beside their limits: last on the line and on stderr

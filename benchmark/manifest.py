@@ -9,6 +9,7 @@ returns the list of what is wrong; an empty list passes.
 
 from __future__ import annotations
 
+import ast
 import json
 import os
 import re
@@ -30,6 +31,16 @@ WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "projection",
                "head_dim", "head_size", "expansion", "experts_per_tok",
                "feature_dim", "label_dim", "dim")
 MAX_BYTES = 64 * 1024
+# what a configuration's file names besides its sizes: the Python files
+# that hold what is particular to its model family, and the functions
+# the harness and check.py call there (benchmark/sage_reference.py and
+# benchmark/costs.py state what each does)
+CONFIG_FILES = {
+    "reference": ("init_state", "drawn_fanouts", "drawn_hops",
+                  "reference_batch", "batch_rows", "first_gradient",
+                  "compared_state", "train_steps"),
+    "costs": ("step_costs",),
+}
 # a full check: 2 + 14 x cells runs of run_seconds + 60 s, 2 x 90 s a cell
 # to compile, 1200 s spare, inside 43200 s, at the full 24 cells
 MAX_RUN_SECONDS = (43200 - 1200 - 24 * 180) // (2 + 14 * 24) - 60
@@ -61,6 +72,25 @@ def _unique(names, what, out):
         if n in seen:
             out.append(f"{what} {n!r} appears twice")
         seen.add(n)
+
+
+def bound_names(path: str) -> set:
+    """The names a Python file binds at its top level (functions,
+    classes, assignments, imports), read as text: nothing is imported."""
+    with open(path) as f:
+        tree = ast.parse(f.read(), path)
+    names = set()
+    for node in tree.body:
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            names.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            names.update((a.asname or a.name).split(".")[0]
+                         for a in node.names)
+        elif isinstance(node, (ast.Assign, ast.AnnAssign)):
+            targets = node.targets if isinstance(node, ast.Assign) \
+                else [node.target]
+            names.update(t.id for t in targets if isinstance(t, ast.Name))
+    return names
 
 
 def problems(path: str, traffic_dir: str | None = None,
@@ -146,11 +176,19 @@ def problems(path: str, traffic_dir: str | None = None,
         else:
             with open(os.path.join(root, file)) as f:
                 cfg = json.load(f)
-            ref = cfg.get("reference")
-            if not ref or not os.path.isfile(os.path.join(root, ref)):
-                out.append(what + f": plain reference {ref!r} not found")
-            elif not under_paths(os.path.normpath(ref)):
-                out.append(what + ": its reference lies outside paths")
+            for key, functions in CONFIG_FILES.items():
+                named = cfg.get(key)
+                if not named or not os.path.isfile(os.path.join(root, named)):
+                    out.append(what + f": its {key} file {named!r} "
+                               "not found")
+                elif not under_paths(os.path.normpath(named)):
+                    out.append(what + f": its {key} file lies outside paths")
+                else:
+                    lacks = sorted(set(functions) - bound_names(
+                        os.path.join(root, named)))
+                    if lacks:
+                        out.append(what + f": its {key} file {named!r} "
+                                   f"lacks {lacks}")
             if "limits" not in cfg:
                 out.append(what + ": no limits for the numbers compared")
 

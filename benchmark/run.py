@@ -44,6 +44,8 @@ def main(argv=None) -> int:
         print(f"benchmark: {e}; no result", file=sys.stderr)
         return 3
     sys.stdout.flush()
+    for entry in result.pop("stall_journal", ()):
+        print("stall " + json.dumps(entry), file=sys.stderr)
     for name, row in result["compared"].items():
         print(f"compared {name} {row['value']!r} limit {row['limit']!r}",
               file=sys.stderr)

@@ -13,6 +13,26 @@ reference is handed the ids the sampler drew (the draws themselves are
 judged against the graph in ``check.py``) and reads features and labels
 from the benchmark's own graph function.
 
+This module is also the configuration's adapter, the one protocol the
+harness and ``check.py`` know a model family by (a configuration's file
+names its module under ``"reference"``; the next family brings its own):
+
+* ``init_state(cfg, key, optimizer)``  (start, state): the reference-named
+  leaves everything is followed from, and the whole of what the program's
+  step takes but ``consts`` (the harness adds the tables it built);
+* ``drawn_fanouts(cfg)``, ``drawn_hops(model, state, batch)``  the chained
+  fan-outs of a step's draws and the ids each hop used;
+* ``reference_batch(spec, hops)``, ``batch_rows(cfg, batch, rows)``  what
+  the reference trains on, from the graph function, and its first-rows
+  cut (the planted faults: half of the batch, one chip's share);
+* ``first_gradient(state)``, ``compared_state(state)``  the named leaves
+  compared after step 1 (the gradient as the optimizer got it) and after
+  step 3 (against ``start``: the change), given the program's state;
+* ``train_steps(cfg, start, batches, dtype, precision)``  the reference.
+
+The work a step needs by shape (edges, FLOPs, bytes) is the cost function
+the configuration names under ``"costs"`` (``configs/graphsage_costs.py``).
+
 ``precision=None`` gives the same float32 step at the platform's default
 matmul precision: the arithmetic the configuration states (see
 ``check.py`` for why both are kept). ``dtype=jnp.bfloat16`` gives the
@@ -177,14 +197,53 @@ def from_program(tree) -> dict:
     return out
 
 
-def first_gradient(opt_state) -> dict:
+def init_state(cfg: dict, key, optimizer) -> tuple:
+    """(start, state): the benchmark's weights from ``key`` in one jitted
+    call, under the reference's names, and what the program's step takes
+    (``params`` and the optimizer's state over them; the harness adds
+    ``consts``)."""
+    start = jax.jit(lambda k: init_params(cfg, k))(key)
+    tree = to_program(start)
+    return start, {"params": tree, "opt_state": optimizer.init(tree)}
+
+
+def first_gradient(state) -> dict:
     """The first gradient as the optimizer got it, from the program's
     Adam state after one step: mu_1 = (1 - b1) * g_1."""
-    mu = opt_state[0].mu
+    mu = jax.device_get(state["opt_state"])[0].mu
     return {
         k: np.asarray(v) / (1.0 - ADAM_B1)
         for k, v in from_program(mu).items()
     }
+
+
+def compared_state(state) -> dict:
+    """The leaves whose change after the captured steps is compared:
+    the parameters, under the names of ``start``."""
+    return {
+        k: np.asarray(v)
+        for k, v in from_program(jax.device_get(state["params"])).items()
+    }
+
+
+def drawn_fanouts(cfg: dict) -> list:
+    """Hop h+1 holds ``drawn_fanouts[h]`` picks per row of hop h."""
+    return list(cfg["fanouts"])
+
+
+def reference_batch(spec, hops: list) -> tuple:
+    """(x0, x1, x2, labels): features of every hop and the roots' labels,
+    from the graph function (not from any table the program built)."""
+    x = [spec.features(np.asarray(h).reshape(-1)) for h in hops]
+    y = spec.labels(np.asarray(hops[0]).reshape(-1))
+    return x[0], x[1], x[2], y
+
+
+def batch_rows(cfg: dict, batch: tuple, rows: int) -> tuple:
+    """The batch of the first ``rows`` roots, with their draws."""
+    f1, f2 = cfg["fanouts"]
+    x0, x1, x2, y = batch
+    return (x0[:rows], x1[:rows * f1], x2[:rows * f1 * f2], y[:rows])
 
 
 @functools.lru_cache(maxsize=8)

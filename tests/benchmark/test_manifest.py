@@ -122,5 +122,72 @@ def test_broken_manifest_is_refused(tmp_path, breaker):
     assert _problems_of(tmp_path, m) != []
 
 
+# ---- what a configuration's file names: its reference, its costs ----
+
+def _with_config(tmp_path, edit):
+    """The committed manifest with its first configuration's file
+    replaced by an edited copy (under a path of the benchmark's)."""
+    m = copy.deepcopy(_load())
+    with open(os.path.join(ROOT, m["configs"][0]["file"])) as f:
+        cfg = json.load(f)
+    edit(cfg)
+    os.makedirs(tmp_path / "cfg")
+    (tmp_path / "cfg" / "edited.json").write_text(json.dumps(cfg))
+    (tmp_path / "cfg" / "short_reference.py").write_text(
+        "from benchmark.sage_reference import train_steps  # noqa: F401\n"
+        "def init_state(cfg, key, optimizer): ...\n")
+    m["paths"] = m["paths"] + ["cfg"]
+    m["configs"][0]["file"] = "cfg/edited.json"
+    return _problems_of(tmp_path, m)
+
+
+def _no_costs_key(cfg):
+    del cfg["costs"]
+
+
+def _costs_file_missing(cfg):
+    cfg["costs"] = "benchmark/configs/no_such_costs.py"
+
+
+def _reference_file_missing(cfg):
+    cfg["reference"] = "benchmark/configs/no_such_reference.py"
+
+
+def _costs_outside_paths(cfg):
+    cfg["costs"] = "bench.py"
+
+
+def _reference_lacks_functions(cfg):
+    cfg["reference"] = "cfg/short_reference.py"
+
+
+@pytest.mark.parametrize("edit,says", [
+    (_no_costs_key, "costs file None not found"),
+    (_costs_file_missing, "no_such_costs.py' not found"),
+    (_reference_file_missing, "no_such_reference.py' not found"),
+    (_costs_outside_paths, "costs file lies outside paths"),
+    (_reference_lacks_functions, "lacks ['batch_rows', 'compared_state'"),
+], ids=lambda x: x.__name__.lstrip("_") if callable(x) else None)
+def test_configuration_whose_named_files_are_wrong_is_refused(
+        tmp_path, edit, says):
+    found = _with_config(tmp_path, edit)
+    assert any(says in p for p in found), found
+
+
+def test_configuration_with_both_files_passes(tmp_path):
+    assert _with_config(tmp_path, lambda cfg: None) == []
+
+
+def test_the_toy_store_family_names_whole_files():
+    """The second family's reference and cost function bind every
+    function the harness calls (read as text, as the manifest does)."""
+    toy = os.path.join(ROOT, "tests", "benchmark", "toy")
+    with open(os.path.join(toy, "toy_store.json")) as f:
+        cfg = json.load(f)
+    for key, functions in manifest.CONFIG_FILES.items():
+        path = os.path.join(os.path.dirname(toy), cfg[key])
+        assert set(functions) <= manifest.bound_names(path), key
+
+
 def test_unbroken_copy_passes(tmp_path):
     assert _problems_of(tmp_path, _load()) == []

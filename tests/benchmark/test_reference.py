@@ -52,7 +52,7 @@ def test_reference_step_matches_the_programs_step(tmp_path, name):
         hops = [np.asarray(h["gids"]) for h in batch["hops"]]
         state, loss, _ = step(state, batch)
         losses.append(float(loss))
-        batches.append(check.reference_batch(spec, hops))
+        batches.append(ref.reference_batch(spec, hops))
     ref_losses, ref_grad, ref_params = ref.train_steps(
         cfg, {k: jnp.asarray(v) for k, v in params0.items()}, batches)
     np.testing.assert_allclose(losses, ref_losses, rtol=2e-5)
@@ -129,7 +129,10 @@ def test_worst_leaf_gap_by_hand():
             "c": np.array([1.0])}
     # norms 5, 1e-6, 1; median 1: leaf a reads 0.05/5, leaf b 1e-6/1
     assert check.worst_leaf_gap(prog, refd) == pytest.approx(0.01)
-    assert check.moving_leaves(refd) == {"a", "c"}
+    assert check.still_leaves(refd) == {"b"}
+    # with leaf a dropped the median is of b and c: 1e-6 / 0.5000005
+    assert check.worst_leaf_gap(prog, refd, drop={"a"}) == pytest.approx(
+        2e-6, rel=1e-5)
 
 
 def test_a_sampler_stuck_on_one_slot_reads_half():

@@ -46,6 +46,80 @@ def test_scope_of_op_name(op_name, scope):
     assert scopes.scope_of_op_name(op_name) == scope
 
 
+# ---------------------------------------------------------------------------
+# which names are scopes: the readers say, as data
+# ---------------------------------------------------------------------------
+
+
+def test_every_scope_of_the_program_is_claimed_by_exactly_one_reader():
+    """In place of the old pin (a tuple here equal to the program's): the
+    universe is what the readers under layers/ declare. Each scope of the
+    program has one reader, so the scope metrics, ``step.unscoped_ms``
+    and ``mesh.collective_ms`` add up to ``step.device_busy_ms``; a
+    scope claimed twice is refused where the declarations are read."""
+    from euler_tpu import trace as TR
+
+    claimed = scopes.declared_scopes()
+    assert set(TR.STEP_SCOPES) <= set(claimed)
+    assert scopes.STEP_HLO_FILE == TR.STEP_HLO_FILE
+    assert scopes.UNSCOPED not in claimed and "collective" not in claimed
+    for scope, name in claimed.items():
+        assert scope in reader(name).SCOPES
+    # every reader that declares scopes is a metric of the manifest
+    import json
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        metrics = {m["name"] for m in json.load(f)["per_layer"]}
+    assert set(claimed.values()) <= metrics
+
+
+def _layers_with(tmp_path, **files):
+    d = tmp_path / "layers"
+    d.mkdir()
+    for fn in os.listdir(LAYERS):
+        if fn.endswith(".py"):
+            (d / fn).write_text(open(os.path.join(LAYERS, fn)).read())
+    for name, text in files.items():
+        (d / (name.replace("__", ".") + ".py")).write_text(text)
+    return str(d)
+
+
+def test_a_new_reader_file_opens_the_set(tmp_path):
+    """A PR that names ``store_read`` and ``store_update`` in the
+    program's step adds a reader file that claims them; nothing else."""
+    d = _layers_with(tmp_path, step__store_ms=(
+        "from benchmark import scopes\n\n"
+        'SCOPES = ("store_read", "store_update")\n\n\n'
+        "def read(ctx):\n    return scopes.scopes_ms(ctx, *SCOPES)\n"))
+    universe = scopes.declared_scopes(d)
+    assert universe["store_read"] == universe["store_update"] == \
+        "step.store_ms"
+    assert set(scopes.declared_scopes()) < set(universe)
+    path = "jit(train_step)/transpose(jvp(M))/store_update/scatter-add"
+    assert scopes.scope_of_op_name(path) is None
+    assert scopes.scope_of_op_name(path, universe) == "store_update"
+    table = scopes.parse_hlo_scopes(
+        'ENTRY %main (a: f32[8]) -> f32[8] {\n'
+        '  %scatter.1 = f32[8]{0} scatter(%a), metadata={op_name="' + path
+        + '"}\n}\n', universe)
+    assert table["scatter.1"] == "store_update"
+
+
+def test_a_scope_claimed_twice_is_refused(tmp_path):
+    d = _layers_with(tmp_path, step__again_ms='SCOPES = ("dense",)\n')
+    with pytest.raises(ValueError, match="claimed by both"):
+        scopes.declared_scopes(d)
+
+
+def test_the_old_name_is_the_readers_union_in_any_order():
+    from euler_tpu import trace as TR
+
+    assert scopes.STEP_SCOPES == TR.STEP_SCOPES
+    assert scopes.STEP_SCOPES == tuple(reversed(TR.STEP_SCOPES))
+    assert scopes.STEP_SCOPES != TR.STEP_SCOPES[1:]
+    with pytest.raises(AttributeError):
+        scopes.NO_SUCH_NAME
+
+
 HLO_BY_HAND = """\
 HloModule jit_train_step, is_scheduled=true, entry_computation_layout={(f32[8,4]{1,0:T(8,128)})->f32[8,4]{1,0}}
 
@@ -140,7 +214,7 @@ def test_recorded_scopes_add_up_to_the_busy_time(recorded):
     cap, table = recorded
     lane = cap.fullest()
     sec = scopes.lane_scope_seconds(lane, table)
-    assert set(sec) <= set(scopes.STEP_SCOPES) | {"unscoped", "collective"}
+    assert set(sec) <= set(scopes.declared_scopes()) | {"unscoped", "collective"}
     assert sum(sec.values()) == pytest.approx(lane.busy_ns() * 1e-9, rel=1e-9)
     assert cap.busy_s == pytest.approx(0.032889798, rel=1e-6)
 
@@ -224,7 +298,9 @@ def test_a_window_without_a_stall_reads_zero_not_nothing():
 
 
 SPANS_BY_HAND = [
-    ("fence", 900, 90, 6, "MainThread"),   # the step before: its fence only
+    # the step the recorder was switched on in: its fence only. Left out
+    ("step", 0, 1000, 6, "MainThread"),
+    ("fence", 900, 90, 6, "MainThread"),
     # step 7: 1000..2000 µs; leaves cover 1000..1990 but for 1500..1504
     ("step", 1000, 1000, 7, "MainThread"),
     ("input_other", 1000, 10, 7, "MainThread"),
@@ -247,6 +323,8 @@ def test_unspanned_is_the_step_less_the_union_of_its_leaves():
     assert spans.unspanned_ms(SPANS_BY_HAND) == pytest.approx(0.007)
     ctx = _ctx(phases_close={"input_other": (2, 20)}, events=SPANS_BY_HAND)
     assert reader("trainer.unspanned_ms").read(ctx) == pytest.approx(0.007)
+    # a record of one step has no later one to stand on: it is read whole
+    assert spans.unspanned_ms(SPANS_BY_HAND[:2]) == pytest.approx(0.910)
 
 
 def test_launch_gap_and_fence_return_by_hand():
