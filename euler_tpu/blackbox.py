@@ -58,6 +58,15 @@ def install(postmortem_dir: str | None = None, shard: int = -1,
         raise RuntimeError(lib().eg_last_error().decode())
 
 
+def stop_sampler() -> None:
+    """End the resource sampler thread ``install`` started (within
+    50 ms); handlers, dump directory and history stay, and the next
+    ``install`` starts one again. For a process that outlives the run
+    that armed the recorder: a live sampler keeps stamping its ticks
+    into every later stall journal."""
+    lib().eg_blackbox_stop_sampler()
+
+
 def blackbox_enabled() -> bool:
     return lib().eg_blackbox_enabled() == 1
 

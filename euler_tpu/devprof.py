@@ -12,10 +12,12 @@ so metrics_text(), the STATS scrape, blackbox postmortems and
 scripts/metrics_dump.py report the device plane with zero new plumbing:
 
     devprof.install()                once per process, before first jit
+    devprof.uninstall()              take the listener out, stop the sampler
     fn = devprof.watch(jitted, "loss_step")   recompile attribution
     devprof.recompile_ledger()       journaled recompiles, newest last
     devprof.sample_device_mem()      one-shot HBM/buffer gauge refresh
     devprof.record_feature_table(w, stored)   feature-table width gauges
+    devprof.record_store_table(w, stored)     per-node store width gauges
     devprof.count_h2d(batch)         transfer-byte bracketing
     devprof.set_devprof(False)       process-global kill-switch
 
@@ -101,6 +103,24 @@ def install(sample_ms: int = 0) -> None:
             _installed = True
     if sample_ms > 0:
         start_sampler(sample_ms)
+
+
+def uninstall() -> None:
+    """Disarm what ``install`` armed (idempotent): take the compile
+    listener out of jax.monitoring and stop the memory sampler. For a
+    process that goes on after the run that armed the plane (a test
+    worker): a listener left in records ``compile`` spans into whatever
+    runs next."""
+    global _installed
+    with _lock:
+        if _installed:
+            import jax.monitoring
+
+            jax.monitoring.unregister_event_duration_listener(
+                _on_event_duration
+            )
+            _installed = False
+    stop_sampler()
 
 
 def setup(enabled: bool = True, sample_ms: int = 0) -> bool:
@@ -304,6 +324,16 @@ def record_feature_table(width: int, stored_width: int) -> None:
     build_consts calls this once per table it builds)."""
     if _enabled:
         lib().eg_devprof_set_feature_table(int(width), int(stored_width))
+
+
+def record_store_table(width: int, stored_width: int) -> None:
+    """The gauges ``store_table_width`` / ``store_table_stored_width`` of
+    the resource section: the width of a training state's per-node
+    stores and the lanes a stored row takes in device memory, 0 where the
+    device keeps the table column-major (models/base.py
+    ScalableStoreModel.describe_state, once per ``train()``)."""
+    if _enabled:
+        lib().eg_devprof_set_store_table(int(width), int(stored_width))
 
 
 def start_sampler(period_ms: int = 1000) -> None:

@@ -248,17 +248,25 @@ void Blackbox::AppendHistory(const ResourceSample& s) {
   hist_head_.store(h + 1, std::memory_order_release);
 }
 
-void Blackbox::SamplerLoop() {
-  while (true) {
+void Blackbox::SamplerLoop(uint64_t gen) {
+  while (sampler_gen_.load(std::memory_order_acquire) == gen) {
     // stamped for the training loop's stall journal (eg_phase.h)
     PhaseStats::Global().Tick(kJobBlackboxSampler, false);
     AppendHistory(SampleResources());
     PhaseStats::Global().Tick(kJobBlackboxSampler, true);
     int ms = sample_ms_.load(std::memory_order_relaxed);
-    for (int slept = 0; slept < ms; slept += 50)
+    for (int slept = 0;
+         slept < ms && sampler_gen_.load(std::memory_order_acquire) == gen;
+         slept += 50)
       std::this_thread::sleep_for(std::chrono::milliseconds(
           std::min(50, ms - slept)));
   }
+}
+
+void Blackbox::StopSampler() {
+  std::lock_guard<std::mutex> l(install_mu_);
+  sampler_gen_.fetch_add(1, std::memory_order_acq_rel);
+  sampler_running_.store(false);
 }
 
 bool Blackbox::Install(const std::string& postmortem_dir, int shard,
@@ -301,15 +309,16 @@ bool Blackbox::Install(const std::string& postmortem_dir, int shard,
       ::sigaction(sig, &sa, nullptr);
   }
   if (!sampler_running_.exchange(true)) {
-    std::thread([this] {
+    uint64_t gen = sampler_gen_.fetch_add(1, std::memory_order_acq_rel) + 1;
+    std::thread([this, gen] {
       try {
-        SamplerLoop();
+        SamplerLoop(gen);
       } catch (...) {
         // std::terminate barrier (eg-lint: thread-catch): a dead
         // sampler freezes the resource history; the postmortem still
         // dumps rings + counters
       }
-    }).detach();  // process-lifetime thread; never joined
+    }).detach();  // never joined; lives until StopSampler
     // seed the history immediately so a crash (or scrape) right after
     // init already has one sample
     AppendHistory(SampleResources());
@@ -608,6 +617,12 @@ void Blackbox::ResourceJsonBody(std::string* out) {
   out->push_back(',');
   AppendKey(out, "feature_table_stored_width");
   AppendI64(out, Devprof::Global().feature_table_stored_width());
+  out->push_back(',');
+  AppendKey(out, "store_table_width");
+  AppendI64(out, Devprof::Global().store_table_width());
+  out->push_back(',');
+  AppendKey(out, "store_table_stored_width");
+  AppendI64(out, Devprof::Global().store_table_stored_width());
   out->push_back(',');
   AppendKey(out, "history_depth");
   uint64_t hh = hist_head_.load(std::memory_order_acquire);

@@ -189,6 +189,12 @@ class Blackbox {
     std::lock_guard<std::mutex> l(install_mu_);
     return error_;
   }
+  // End the resource sampler thread at its next wake-up (within 50 ms);
+  // handlers, directory and history stay, and the next Install starts a
+  // sampler again. For a process that goes on after the run that armed
+  // the recorder (a test worker): a live sampler keeps stamping its job
+  // ticks into every later stall journal.
+  void StopSampler();
   int shard() const { return shard_.load(std::memory_order_relaxed); }
 
   // One fresh resource sample read from /proc (NOT signal-safe; the
@@ -228,7 +234,7 @@ class Blackbox {
  private:
   Blackbox() = default;
   BlackboxRing* ThreadRing();
-  void SamplerLoop();
+  void SamplerLoop(uint64_t gen);
   void AppendHistory(const ResourceSample& s);
   // `{rss_bytes,...,history_depth}` object body shared by the live
   // surfaces (NOT the signal path — it samples /proc).
@@ -250,6 +256,9 @@ class Blackbox {
   std::atomic<bool> installed_{false};
   std::atomic<int> sample_ms_{1000};
   std::atomic<bool> sampler_running_{false};
+  // bumped by every start and stop: a sampler thread lives while the
+  // generation it was started under is the current one
+  std::atomic<uint64_t> sampler_gen_{0};
   // Install/config strings: written only under install_mu_ (Install is
   // the cold init path); surfaces that read them take the same lock.
   mutable std::mutex install_mu_;

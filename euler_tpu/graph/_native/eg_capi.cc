@@ -944,6 +944,15 @@ void eg_devprof_set_feature_table(int64_t width, int64_t stored_width) {
   EG_API_GUARD()
 }
 
+// The per-node stores' logical and stored widths (train() sets them
+// once, through models/base.py ScalableStoreModel.describe_state).
+void eg_devprof_set_store_table(int64_t width, int64_t stored_width) {
+  try {
+    eg::Devprof::Global().SetStoreTable(width, stored_width);
+  }
+  EG_API_GUARD()
+}
+
 // Refresh the live serve-SLO gauges (µs): euler_tpu/serving/slo.py
 // pushes its windowed p50/p99 and lifetime violations every few
 // records, so a scrape reads serving latency without draining.
@@ -1168,6 +1177,15 @@ int eg_blackbox_init(const char* postmortem_dir, int shard, int sample_ms) {
     return 0;
   }
   EG_API_GUARD(-1)
+}
+
+// End the resource sampler thread (blackbox.stop_sampler); the next
+// eg_blackbox_init starts one again.
+void eg_blackbox_stop_sampler() {
+  try {
+    eg::Blackbox::Global().StopSampler();
+  }
+  EG_API_GUARD()
 }
 
 // One app-level flight-recorder event from Python (the run_loop /

@@ -42,6 +42,11 @@ void Devprof::SetFeatureTable(int64_t width, int64_t stored_width) {
   feature_stored_width_.store(stored_width, std::memory_order_relaxed);
 }
 
+void Devprof::SetStoreTable(int64_t width, int64_t stored_width) {
+  store_width_.store(width, std::memory_order_relaxed);
+  store_stored_width_.store(stored_width, std::memory_order_relaxed);
+}
+
 void Devprof::SetServeSlo(uint64_t p50_us, uint64_t p99_us,
                           uint64_t violations, uint64_t count) {
   slo_p50_us_.store(p50_us, std::memory_order_relaxed);
@@ -72,8 +77,9 @@ void Devprof::Reset() {
   mem_bytes_.store(0, std::memory_order_relaxed);
   mem_peak_bytes_.store(0, std::memory_order_relaxed);
   buffers_.store(0, std::memory_order_relaxed);
-  // the feature-table widths stay: they are set once, when the table is
-  // built, and the table outlives a reset of the measurements
+  // the feature-table and store-table widths stay: they are set once,
+  // when the tables are built, and the tables outlive a reset of the
+  // measurements
   slo_p50_us_.store(0, std::memory_order_relaxed);
   slo_p99_us_.store(0, std::memory_order_relaxed);
   slo_violations_.store(0, std::memory_order_relaxed);

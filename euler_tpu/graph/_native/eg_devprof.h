@@ -48,6 +48,13 @@ class Devprof {
   // built.
   void SetFeatureTable(int64_t width, int64_t stored_width);
 
+  // Widths of the per-node historical-embedding stores of the training
+  // state (models/base.py ScalableStoreModel): the model's dim and the
+  // lanes a stored row takes in device memory (0: the device keeps the
+  // table column-major, so a row is not contiguous). 0/0 until a state
+  // with stores is handed to train().
+  void SetStoreTable(int64_t width, int64_t stored_width);
+
   int64_t mem_bytes() const {
     return mem_bytes_.load(std::memory_order_relaxed);
   }
@@ -62,6 +69,12 @@ class Devprof {
   }
   int64_t feature_table_stored_width() const {
     return feature_stored_width_.load(std::memory_order_relaxed);
+  }
+  int64_t store_table_width() const {
+    return store_width_.load(std::memory_order_relaxed);
+  }
+  int64_t store_table_stored_width() const {
+    return store_stored_width_.load(std::memory_order_relaxed);
   }
 
   // Append `,"serve_slo":{"p50_us":..,"p99_us":..,"violations":..,
@@ -79,6 +92,8 @@ class Devprof {
   std::atomic<int64_t> buffers_{0};
   std::atomic<int64_t> feature_width_{0};
   std::atomic<int64_t> feature_stored_width_{0};
+  std::atomic<int64_t> store_width_{0};
+  std::atomic<int64_t> store_stored_width_{0};
   std::atomic<uint64_t> slo_p50_us_{0};
   std::atomic<uint64_t> slo_p99_us_{0};
   std::atomic<uint64_t> slo_violations_{0};

@@ -108,6 +108,7 @@ def _load() -> ctypes.CDLL:
     _sig(L.eg_serve_batch, None, [c.c_uint64])
     _sig(L.eg_devprof_set_mem, None, [c.c_int64, c.c_int64])
     _sig(L.eg_devprof_set_feature_table, None, [c.c_int64, c.c_int64])
+    _sig(L.eg_devprof_set_store_table, None, [c.c_int64, c.c_int64])
     _sig(L.eg_serve_slo_set, None,
          [c.c_uint64, c.c_uint64, c.c_uint64, c.c_uint64])
     _sig(L.eg_telemetry_enabled, c.c_int, [])
@@ -137,6 +138,7 @@ def _load() -> ctypes.CDLL:
     _sig(L.eg_blackbox_enabled, c.c_int, [])
     _sig(L.eg_blackbox_set_enabled, None, [c.c_int])
     _sig(L.eg_blackbox_init, c.c_int, [c.c_char_p, c.c_int, c.c_int])
+    _sig(L.eg_blackbox_stop_sampler, None, [])
     _sig(
         L.eg_blackbox_record,
         None,

@@ -593,6 +593,11 @@ class Model:
             state["consts"] = consts
         return state
 
+    def describe_state(self, state) -> None:
+        """Hook ``train()`` calls once, on the state as it sits on the
+        devices: say (gauges, route log) what per-node tables the state
+        holds besides ``consts``. Default: none."""
+
     def make_train_step(self, optimizer):
         """Pure (state, batch) -> (state, loss, metric); jitted by the
         trainer with params replicated and batch sharded over 'data'. The
@@ -646,6 +651,13 @@ class Model:
         return embed_step
 
 
+def last_occurrence(ids):
+    """[n] bool: True where no later row of ``ids`` holds the same id."""
+    pos = jnp.arange(ids.shape[0])
+    later_same = (ids[:, None] == ids[None, :]) & (pos[None, :] > pos[:, None])
+    return ~later_same.any(axis=1)
+
+
 class ScalableStoreModel(Model):
     """Shared training machinery for the Scalable{GCN,Sage} family
     (reference encoders.py:218-519 + the gcn.py/graphsage.py session hooks).
@@ -660,6 +672,14 @@ class ScalableStoreModel(Model):
          sum(node_emb * stale_grad)
       4. scatter-add d(loss + store_loss)/d(store_read) at the neighbors
       5. write fresh activations back to the stores
+    A root drawn more than once into a batch has one fresh activation per
+    occurrence (its neighbors are drawn anew each time). The reference's
+    scatter_update leaves open which one stays; here the last occurrence
+    in batch order is written and the others are dropped, so the step is a
+    function of its batch whatever order the device writes rows in (the
+    host-sampled and device-sampled batches share this step). Steps 1 and
+    4 need no rule: the clear writes equal rows, and the clear comes
+    before the add for a root that is also a neighbor.
     Requires: self.num_layers, self.dim, self.max_id,
     self.store_learning_rate, self.store_init_maxval, and a module exposing
     forward_train(batch, store_reads) -> (loss, metric, node_embeddings, emb)
@@ -715,12 +735,13 @@ class ScalableStoreModel(Model):
             batch = self._expand_batch(batch, consts)
             node_ids = batch["node_ids"]
             neigh_ids = batch["neigh_ids"]
-            store_reads = [s[neigh_ids] for s in state["stores"]]
-            stale = [gs[node_ids] for gs in state["grad_stores"]]
-            grad_stores = [
-                gs.at[node_ids].set(jnp.zeros_like(s))
-                for gs, s in zip(state["grad_stores"], stale)
-            ]
+            with jax.named_scope("stores_read"):
+                store_reads = [s[neigh_ids] for s in state["stores"]]
+                stale = [gs[node_ids] for gs in state["grad_stores"]]
+                grad_stores = [
+                    gs.at[node_ids].set(jnp.zeros_like(s))
+                    for gs, s in zip(state["grad_stores"], stale)
+                ]
 
             def forward(params, reads):
                 return module.apply(
@@ -740,10 +761,11 @@ class ScalableStoreModel(Model):
                     state["params"], store_reads
                 )
             )
-            updates, opt_state = optimizer.update(
-                gp_main, state["opt_state"], state["params"]
-            )
-            params = optax.apply_updates(state["params"], updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = optimizer.update(
+                    gp_main, state["opt_state"], state["params"]
+                )
+                params = optax.apply_updates(state["params"], updates)
 
             if num_stores > 0:
 
@@ -757,21 +779,31 @@ class ScalableStoreModel(Model):
                 gp_store, gr_store = jax.grad(
                     store_loss_fn, argnums=(0, 1)
                 )(state["params"], store_reads)
-                supdates, store_opt_state = store_opt.update(
-                    gp_store, state["store_opt_state"], params
-                )
-                params = optax.apply_updates(params, supdates)
-                grad_stores = [
-                    gs.at[neigh_ids].add(gm + gss)
-                    for gs, gm, gss in zip(grad_stores, gr_main, gr_store)
-                ]
+                with jax.named_scope("optimizer"):
+                    supdates, store_opt_state = store_opt.update(
+                        gp_store, state["store_opt_state"], params
+                    )
+                    params = optax.apply_updates(params, supdates)
+                with jax.named_scope("stores_write"):
+                    grad_stores = [
+                        gs.at[neigh_ids].add(gm + gss)
+                        for gs, gm, gss in zip(
+                            grad_stores, gr_main, gr_store
+                        )
+                    ]
             else:
                 store_opt_state = state["store_opt_state"]
 
-            stores = [
-                s.at[node_ids].set(jax.lax.stop_gradient(emb))
-                for s, emb in zip(state["stores"], node_embs)
-            ]
+            with jax.named_scope("stores_write"):
+                # rows past the store's end are dropped: every occurrence
+                # of a root but its last writes nothing
+                keep = last_occurrence(node_ids)
+                stores = [
+                    s.at[jnp.where(keep, node_ids, s.shape[0])].set(
+                        jax.lax.stop_gradient(emb), mode="drop"
+                    )
+                    for s, emb in zip(state["stores"], node_embs)
+                ]
             new_state = {
                 "params": params,
                 "opt_state": opt_state,
@@ -784,6 +816,31 @@ class ScalableStoreModel(Model):
             return new_state, loss, metric
 
         return train_step
+
+    def describe_state(self, state) -> None:
+        """The stores' width and what the device made of it: gauges
+        ``store_table_width`` / ``store_table_stored_width`` and one
+        route-log line. The stores are [n, dim] float32 with dim under a
+        lane tile; whether a row lies contiguous (and then pads to the
+        tile's lanes) or the table lies column-major is the device's
+        choice, read here from the arrays' own layout."""
+        stores = state.get("stores") or []
+        if not stores:
+            return
+        table = stores[0]
+        layout = table.format.layout
+        rows_major = tuple(layout.major_to_minor) == (0, 1)
+        lanes = layout.tiling[0][-1] if layout.tiling else 1
+        stored = -(-self.dim // lanes) * lanes if rows_major else 0
+        devprof.record_store_table(self.dim, stored)
+        log.info(
+            "store table: [%d, %d] %s x %d (stores and gradient stores), "
+            "device layout major_to_minor=%s tiling=%s: %s",
+            table.shape[0], self.dim, table.dtype, 2 * len(stores),
+            tuple(layout.major_to_minor), tuple(layout.tiling),
+            f"rows contiguous, stored {stored} wide" if rows_major
+            else "column-major, rows not contiguous",
+        )
 
     def _expand_batch(self, batch, consts):
         """Hook: turn a device-sampling batch (roots + seed) into the

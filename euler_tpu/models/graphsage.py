@@ -371,10 +371,11 @@ class ScalableSage(base.ScalableStoreModel):
         from euler_tpu.graph import device as device_graph
 
         roots = batch["roots"]
-        key = jax.random.PRNGKey(batch["seed"][0])
-        neigh = device_graph.sample_neighbor(
-            consts["adj"][self._adj_key], roots, key, self.fanout
-        ).reshape(-1)
+        with jax.named_scope("draw"):
+            key = jax.random.PRNGKey(batch["seed"][0])
+            neigh = device_graph.sample_neighbor(
+                consts["adj"][self._adj_key], roots, key, self.fanout
+            ).reshape(-1)
         node_feats = {"gids": roots}
         neigh_feats = {"gids": neigh}
         if self.use_id:

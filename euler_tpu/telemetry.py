@@ -539,6 +539,14 @@ _RESOURCE_FAMILIES = {
                                    "Width the feature table's rows are "
                                    "stored at: feature_dim rounded up to "
                                    "128 lanes, so rows are contiguous"),
+    "store_table_width": ("eg_store_table_width",
+                          "Logical width (dim) of the per-node "
+                          "historical-embedding stores of the training "
+                          "state; 0 = the state has none"),
+    "store_table_stored_width": ("eg_store_table_stored_width",
+                                 "Lanes a stored row of a store takes in "
+                                 "device memory; 0 = the device keeps the "
+                                 "store column-major (rows not contiguous)"),
 }
 
 

@@ -58,10 +58,14 @@ ALIGN_PREFIX = "eg_align:"
 # device op of the step carries the scope it was traced under in its HLO
 # ``op_name`` (the backward pass as ``transpose(jvp(...))/<scope>/...``),
 # which is how a capture's device time is split by what the program was
-# doing and not by XLA's instruction names. The benchmark keeps its own
-# copy (benchmark/scopes.py); a test pins the two equal.
+# doing and not by XLA's instruction names. The benchmark's readers
+# (benchmark/layers/) each claim the scopes they sum; a test holds every
+# name here to exactly one reader. ``stores_read`` and ``stores_write``
+# are the per-node stores of models/base.py ScalableStoreModel: the
+# gathers from the stores and gradient stores with the clearing set, and
+# the scatter-add of gradients with the set of fresh activations.
 STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
-               "dense", "loss", "optimizer")
+               "dense", "loss", "optimizer", "stores_read", "stores_write")
 
 # File ``train(profile_dir=)`` leaves the compiled step's HLO text in,
 # beside the capture: the map from a trace event's instruction name to
@@ -101,7 +105,8 @@ class TraceRecorder:
         return self
 
     def stop(self) -> None:
-        if _telemetry._trace_sink is self._on_phase:
+        # ``==``: two reads of a bound method are equal, never identical
+        if _telemetry._trace_sink == self._on_phase:
             _telemetry.set_trace_sink(None)
 
     def _on_phase(self, phase: str, us: float, step: int | None,

@@ -238,6 +238,7 @@ def train(
     state = pad_tables_for_mesh(state, mesh)
     shardings = state_sharding(mesh, state)
     state = put_global(state, shardings)
+    model.describe_state(state)
 
     ckpt = None
     start_step = 0

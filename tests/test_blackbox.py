@@ -39,6 +39,9 @@ def _clean_slate():
     B.blackbox_reset()
     B.set_blackbox(True)
     yield
+    # a test that armed the recorder in this process started its
+    # sampler: end it, or it ticks into every later file's stall journal
+    B.stop_sampler()
     native.fault_clear()
     native.reset_counters()
     T.telemetry_reset()

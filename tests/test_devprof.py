@@ -27,6 +27,10 @@ def _clean_slate():
     set_telemetry(True)
     devprof.set_devprof(True)
     yield
+    # leave the worker as it was found: the compile listener out and no
+    # sampler ticking, or the file that runs next in this worker records
+    # ``compile`` spans and sampler ticks it never asked for
+    devprof.uninstall()
     native.reset_counters()
     telemetry_reset()
     devprof.devprof_reset()
@@ -220,6 +224,58 @@ def test_feature_table_gauges_and_route_log(fixture_dir, caplog):
     devprof.set_devprof(False)
     devprof.record_feature_table(7, 128)
     assert T.telemetry_json()["resource"]["feature_table_width"] == 50
+
+
+def test_store_table_gauges_and_route_log(fixture_dir, caplog):
+    """train() says once what the per-node stores of the state are and
+    how the device keeps them (one route-log line) and sets the two
+    width gauges, which outlive a reset of the measurements and reach
+    metrics_text(). A model without stores says nothing."""
+    import logging
+
+    import jax
+
+    import euler_tpu
+    from euler_tpu import telemetry as T
+    from euler_tpu import train as train_lib
+    from euler_tpu.models import ScalableSage, SupervisedGraphSage
+
+    model = ScalableSage(
+        label_idx=2, label_dim=3, edge_type=[0, 1], fanout=2, num_layers=2,
+        dim=8, max_id=16, concat=True, feature_idx=0, feature_dim=2,
+        device_features=True,
+    )
+    g = euler_tpu.Graph(directory=fixture_dir)
+    try:
+        with caplog.at_level(logging.INFO, logger="euler_tpu"):
+            train_lib.train(model, g, lambda s: g.sample_node(8, -1),
+                            num_steps=1, log_every=1)
+        first = caplog.text
+        caplog.clear()
+        with caplog.at_level(logging.INFO, logger="euler_tpu"):
+            SupervisedGraphSage(
+                label_idx=2, label_dim=3, metapath=[[0, 1]], fanouts=[2],
+                dim=8, feature_idx=0, feature_dim=2, max_id=16,
+            ).describe_state({"params": {}})
+    finally:
+        g.close()
+    assert "store table:" not in caplog.text
+    # the CPU keeps a [18, 8] table row-major and untiled: 8 lanes a row
+    assert ("store table: [18, 8] float32 x 2 (stores and gradient "
+            "stores), device layout major_to_minor=(0, 1) tiling=(): rows "
+            "contiguous, stored 8 wide") in first
+    assert first.count("store table:") == 1
+    T.telemetry_reset()
+    res = T.telemetry_json()["resource"]
+    assert res["store_table_width"] == 8
+    assert res["store_table_stored_width"] == 8
+    text = euler_tpu.metrics_text()
+    assert "eg_store_table_width 8" in text
+    assert "eg_store_table_stored_width 8" in text
+    # a column-major table reads 0: its rows are not contiguous
+    devprof.record_store_table(64, 0)
+    assert T.telemetry_json()["resource"]["store_table_stored_width"] == 0
+    assert jax.devices()[0].platform == "cpu"
 
 
 # ------------------------------------------------------ serve guard drill

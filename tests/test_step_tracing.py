@@ -13,9 +13,12 @@ from euler_tpu import telemetry as T
 from euler_tpu import trace as TR
 from euler_tpu import train as train_lib
 from euler_tpu.graph import native
-from euler_tpu.models import SupervisedGraphSage
+from euler_tpu.models import ScalableSage, SupervisedGraphSage
 
 MAX_ID = 16  # fixture ids go up to 16
+# the scopes of the historical-store family's step alone (models/base.py
+# ScalableStoreModel); every other scope is on GraphSAGE's step too
+STORE_SCOPES = {"stores_read", "stores_write"}
 TRAIN_THREAD_LEAVES = {"input_stall", "input_other", "h2d", *T.PHASE_PARENT}
 
 
@@ -58,9 +61,34 @@ def test_lowered_train_step_holds_every_step_scope(graph):
     text = jax.jit(m.make_train_step(opt)).lower(
         state, m.sample(graph, roots)).as_text(debug_info=True)
     for scope in TR.STEP_SCOPES:
-        assert f"/{scope}/" in text, scope
+        assert (f"/{scope}/" in text) == (scope not in STORE_SCOPES), scope
     # the backward pass rides its scope: no scope of its own is needed
     assert "transpose(jvp(" in text
+
+
+def test_lowered_store_step_holds_every_step_scope(graph):
+    """The store family's step: the single-hop draw under ``draw``, the
+    stores' gathers and the clearing set under ``stores_read``, the
+    scatter-add and the set of fresh rows under ``stores_write``, both
+    Adams under ``optimizer``."""
+    m = ScalableSage(
+        label_idx=2, label_dim=3, edge_type=[0, 1], fanout=3, num_layers=2,
+        dim=16, max_id=MAX_ID, concat=True, feature_idx=0, feature_dim=2,
+        device_features=True, device_sampling=True,
+    )
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    text = jax.jit(m.make_train_step(opt)).lower(
+        state, m.sample(graph, roots)).as_text(debug_info=True)
+    for scope in TR.STEP_SCOPES:
+        assert f"/{scope}/" in text, scope
+    lines = text.splitlines()
+    gathers = [ln for ln in lines if "stores_read" in ln and "gather" in ln]
+    adds = [ln for ln in lines if "stores_write" in ln and "scatter" in ln]
+    assert gathers and adds
+    assert any("/optimizer/" in ln for ln in lines)
+    assert not any("/stores_" in ln and "/optimizer/" in ln for ln in lines)
 
 
 def test_benchmark_keeps_the_same_scope_names():
@@ -74,7 +102,7 @@ def test_profiled_run_leaves_the_compiled_step_text(graph, tmp_path):
     _train(graph, 6, profile_dir=str(tmp_path), profile_steps=(2, 4))
     text = (tmp_path / TR.STEP_HLO_FILE).read_text()
     assert text.startswith("HloModule jit_train_step")
-    for scope in TR.STEP_SCOPES:
+    for scope in set(TR.STEP_SCOPES) - STORE_SCOPES:
         assert f"/{scope}/" in text, scope
     # the flag the text's compile is keyed with is put back
     assert not jax.config.jax_compilation_cache_include_metadata_in_key
@@ -83,6 +111,26 @@ def test_profiled_run_leaves_the_compiled_step_text(graph, tmp_path):
 # ---------------------------------------------------------------------------
 # (b) host side: leaves tile the training thread, parents are their sums
 # ---------------------------------------------------------------------------
+
+
+def test_recorder_stop_takes_itself_out_and_leaves_another_in():
+    """``stop()`` unregisters the recorder it is called on (two reads of
+    a bound method are equal and never identical, so an ``is`` test never
+    did), and only that one: a recorder stopped late must not take out
+    the one that has started since."""
+    first = TR.TraceRecorder().start()
+    T.record_phase("fence", 5, step=1)
+    first.stop()
+    assert T._trace_sink is None
+    T.record_phase("fence", 5, step=2)
+    assert [e[3] for e in first.events()] == [1]
+    second = TR.TraceRecorder().start()
+    first.stop()
+    assert T._trace_sink == second._on_phase
+    T.record_phase("fence", 5, step=3)
+    second.stop()
+    assert [e[3] for e in second.events()] == [3]
+    assert T._trace_sink is None
 
 
 def test_leaves_tile_every_step_and_parents_are_their_sums(graph, tmp_path):
