@@ -330,8 +330,10 @@ def record_store_table(width: int, stored_width: int) -> None:
     """The gauges ``store_table_width`` / ``store_table_stored_width`` of
     the resource section: the width of a training state's per-node
     stores and the lanes a stored row takes in device memory, 0 where the
-    device keeps the table column-major (models/base.py
-    ScalableStoreModel.describe_state, once per ``train()``)."""
+    table lies column-major: a state that did not pass
+    ``parallel.state_sharding``, which pins the stores rows-major
+    (models/base.py ScalableStoreModel.describe_state, once per
+    ``train()``)."""
     if _enabled:
         lib().eg_devprof_set_store_table(int(width), int(stored_width))
 

@@ -132,7 +132,12 @@ def upload_sparse_tables(
 # transpose of the whole table every step (PERF.md section 6, PR 28). A
 # width that is a multiple of the lanes pads nothing row-major, which
 # makes rows-contiguous the runtime's own choice in every jit that takes
-# the table, with no layout argument anywhere.
+# the feature table, with no layout argument for it anywhere. The one
+# layout argument of the program is for the Scalable* stores
+# (parallel/mesh.py state_sharding pins them rows-major): their logical
+# [max_id + 2, dim] shape is what callers that hand train() a state
+# build and read back and what a checkpoint holds, so the width route
+# was not open there (PERF.md section 6, PR 31).
 TABLE_LANES = 128
 
 
@@ -821,9 +826,12 @@ class ScalableStoreModel(Model):
         """The stores' width and what the device made of it: gauges
         ``store_table_width`` / ``store_table_stored_width`` and one
         route-log line. The stores are [n, dim] float32 with dim under a
-        lane tile; whether a row lies contiguous (and then pads to the
-        tile's lanes) or the table lies column-major is the device's
-        choice, read here from the arrays' own layout."""
+        lane tile; left to itself a TPU lays such a table column-major,
+        so ``state_sharding`` (parallel/mesh.py, the program's one
+        layout argument) pins the store leaves rows-major and a row then
+        pads to the tile's lanes. Read here from the arrays' own layout,
+        after ``put_global``: a stored width of 0 means a state that
+        did not pass ``state_sharding`` on its way to the device."""
         stores = state.get("stores") or []
         if not stores:
             return

@@ -1,5 +1,6 @@
 from euler_tpu.parallel.mesh import (
     batch_sharding,
+    compiles_keep_layouts,
     enable_compile_cache,
     force_cpu_devices,
     make_mesh,
@@ -14,6 +15,7 @@ from euler_tpu.parallel.prefetch import pipeline, prefetch
 
 __all__ = [
     "batch_sharding",
+    "compiles_keep_layouts",
     "enable_compile_cache",
     "force_cpu_devices",
     "make_mesh",

@@ -17,15 +17,24 @@ inserts the gather/scatter collectives inside the jitted step.
 
 from __future__ import annotations
 
+import contextlib
 import os
 
 import jax
 import numpy as np
+from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # Top-level train-state keys holding [num_nodes, dim]-shaped tables that
 # row-shard over the 'model' axis.
 _TABLE_KEYS = ("consts", "stores", "grad_stores")
+# Of those, the keys whose tables the step gathers from AND scatters into
+# (donated, mutable state): their device layout is pinned row-major.
+_STORE_KEYS = ("stores", "grad_stores")
+# Rows contiguous, the tiling left to the device's compiler (a TPU tiles
+# it T(8,128), so a 64-wide float32 row pads to one 128-lane line; a CPU
+# has none). What ``describe_state`` calls rows_major.
+_ROWS_MAJOR = Layout(major_to_minor=(0, 1))
 
 _CHECKOUT = os.path.dirname(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -137,6 +146,11 @@ def _model_axis_size(mesh: Mesh) -> int:
     return mesh.shape.get("model", 1)
 
 
+def _top_key(path):
+    key = path[0]
+    return getattr(key, "key", getattr(key, "idx", None))
+
+
 def _is_table(path, x) -> bool:
     """True for leaves under a _TABLE_KEYS top-level state key — the
     per-node tables that row-shard (and row-pad) over the model axis.
@@ -144,8 +158,7 @@ def _is_table(path, x) -> bool:
     shard; device-sampling structures (adj / roots / negs and anything
     else) replicate — their cumulative-weight arrays must stay contiguous
     and unpadded (zero-padding would unsort the searchsorted input)."""
-    key = path[0]
-    name = getattr(key, "key", getattr(key, "idx", None))
+    name = _top_key(path)
     if name not in _TABLE_KEYS or np.ndim(x) < 1:
         return False
     if name == "consts" and len(path) > 1:
@@ -155,18 +168,74 @@ def _is_table(path, x) -> bool:
     return True
 
 
+def _is_store(path, x) -> bool:
+    """True for the [rows, dim] leaves under ``stores`` / ``grad_stores``:
+    the Scalable* historical-embedding tables."""
+    return _top_key(path) in _STORE_KEYS and np.ndim(x) == 2
+
+
 def state_sharding(mesh: Mesh, state):
     """Sharding pytree for a train state: params/optimizer replicated,
     per-node tables (consts, Scalable stores) row-sharded when the mesh has
     a model axis. Matches state's tree structure, for jit in_/out_shardings
-    and device_put."""
+    and ``put_global``.
+
+    The store leaves (``stores``, ``grad_stores``) get a ``Format`` in the
+    sharding's place: the same sharding with the device layout pinned
+    rows-major. A TPU lays a [rows, dim] float32 table whose dim is no
+    lane multiple column-major by default, and a step that gathers rows
+    from it and scatters rows into it then copies the whole table to a
+    row-major temporary and back, every step (four 1 GB copies, 9.65 of a
+    10.35 ms step at Reddit's sizes: PERF.md section 6, PR 31). Pinned,
+    the table is re-laid once at placement and goes round the donated
+    step in place. Where rows-major is the device's own choice (a lane-
+    multiple dim; any CPU) the pin is the default and changes nothing.
+    The logical shape stays [rows, dim]: it is the checkpoint's format
+    and what callers that hand ``train()`` a state build and read.
+    ``consts`` is not pinned: its tables are read-only, the feature
+    table is rows-major by its stored width (``stored_width``), and the
+    narrow label table's gather is cheap beside the memory a pin costs."""
     rep = replicated_sharding(mesh)
-    if _model_axis_size(mesh) <= 1:
-        return jax.tree.map(lambda _: rep, state)
-    tab = table_sharding(mesh)
-    return jax.tree_util.tree_map_with_path(
-        lambda path, x: tab if _is_table(path, x) else rep, state
-    )
+    tab = table_sharding(mesh) if _model_axis_size(mesh) > 1 else rep
+
+    def place(path, x):
+        s = tab if _is_table(path, x) else rep
+        return Format(_ROWS_MAJOR, s) if _is_store(path, x) else s
+
+    return jax.tree_util.tree_map_with_path(place, state)
+
+
+@contextlib.contextmanager
+def compiles_keep_layouts(shardings):
+    """Around the first call (the compile) of a program placed by
+    ``shardings``: where a leaf pins a layout, the program is compiled
+    here and not fetched from, or left in, the persistent compile cache.
+    An executable that comes back from the cache has lost its pinned
+    result layouts (jaxlib 0.9.0 on a TPU v5e; my chip runs, PR 31: the
+    same re-lay and the same donated step give rows-major tables when
+    compiled and column-major ones on the cache's hit, and the next call
+    then refuses its own output), so the store family pays its step's
+    compile in every process (about 5 s at Reddit's sizes). A pytree
+    without a ``Format`` (every other family) keeps the cache."""
+    pinned = any(isinstance(s, Format) for s in jax.tree.leaves(shardings))
+    if not (
+        pinned
+        and jax.config.jax_enable_compilation_cache
+        and jax.config.jax_compilation_cache_dir
+    ):
+        yield
+        return
+    from jax.experimental.compilation_cache import compilation_cache
+
+    # the cache decides once whether it is used: it is asked anew on both
+    # sides of the compile
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        compilation_cache.reset_cache()
 
 
 def pad_tables_for_mesh(state, mesh: Mesh):
@@ -190,8 +259,27 @@ def pad_tables_for_mesh(state, mesh: Mesh):
     return jax.tree_util.tree_map_with_path(pad, state)
 
 
-def put_global(tree, shardings):
-    """device_put a host pytree onto its shardings, multi-process aware.
+def _placed_as(x, s) -> bool:
+    """True where ``x`` already sits on the devices as ``s`` (a sharding,
+    or a ``Format`` of ``state_sharding``) asks. A pinned layout names no
+    tiling, so the array's own tiling is not compared."""
+    if not isinstance(x, jax.Array):
+        return False
+    if not isinstance(s, Format):
+        return x.sharding == s
+    layout = x.format.layout
+    return (
+        x.sharding == s.sharding
+        and layout is not None
+        and tuple(layout.major_to_minor) == s.layout.major_to_minor
+    )
+
+
+def put_global(tree, shardings, consume: bool = False):
+    """device_put a host pytree onto its shardings (``state_sharding``'s
+    pytree: a leaf is a sharding, or a ``Format`` that also pins the
+    device layout), multi-process aware. A leaf that already sits there
+    is handed back as it is.
 
     Single-controller: plain jax.device_put. Under jax.distributed
     (process_count > 1) the shardings span devices this process cannot
@@ -200,19 +288,40 @@ def put_global(tree, shardings):
     host value (true for replicated params initialised from one PRNG
     seed and for consts derived from the same graph). This is the
     multi-host analog of the reference's parameter-server variable
-    placement (reference tf_euler/python/run_loop.py:391-394)."""
-    if jax.process_count() == 1:
-        return jax.device_put(tree, shardings)
+    placement (reference tf_euler/python/run_loop.py:391-394).
+
+    ``consume``: the caller gives ``tree`` up (``train()`` does: its step
+    donates the state). A device array that had to be re-laid into a
+    pinned layout is then freed once its copy exists, so a [rows, dim]
+    store is not held twice through the run by whoever built it."""
+    multi = jax.process_count() > 1
 
     def put(x, s):
-        if isinstance(x, jax.Array) and x.sharding == s:
+        if _placed_as(x, s):
             # already placed (e.g. a checkpoint-restored global array) —
             # np.asarray on it would crash for model-axis-sharded leaves
             # (spans non-addressable devices) and needlessly round-trip
             # everything else
             return x
-        x = np.asarray(x)
-        return jax.make_array_from_callback(x.shape, s, lambda idx: x[idx])
+        if multi:
+            host = np.asarray(x)
+            with compiles_keep_layouts(s):
+                return jax.make_array_from_callback(
+                    host.shape, s, lambda idx: host[idx]
+                )
+        if not isinstance(s, Format):
+            return jax.device_put(x, s)
+        # onto the devices first (device_put to a Format re-lays an array
+        # where it is and cannot move one), then the one layout copy,
+        # where the device's own layout is another
+        y = jax.device_put(x, s.sharding)
+        if _placed_as(y, s):
+            return y
+        with compiles_keep_layouts(s):
+            y = jax.device_put(y, s)
+        if consume and isinstance(x, jax.Array):
+            x.delete()
+        return y
 
     return jax.tree.map(put, tree, shardings)
 

@@ -25,6 +25,7 @@ from euler_tpu.graph import device as device_graph
 from euler_tpu.nn import metrics as metrics_lib
 from euler_tpu.parallel import (
     batch_sharding,
+    compiles_keep_layouts,
     make_mesh,
     pad_tables_for_mesh,
     pipeline,
@@ -234,10 +235,13 @@ def train(
         )
     rep = replicated_sharding(mesh)
     # Params/opt replicated; per-node tables row-sharded over the mesh's
-    # 'model' axis when present (pure DP: everything replicated).
+    # 'model' axis when present (pure DP: everything replicated); the
+    # Scalable* stores also carry their pinned rows-major device layout
+    # from here through the step's input and output. The state is the
+    # step's to donate, so a store that had to be re-laid is not kept.
     state = pad_tables_for_mesh(state, mesh)
     shardings = state_sharding(mesh, state)
-    state = put_global(state, shardings)
+    state = put_global(state, shardings, consume=True)
     model.describe_state(state)
 
     ckpt = None
@@ -249,7 +253,7 @@ def train(
         latest = ckpt.latest_step()
         if latest is not None:
             state = ckpt.restore(state, latest)
-            state = put_global(state, shardings)
+            state = put_global(state, shardings, consume=True)
             start_step = latest
             (log_fn or log.info)(
                 f"resumed from {checkpoint_dir} at step {latest}"
@@ -469,8 +473,11 @@ def train(
                 devprof.count_h2d(batch)
                 if phase_profile:
                     mark = leaf("h2d", mark, cur, leaves)
-            if profile_dir and cur == start_step:
-                with _cache_keyed_with_metadata():  # this call compiles
+            if cur == start_step:  # this call compiles
+                with contextlib.ExitStack() as compiling:
+                    if profile_dir:
+                        compiling.enter_context(_cache_keyed_with_metadata())
+                    compiling.enter_context(compiles_keep_layouts(shardings))
                     state, last_loss, metric = step_fn(state, batch)
             else:
                 state, last_loss, metric = step_fn(state, batch)
@@ -498,7 +505,8 @@ def train(
             if profile_dir and cur == start_step:
                 # after the first step's hook, which may read the compile
                 # ledger as of the first dispatch
-                write_step_hlo(step_fn, state, batch, profile_dir)
+                with compiles_keep_layouts(shardings):
+                    write_step_hlo(step_fn, state, batch, profile_dir)
             if sync_every and steps_done % sync_every == 0:
                 jax.block_until_ready(last_loss)
             if len(window_metrics) == log_every:
@@ -615,20 +623,21 @@ def evaluate(
     acc = _metric_zero(name)
     losses = []
     n_proc = jax.process_count()
-    for ids in source_iter:
-        if n_proc > 1:
-            ids = np.asarray(ids)
-            if len(ids) % n_proc:
-                raise ValueError(
-                    f"eval batch {len(ids)} not divisible by "
-                    f"{n_proc} processes"
-                )
-            per = len(ids) // n_proc
-            ids = ids[jax.process_index() * per:][:per]
-        batch = shard_batch(model.sample(graph, ids), mesh)
-        loss, metric = eval_fn(state, batch)
-        acc = _metric_accumulate(name, acc, metric)
-        losses.append(float(loss))
+    with compiles_keep_layouts(shardings):  # eval_fn compiles in here
+        for ids in source_iter:
+            if n_proc > 1:
+                ids = np.asarray(ids)
+                if len(ids) % n_proc:
+                    raise ValueError(
+                        f"eval batch {len(ids)} not divisible by "
+                        f"{n_proc} processes"
+                    )
+                per = len(ids) // n_proc
+                ids = ids[jax.process_index() * per:][:per]
+            batch = shard_batch(model.sample(graph, ids), mesh)
+            loss, metric = eval_fn(state, batch)
+            acc = _metric_accumulate(name, acc, metric)
+            losses.append(float(loss))
     result = {name: _metric_value(name, acc), "loss": float(np.mean(losses))}
     (log_fn or log.info)(f"eval: {result}")
     return result
@@ -671,11 +680,12 @@ def save_embedding(
     pad = (-len(ids)) % batch_size
     padded = np.concatenate([ids, np.zeros(pad, dtype=np.int64)])
     per = batch_size // n_proc
-    for i in range(0, len(padded), batch_size):
-        chunk = padded[i : i + batch_size]
-        if n_proc > 1:
-            chunk = chunk[jax.process_index() * per:][:per]
-        batch = shard_batch(model.sample_embed(graph, chunk), mesh)
-        chunks.append(np.asarray(embed_fn(state, batch)))
+    with compiles_keep_layouts(shardings):  # embed_fn compiles in here
+        for i in range(0, len(padded), batch_size):
+            chunk = padded[i : i + batch_size]
+            if n_proc > 1:
+                chunk = chunk[jax.process_index() * per:][:per]
+            batch = shard_batch(model.sample_embed(graph, chunk), mesh)
+            chunks.append(np.asarray(embed_fn(state, batch)))
     out = np.concatenate(chunks, axis=0)[: len(ids)]
     return out
