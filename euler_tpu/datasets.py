@@ -297,10 +297,7 @@ def powerlaw_degrees(
 def _powerlaw_params(num_nodes, num_edges, feature_dim, label_dim,
                      alpha, multilabel, num_partitions, seed,
                      placement="hash") -> str:
-    """The cache-identity string build_powerlaw's done marker records —
-    one constructor so external gates (powerlaw_cache_ready's callers:
-    bench.py's default config list, the batch sweep) and the builder
-    can never disagree on it."""
+    """The cache-identity string build_powerlaw's done marker records."""
     d = dict(kind="powerlaw", num_nodes=num_nodes, num_edges=num_edges,
              feature_dim=feature_dim, label_dim=label_dim, alpha=alpha,
              multilabel=multilabel, num_partitions=num_partitions,
@@ -313,12 +310,8 @@ def _powerlaw_params(num_nodes, num_edges, feature_dim, label_dim,
 
 
 def heavytail_cache_dir() -> str:
-    """Default build_powerlaw cache dir for the Reddit-scale graph —
-    ONE resolver shared by bench.py's reddit_heavytail config and its
-    default-config gate, scripts/batch_sweep.py and
-    scripts/reddit_heavytail.py --full (a hard-coded copy of the path is
-    how a gate ends up checking a different directory than the bench
-    builds in).
+    """Default build_powerlaw cache dir for the Reddit-scale graph of
+    scripts/reddit_heavytail.py --full.
     EULER_TPU_HEAVYTAIL_CACHE overrides; else <repo>/.data/reddit_ht."""
     return os.environ.get(
         "EULER_TPU_HEAVYTAIL_CACHE",
@@ -327,33 +320,6 @@ def heavytail_cache_dir() -> str:
             ".data", "reddit_ht",
         ),
     )
-
-
-def powerlaw_cache_ready(
-    out_dir: str,
-    num_nodes: int,
-    num_edges: int,
-    feature_dim: int,
-    label_dim: int,
-    alpha: float = 1.8,
-    multilabel: bool = False,
-    num_partitions: int = 4,
-    seed: int = 17,
-) -> bool:
-    """True when ``out_dir`` holds a FINISHED build_powerlaw cache with
-    EXACTLY these params (the done marker records them). A bare
-    existence check is not enough: _cache_begin wipes and regenerates
-    on any params mismatch, so a gate that only tests the marker file
-    would wave through a stale cache and pay the full rebuild anyway,
-    inside a benchmark's time budget."""
-    marker = os.path.join(out_dir, "done")
-    if not os.path.exists(marker):
-        return False
-    with open(marker) as f:
-        return f.read() == _powerlaw_params(
-            num_nodes, num_edges, feature_dim, label_dim, alpha,
-            multilabel, num_partitions, seed,
-        )
 
 
 def build_powerlaw(

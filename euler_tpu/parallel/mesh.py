@@ -44,7 +44,7 @@ _CHECKOUT = os.path.dirname(
 def enable_compile_cache() -> str | None:
     """Turn on JAX's persistent compilation cache and return its
     directory. The ONE place that decides where compiled programs are
-    kept (run_loop, serve, bench.py, batch_sweep.py, chip_smoke.py and
+    kept (run_loop, serve, chip_smoke.py, the benchmark's harness and
     the on-chip tests all call it before their first jit): where
     JAX_COMPILATION_CACHE_DIR is set, JAX's own handling of the variable
     stands and nothing here touches ``jax_compilation_cache_dir``;

@@ -146,8 +146,6 @@ def train(
     checkpoint_every: int = 0,
     profile_dir: Optional[str] = None,
     profile_steps: tuple = (10, 20),
-    device_prefetch: bool = True,
-    sync_every: Optional[int] = None,
     step_hook=None,
     phase_profile: Optional[bool] = None,
 ):
@@ -198,36 +196,29 @@ def train(
     must then be a path every process can reach (orbax coordinates the
     distributed save) or None.
 
-    device_prefetch=True also issues the host->device copy from the
-    prefetch workers, overlapping H2D of batch k+1 with compute of step k
-    — at the cost of holding up to prefetch_depth+1 staged batches in
-    device memory. Set False (one staged batch) for configs sized near the
-    HBM limit.
-
     checkpoint_dir enables MonitoredTrainingSession-style periodic save +
     resume-from-latest (reference run_loop.py:132-138); profile_dir captures
     a JAX profiler trace over profile_steps (the reference's ProfilerHook,
     run_loop.py:124-126), and leaves the compiled step's HLO text beside
     it (trace.STEP_HLO_FILE: the map from a device op of the capture to
-    the named scope it was traced under). Note with device_prefetch the
-    copies for the first ~prefetch_depth profiled steps were issued
-    before the trace starts and won't appear in it.
+    the named scope it was traced under). The host->device copies of
+    the first ~prefetch_depth profiled steps were issued by the prefetch
+    workers before the trace starts and won't appear in it.
     """
     n_mesh_devices = int(np.prod(mesh.devices.shape))
     cpu_virtual_mesh = (
         n_mesh_devices > 1
         and mesh.devices.reshape(-1)[0].platform == "cpu"
     )
-    if sync_every is None:
-        # Async dispatch depth must be 1 on a multi-device CPU (virtual)
-        # mesh: XLA-CPU collectives BLOCK a shared pool thread inside the
-        # all-reduce rendezvous, so device programs queued from later
-        # steps can consume every pool thread while an earlier step's
-        # rendezvous still waits for its last participant — a livelock
-        # XLA resolves by aborting the process after 40 s. Real TPU
-        # queues per-device streams in hardware; a modest sync there just
-        # bounds queued-buffer memory.
-        sync_every = 1 if cpu_virtual_mesh else 32
+    # Async dispatch depth must be 1 on a multi-device CPU (virtual)
+    # mesh: XLA-CPU collectives BLOCK a shared pool thread inside the
+    # all-reduce rendezvous, so device programs queued from later
+    # steps can consume every pool thread while an earlier step's
+    # rendezvous still waits for its last participant — a livelock
+    # XLA resolves by aborting the process after 40 s. Real TPU
+    # queues per-device streams in hardware; a modest sync there just
+    # bounds queued-buffer memory.
+    sync_every = 1 if cpu_virtual_mesh else 32
     opt = get_optimizer(optimizer, learning_rate)
     if state is None:
         state = model.init_state(
@@ -300,14 +291,16 @@ def train(
             leaves[name] = end_us - start_us
             record_phase(name, end_us - start_us, step=step, end_us=end_us)
             return end_us
-    if device_prefetch and cpu_virtual_mesh:
-        # XLA's CPU multi-device backend shares one in-process communicator:
-        # device_put issued from prefetch worker threads can starve a
-        # collective rendezvous inside a concurrently executing step (7 of 8
-        # participants arrive, then a fatal 40s termination timeout). Real
-        # TPU/GPU devices transfer asynchronously and don't have this
-        # hazard; on a virtual CPU mesh, transfer on the consumer thread.
-        device_prefetch = False
+    # The prefetch workers also issue the host->device copy, so H2D of
+    # batch k+1 overlaps compute of step k (up to prefetch_depth+1 staged
+    # batches in device memory) — except on a virtual CPU mesh. XLA's CPU
+    # multi-device backend shares one in-process communicator: device_put
+    # issued from prefetch worker threads can starve a collective
+    # rendezvous inside a concurrently executing step (7 of 8
+    # participants arrive, then a fatal 40s termination timeout). Real
+    # TPU/GPU devices transfer asynchronously and don't have this hazard;
+    # on a virtual CPU mesh, transfer on the consumer thread.
+    device_prefetch = not cpu_virtual_mesh
 
     def make_batch(step):
         # With device_prefetch, device_put runs here inside the prefetch
@@ -507,7 +500,7 @@ def train(
                 # ledger as of the first dispatch
                 with compiles_keep_layouts(shardings):
                     write_step_hlo(step_fn, state, batch, profile_dir)
-            if sync_every and steps_done % sync_every == 0:
+            if steps_done % sync_every == 0:
                 jax.block_until_ready(last_loss)
             if len(window_metrics) == log_every:
                 flush()

@@ -209,7 +209,7 @@ def run_smoke() -> int:
 
     import euler_tpu
     from euler_tpu.graph.service import GraphService
-    from scripts.remote_bench import build_powerlaw_fixture
+    from tests.fixture_graph import build_powerlaw_fixture
 
     tmp = tempfile.mkdtemp(prefix="euler_heat_smoke_")
     svcs = []
@@ -268,7 +268,7 @@ def run_ab_smoke() -> int:
     from euler_tpu.graph import native
     from euler_tpu.graph.convert import convert_dicts
     from euler_tpu.graph.service import GraphService
-    from scripts.remote_bench import PL_META, powerlaw_fixture_nodes
+    from tests.fixture_graph import PL_META, powerlaw_fixture_nodes
 
     tmp = tempfile.mkdtemp(prefix="euler_locality_ab_")
     try:

@@ -270,7 +270,7 @@ def run_smoke() -> int:
     from euler_tpu.graph.service import GraphService
 
     sys.path.insert(0, REPO)
-    from scripts.remote_bench import build_powerlaw_fixture
+    from tests.fixture_graph import build_powerlaw_fixture
 
     tmp = tempfile.mkdtemp(prefix="euler_metrics_smoke_")
     svcs = []

@@ -186,7 +186,7 @@ def run_smoke() -> int:
 
     import euler_tpu
     from euler_tpu import trace as trace_mod
-    from scripts.remote_bench import build_powerlaw_fixture
+    from tests.fixture_graph import build_powerlaw_fixture
 
     tmp = tempfile.mkdtemp(prefix="euler_postmortem_smoke_")
     procs = []

@@ -113,8 +113,7 @@ def full_scale(workdir: str, num_edges: int, batch: int, steps: int) -> dict:
 
     # device-sampling step timing at the reference reddit recipe
     # (batch 1000 roots x fanouts [4,4]); on CPU this is context, on a
-    # TPU backend it is the real number — bench.py --configs
-    # reddit_heavytail is the driver-visible form of the same measure
+    # TPU backend it is the real number
     import jax
     import jax.numpy as jnp
 
@@ -468,10 +467,8 @@ def main() -> None:
     if args.walk_study:
         out["walk_study"] = walk_study()
     if args.full:
-        # default to the SAME cache bench.py's reddit_heavytail config
-        # uses (one resolver: datasets.heavytail_cache_dir) so the
-        # documented script-then-bench queue builds the ~2 GB graph
-        # once, not twice
+        # the cache every --full run shares (datasets.heavytail_cache_dir):
+        # the ~2 GB graph is built once
         from euler_tpu.datasets import heavytail_cache_dir
 
         wd = args.workdir or heavytail_cache_dir()

@@ -29,7 +29,6 @@ work, so treat the edges/s ratio as a floor for real networks.
 Usage:
     python scripts/remote_bench.py             # full run, JSON to stdout
     python scripts/remote_bench.py --smoke     # small/fast (verify.sh)
-    python bench.py --configs remote           # same, bench-driver shaped
 
 Subprocess shards by default (one OS process per shard, like the chaos
 soak) so server CPU is not attributed to the client loop; --inproc uses
@@ -50,85 +49,6 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 NUM_SHARDS = 2
-NUM_PARTITIONS = 4
-
-PL_META = {
-    "node_type_num": 2,
-    "edge_type_num": 2,
-    "node_uint64_feature_num": 1,
-    "node_float_feature_num": 1,
-    "node_binary_feature_num": 0,
-    "edge_uint64_feature_num": 0,
-    "edge_float_feature_num": 0,
-    "edge_binary_feature_num": 0,
-}
-
-
-def powerlaw_fixture_nodes(num_nodes: int, avg_degree: int,
-                           feature_dim: int, alpha: float = 1.1,
-                           seed: int = 7) -> list:
-    """Node dicts of the hub-heavy synthetic graph: zipf(alpha)-ranked
-    destination draws, so the first few ids soak up most edge mass (the
-    Reddit heavy tail at bench size). Split from the .dat writer so the
-    locality A/B (scripts/heat_dump.py --ab-smoke) can partition ONE
-    node set two ways."""
-    rng = np.random.default_rng(seed)
-    # zipf-ish rank weights over destinations
-    ranks = np.arange(1, num_nodes + 1, dtype=np.float64)
-    probs = ranks ** (-alpha)
-    probs /= probs.sum()
-    nodes = []
-    for nid in range(num_nodes):
-        deg = max(1, int(rng.poisson(avg_degree)))
-        dsts = rng.choice(num_nodes, size=deg, p=probs)
-        groups: dict = {}
-        for d in dsts:
-            d = int(d)
-            t = d % 2
-            groups.setdefault(t, {})
-            groups[t][d] = groups[t].get(d, 0.0) + 1.0
-        nodes.append(
-            {
-                "node_id": nid,
-                "node_type": nid % 2,
-                "node_weight": 1.0,
-                "neighbor": {
-                    str(t): {str(d): w for d, w in g.items()}
-                    for t, g in groups.items()
-                },
-                "uint64_feature": {"0": [nid]},
-                "float_feature": {
-                    "0": (np.arange(feature_dim) * 0.01 + nid * 0.001)
-                    .astype(float).tolist()
-                },
-                "binary_feature": {},
-                "edge": [
-                    {
-                        "src_id": nid, "dst_id": d, "edge_type": t,
-                        "weight": w, "uint64_feature": {},
-                        "float_feature": {}, "binary_feature": {},
-                    }
-                    for t, g in groups.items()
-                    for d, w in g.items()
-                ],
-            }
-        )
-    return nodes
-
-
-def build_powerlaw_fixture(directory: str, num_nodes: int, avg_degree: int,
-                           feature_dim: int, alpha: float = 1.1,
-                           seed: int = 7, placement: str = "hash") -> None:
-    """Partition the hub-heavy fixture into NUM_PARTITIONS .dat files
-    (placement='degree' adds the converter's placement artifact)."""
-    import euler_tpu
-
-    euler_tpu.convert_dicts(
-        powerlaw_fixture_nodes(num_nodes, avg_degree, feature_dim, alpha,
-                               seed),
-        PL_META, os.path.join(directory, "part"),
-        num_partitions=NUM_PARTITIONS, placement=placement,
-    )
 
 
 def _launch_shards_subproc(data: str, reg: str):
@@ -482,10 +402,12 @@ def devprof_ab_paired(pairs: int, steps: int) -> dict:
 
 def run_remote_bench(smoke: bool = False, inproc: bool | None = None,
                      steps: int | None = None) -> dict:
-    """Full before/after measurement; returns the bench-driver-shaped
-    result dict (metric/value/unit/vs_baseline/detail)."""
+    """Full before/after measurement; returns one result dict
+    (metric/value/unit/vs_baseline/detail)."""
     import shutil
     import tempfile
+
+    from tests.fixture_graph import build_powerlaw_fixture
 
     if smoke:
         num_nodes, avg_degree, feature_dim = 300, 10, 16
@@ -607,10 +529,8 @@ def run_remote_bench(smoke: bool = False, inproc: bool | None = None,
                 "heat_ab": heat_ab,
                 "devprof_ab": devprof_ab,
                 "sampler_depth_sweep": sweep,
-                # the bench-breakdown contract for the remote path: the
-                # measured depth-2 stall vs the (simulated, sample-time
-                # calibrated) device step, judged at the same 5%
-                # threshold bench.py applies to the local host path
+                # the measured depth-2 stall vs the (simulated,
+                # sample-time calibrated) device step, judged at 5% of it
                 "breakdown": {
                     "device_step_ms": sweep["device_step_ms"],
                     "sampler_depth": 2,
@@ -658,20 +578,6 @@ def main() -> int:
                               steps=args.steps)
     print(json.dumps(result), flush=True)
     detail = result["detail"]
-    # per-depth throughput into the perf_gate smoke history, so a
-    # pipelined-sampling regression shows up in the same trajectory the
-    # gate reads (keys beyond bench_smoke/remote_smoke are carried, not
-    # enforced — the 1-core container noise rule)
-    try:
-        from perf_gate import append_history
-
-        sweep_vals = {
-            f"remote_depth{r['sampler_depth']}": r["edges_per_sec"]
-            for r in detail["sampler_depth_sweep"]["rows"]
-        }
-        append_history({"unix": int(time.time()), "values": sweep_vals})
-    except Exception as e:
-        print(f"history append skipped: {e}", file=sys.stderr)
     if args.smoke:
         # the smoke gate's contract: the optimized path must demonstrably
         # coalesce — a silent dedup regression fails verify, not PERF.md

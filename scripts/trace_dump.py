@@ -78,7 +78,7 @@ def run_smoke() -> int:
     from euler_tpu.graph import native
     from euler_tpu.graph.service import GraphService
     from euler_tpu.parallel import prefetch
-    from scripts.remote_bench import build_powerlaw_fixture
+    from tests.fixture_graph import build_powerlaw_fixture
 
     tmp = tempfile.mkdtemp(prefix="euler_trace_smoke_")
     svcs = []

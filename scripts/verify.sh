@@ -31,7 +31,7 @@ python scripts/check_contracts.py || fail=1
 
 step "ruff"
 if command -v ruff >/dev/null 2>&1; then
-  ruff check euler_tpu scripts tests examples bench.py || fail=1
+  ruff check euler_tpu scripts tests examples || fail=1
 else
   echo "SKIPPED: ruff not installed in this image (config: pyproject.toml [tool.ruff])"
 fi
@@ -149,15 +149,6 @@ timeout -k 10 420 env JAX_PLATFORMS=cpu python -m pytest \
 timeout -k 10 300 env JAX_PLATFORMS=cpu \
   python scripts/devprof_dump.py --smoke >/dev/null || fail=1
 
-step "perf gate (scripts/perf_gate.py — strict for bench_smoke, warn-only remote)"
-# Smoke-to-smoke control-flow check on XLA-CPU (says nothing about the
-# chip). The host-only bench.py --smoke config GATES verify (its history
-# has several rounds and it runs without the remote path's container
-# noise); the remote configs stay warn-only. `perf_gate.py --strict`
-# enforces everything.
-timeout -k 10 600 env JAX_PLATFORMS=cpu \
-  python scripts/perf_gate.py --strict-configs bench_smoke || fail=1
-
 step "sanitizer smoke (scripts/sanitize.sh --smoke; SANITIZERS.md)"
 # One TSAN round over the fuzz barrage (16 threads of garbage +
 # concurrent valid traffic against a live service — the densest
@@ -168,7 +159,7 @@ timeout -k 10 600 scripts/sanitize.sh --smoke || fail=1
 
 step "python syntax floor (compileall)"
 # stdlib floor under the optional tools above: at minimum, every file parses
-python -m compileall -q euler_tpu tests scripts examples bench.py || fail=1
+python -m compileall -q euler_tpu tests scripts examples || fail=1
 
 step "tier-1 tests (ROADMAP.md)"
 rm -f /tmp/_t1.log

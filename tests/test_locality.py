@@ -210,8 +210,8 @@ def _stripe_colliding_ids(spec, stripe, want, limit=100000):
 def lfu_cluster(tmp_path_factory):
     """Single-shard cluster over a wide id space (ids 0..2999), so
     stripe-colliding id sets exist for any spec."""
-    from scripts.remote_bench import PL_META as BENCH_META
-    from scripts.remote_bench import powerlaw_fixture_nodes
+    from tests.fixture_graph import PL_META as BENCH_META
+    from tests.fixture_graph import powerlaw_fixture_nodes
 
     data = str(tmp_path_factory.mktemp("lfu_data"))
     convert_dicts(powerlaw_fixture_nodes(3000, 6, 8), BENCH_META,

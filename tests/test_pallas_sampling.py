@@ -406,7 +406,7 @@ def test_kernel_mesh_scope_registers_and_restores(monkeypatch):
 @multi_device
 def test_trainer_entry_points_scope_their_mesh(monkeypatch):
     """train()/evaluate()/save_embedding() called directly (the
-    examples, bench.py) register their mesh like run_loop.main does —
+    examples, the benchmark) register their mesh like run_loop.main does —
     a multi-chip run no longer takes the XLA chain for want of a
     registration — and default the mesh to every device."""
     from euler_tpu import train as train_lib

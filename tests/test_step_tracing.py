@@ -4,11 +4,13 @@ jitted step, the leaves that tile the training thread's iteration on one
 clock, the kill-switch, and the stall journal."""
 
 import gc
+import statistics
 import time
 
 import jax
 import pytest
 
+from euler_tpu import blackbox, devprof
 from euler_tpu import telemetry as T
 from euler_tpu import trace as TR
 from euler_tpu import train as train_lib
@@ -232,15 +234,26 @@ def test_telemetry_off_means_phase_profile_off(graph):
 
 
 def test_a_stalled_step_is_journalled_with_its_cause(graph):
+    # No periodic job may be alive in this process, or its tick inside
+    # the stalled step is journalled, and rightly: `run_loop.main()`
+    # leaves the device-memory sampler ticking once a second, and which
+    # files ran before this one in a worker is the scheduler's choice.
+    # Both samplers end within a tick of theirs: long before step 30.
+    devprof.stop_sampler()
+    blackbox.stop_sampler()
     stamps, slept = [], []
 
     def hook(step):
         stamps.append(time.monotonic())
         if step == 30:
             # 80 ms, or more where a loaded host makes the toy steps so
-            # slow that 80 ms would be under 5 x their median
-            usual = sorted(b - a for a, b in zip(stamps, stamps[1:]))
-            slept.append(max(0.08, 8 * usual[len(usual) // 2]))
+            # slow that 80 ms would be under 5 x their median: the
+            # journal's, which at step 30 is still that of its first
+            # FIRST steps, or the run's so far, whichever is larger
+            gaps = [b - a for a, b in zip(stamps, stamps[1:])]
+            usual = max(statistics.median(gaps),
+                        statistics.median(gaps[:T.StallJournal.FIRST]))
+            slept.append(max(0.08, 8 * usual))
             time.sleep(slept[0])
             gc.collect(0)  # a young collection: listed, and cheap
 
