@@ -45,7 +45,7 @@ enum StepPhase : int {
   kPhaseInputStall = 0,  // consumer blocked on the prefetch queue
   kPhaseSample,          // worker make_batch produce time (graph engine)
   kPhaseH2d,             // host->device transfer (shard_batch/device_put)
-  kPhaseDevice,          // device compute, fenced via block_until_ready
+  kPhaseDevice,          // jitted call + the fence of the steps that have one
   kPhaseHost,            // optimizer/bookkeeping tail on the host
   kPhaseStep,            // whole-step wall (the sum check for the rest)
   kPhaseCompile,         // XLA backend compile (jax.monitoring via
@@ -57,7 +57,7 @@ enum StepPhase : int {
   // checkpoint + host_other (telemetry.py PHASE_PARENT).
   kPhaseInputOther,      // between two bodies, less input_stall
   kPhaseDispatch,        // the jitted step call, to its return
-  kPhaseFence,           // block_until_ready on the step's loss
+  kPhaseFence,           // block_until_ready, every sync_every-th step
   kPhaseHook,            // step_hook(step)
   kPhaseLogFlush,        // metric materialisation every log_every steps
   kPhaseCheckpoint,      // ckpt.save

@@ -157,18 +157,23 @@ def train(
 
     phase_profile records the step-phase histograms (OBSERVABILITY.md
     "Step phases"): input_stall + sample inside the prefetch pipeline,
-    h2d (host->device transfer), device (compute, FENCED per step via
-    block_until_ready — attribution needs the fence, so async dispatch
-    no longer runs ahead; host sampling still overlaps through the
-    prefetch workers), host (optimizer/bookkeeping tail), and the
-    whole-step wall. On this thread the leaves input_stall, input_other,
-    h2d, dispatch, fence, hook, log_flush, checkpoint and host_other
-    tile every iteration on one clock (device = dispatch + fence and
-    host = the last four are kept as histograms), and a StallJournal
-    journals the steps that took several times the running median. None
-    (default) follows the telemetry kill-switch: profiling on when
-    telemetry is on, and `telemetry=0` restores the fully-async
-    unfenced loop with none of this.
+    h2d (host->device transfer), device (the jitted call plus the fence
+    of the steps that have one: host wall, not device time — the
+    device's share of a step is read from a capture's scopes), host
+    (optimizer/bookkeeping tail), and the whole-step wall. On this
+    thread the leaves input_stall, input_other, h2d, dispatch, hook,
+    fence, log_flush, checkpoint and host_other tile every iteration on
+    one clock (device = dispatch + fence and host = the other four are
+    kept as histograms), and a StallJournal journals the steps that
+    took several times the running median. Every leaf is a clock
+    reading, so recording changes nothing about when the loop
+    synchronises. None (default) follows the telemetry kill-switch:
+    profiling on when telemetry is on, none of it with `telemetry=0`.
+
+    The thread waits for the device once every 32 steps (`fence`: bounds
+    what is queued; every step on a virtual CPU mesh) and once a log
+    window (`log_flush`: one device-to-host pull of the window's
+    metrics and its last loss), with phase_profile on or off.
 
     source_fn(step) -> int64 root-node batch (fixed size, divisible by the
     mesh size). All sampling runs in the prefetch workers.
@@ -366,12 +371,15 @@ def train(
 
     def flush():
         nonlocal window_metrics, t0
-        acc = _metric_zero(name)
-        for m in window_metrics:
-            acc = _metric_accumulate(name, acc, m)
-        loss_v = float(last_loss)
-        # Metric/loss materialization is the training loop's d2h point.
+        # Metric/loss materialization is the training loop's d2h point:
+        # one pull of the whole window, whose copies the loop started,
+        # accumulated on the host.
         devprof.count_d2h((window_metrics, last_loss))
+        metrics, loss = jax.device_get((window_metrics, last_loss))
+        acc = _metric_zero(name)
+        for m in metrics:
+            acc = _metric_accumulate(name, acc, m)
+        loss_v = float(loss)
         mv = _metric_value(name, acc)
         dt = time.time() - t0
         sps = len(window_metrics) / dt
@@ -429,11 +437,14 @@ def train(
             # With phase_profile the leaves tile this thread's iteration,
             # each ending where the next begins (`mark`): input_other
             # (since the last body's end, around the queue wait that
-            # prefetch recorded as input_stall) | h2d | dispatch | fence |
-            # hook | log_flush | checkpoint | host_other. `step` spans
-            # body end to body end.
+            # prefetch recorded as input_stall) | h2d | dispatch | hook |
+            # fence (every sync_every-th step) | log_flush | checkpoint |
+            # host_other. `step` spans body end to body end.
             cur = steps_done  # 0-based step index, matches prefetch labels
             if profile_dir and steps_done - start_step == profile_steps[0]:
+                # the device lane of the capture holds the ops of the
+                # profiled steps and no others: nothing is still queued
+                jax.block_until_ready(last_loss)
                 jax.profiler.start_trace(profile_dir)
                 # Stamp the monotonic-clock marker so the device lanes of
                 # this capture can be time-aligned with the host phase
@@ -484,12 +495,13 @@ def train(
                     "time"
                 )
             if phase_profile:
-                t_dev = mark
-                mark = leaf("dispatch", mark, cur, leaves)
-                jax.block_until_ready(last_loss)
-                mark = t_host = leaf("fence", mark, cur, leaves)
-                record_phase_hist("device", t_host - t_dev)
+                mark = t_host = leaf("dispatch", mark, cur, leaves)
+            # the window's values start for the host as they are produced,
+            # so the flush finds all but the last step's there already
+            metric.copy_to_host_async()
             window_metrics.append(metric)
+            if len(window_metrics) == log_every:
+                last_loss.copy_to_host_async()
             steps_done += 1
             if step_hook is not None:
                 step_hook(steps_done)
@@ -502,6 +514,8 @@ def train(
                     write_step_hlo(step_fn, state, batch, profile_dir)
             if steps_done % sync_every == 0:
                 jax.block_until_ready(last_loss)
+                if phase_profile:
+                    mark = leaf("fence", mark, cur, leaves)
             if len(window_metrics) == log_every:
                 flush()
                 if phase_profile:
@@ -512,7 +526,12 @@ def train(
                     mark = leaf("checkpoint", mark, cur, leaves)
             if phase_profile:
                 now = leaf("host_other", mark, cur, leaves)
-                record_phase_hist("host", now - t_host)
+                # the parents, one sample a step, cut from their leaves'
+                # own clock readings: device = dispatch + this step's
+                # fence, if it has one; host = the rest since the dispatch
+                fence_us = leaves.get("fence", 0)
+                record_phase_hist("device", leaves["dispatch"] + fence_us)
+                record_phase_hist("host", now - t_host - fence_us)
                 record_phase("step", now - t_step, step=cur, end_us=now)
                 if cur != trace_began:
                     journal.step(cur, t_step, now, leaves)
