@@ -361,15 +361,31 @@ def _kernel(ids_ref, seed_ref, pk_hbm, *rest,
             sem.at[slot],
         )
 
+    def each_row(copy):
+        """``copy(r)`` for every row of a stage. A walk step (one draw a
+        row) runs the rows as a loop on the core: unrolled here, a
+        512-row stage is 1,536 DMA descriptors to trace and lower, 10 to
+        35 s of Python a kernel, and a walk holds ``walk_len`` kernels
+        (PERF.md section 6, PR 35). The fan-out draws keep the unrolled
+        stage they were measured with."""
+        if count == 1:
+            def eight(g, _):    # rows is a power of two, 8 at the least
+                for j in range(8):
+                    copy(g * 8 + j)
+                return 0
+
+            jax.lax.fori_loop(0, rows // 8, eight, 0)
+        else:
+            for r in range(rows):
+                copy(r)
+
     def issue(slot, it):
         base = it * rows
-        for r in range(rows):
-            dma(slot, r, ids_ref[base + r]).start()
+        each_row(lambda r: dma(slot, r, ids_ref[base + r]).start())
 
     def wait(slot, it):
         base = it * rows
-        for r in range(rows):
-            dma(slot, r, ids_ref[base + r]).wait()
+        each_row(lambda r: dma(slot, r, ids_ref[base + r]).wait())
 
     issue(0, 0)
 

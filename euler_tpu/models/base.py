@@ -631,7 +631,7 @@ class Model:
                 )
                 params = optax.apply_updates(state["params"], updates)
             new_state = {"params": params, "opt_state": opt_state}
-            if consts:
+            if "consts" in state:
                 new_state["consts"] = consts
             return new_state, loss, out.metric
 
@@ -654,6 +654,29 @@ class Model:
             )
 
         return embed_step
+
+
+def describe_table(what: str, table, width: int, count: str) -> None:
+    """Say what the device made of a per-node [rows, width] table of a
+    training state (a Scalable* store, an id-embedding table): the gauges
+    ``store_table_width`` / ``store_table_stored_width`` and one
+    route-log line, read from the placed array's own layout. A stored
+    width of 0 means the table lies column-major: rows not contiguous,
+    and a step that gathers and scatters rows copies the whole table
+    around them."""
+    layout = table.format.layout
+    rows_major = tuple(layout.major_to_minor) == (0, 1)
+    lanes = layout.tiling[0][-1] if layout.tiling else 1
+    stored = -(-width // lanes) * lanes if rows_major else 0
+    devprof.record_store_table(width, stored)
+    log.info(
+        "%s table: [%d, %d] %s %s, device layout major_to_minor=%s "
+        "tiling=%s: %s",
+        what, table.shape[0], width, table.dtype, count,
+        tuple(layout.major_to_minor), tuple(layout.tiling),
+        f"rows contiguous, stored {stored} wide" if rows_major
+        else "column-major, rows not contiguous",
+    )
 
 
 def last_occurrence(ids):
@@ -833,22 +856,10 @@ class ScalableStoreModel(Model):
         after ``put_global``: a stored width of 0 means a state that
         did not pass ``state_sharding`` on its way to the device."""
         stores = state.get("stores") or []
-        if not stores:
-            return
-        table = stores[0]
-        layout = table.format.layout
-        rows_major = tuple(layout.major_to_minor) == (0, 1)
-        lanes = layout.tiling[0][-1] if layout.tiling else 1
-        stored = -(-self.dim // lanes) * lanes if rows_major else 0
-        devprof.record_store_table(self.dim, stored)
-        log.info(
-            "store table: [%d, %d] %s x %d (stores and gradient stores), "
-            "device layout major_to_minor=%s tiling=%s: %s",
-            table.shape[0], self.dim, table.dtype, 2 * len(stores),
-            tuple(layout.major_to_minor), tuple(layout.tiling),
-            f"rows contiguous, stored {stored} wide" if rows_major
-            else "column-major, rows not contiguous",
-        )
+        if stores:
+            describe_table(
+                "store", stores[0], self.dim,
+                f"x {2 * len(stores)} (stores and gradient stores)")
 
     def _expand_batch(self, batch, consts):
         """Hook: turn a device-sampling batch (roots + seed) into the

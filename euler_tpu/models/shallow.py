@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import flax.linen as nn
+import jax
 import numpy as np
 
 from euler_tpu import ops
@@ -76,8 +77,6 @@ class _ShallowUnsupModule(nn.Module):
         -> skip-gram pairs)."""
         if "src" in batch:
             return batch["src"], batch.get("pos"), batch.get("negs")
-        import jax
-
         from euler_tpu.graph import device as device_graph
 
         roots = batch["roots"]
@@ -85,60 +84,79 @@ class _ShallowUnsupModule(nn.Module):
         k_walk, k_neg = jax.random.split(key)
         adj = consts["adj"][self.adj_key]
         if self.walk_len > 0:
-            if self.walk_p != 1.0 or self.walk_q != 1.0:
-                # trace-time guard: biased membership search is garbage
-                # on unsorted rows; the naming convention (adj_key(et,
-                # sorted=True)) is the sortedness contract
-                if not self.adj_key.endswith("_sorted"):
-                    raise ValueError(
-                        "biased walks (walk_p/walk_q != 1) need an "
-                        "id-sorted adjacency slab: build consts with "
-                        "add_sampling_consts(sorted=True) and pass the "
-                        "matching adj_key(et, sorted=True)"
-                    )
-                if "off" in adj:
-                    # flat-CSR alias form (chosen by set_sampling_options
-                    # or forced by the truncation guard): the rejection-
-                    # sampled walk is exact over FULL neighbor lists
-                    paths = device_graph.alias_biased_random_walk(
-                        adj, roots, k_walk, self.walk_len,
-                        self.walk_p, self.walk_q,
-                        trials=self.walk_trials or None,
-                    )
-                else:
-                    paths = device_graph.biased_random_walk(
-                        adj, roots, k_walk, self.walk_len,
-                        self.walk_p, self.walk_q,
-                    )
-            else:
-                paths = device_graph.random_walk(
-                    adj, roots, k_walk, self.walk_len
-                )
-            ti, ci = ops.walk.pair_indices(
-                self.walk_len + 1, self.left_win, self.right_win
-            )
-            src = paths[:, ti].reshape(-1)
-            pos = paths[:, ci].reshape(-1)
+            with jax.named_scope("walk"):
+                src, pos = self._walk_pairs(adj, roots, k_walk)
         else:
             src = roots
-            pos = device_graph.sample_neighbor(adj, roots, k_walk, 1)[:, 0]
-        negs = device_graph.sample_node(
-            consts["negs"], k_neg, src.shape[0] * self.num_negs
-        )
+            with jax.named_scope("draw"):
+                pos = device_graph.sample_neighbor(
+                    adj, roots, k_walk, 1)[:, 0]
+        with jax.named_scope("negatives"):
+            negs = device_graph.sample_node(
+                consts["negs"], k_neg, src.shape[0] * self.num_negs
+            )
         return self._feats(src), self._feats(pos), self._feats(negs)
+
+    def _walk_pairs(self, adj, roots, key):
+        """(src, pos) of the skip-gram pairs of one device walk a root:
+        ``walk_len`` chained single-neighbour draws, then the window
+        rule's static index arrays."""
+        from euler_tpu.graph import device as device_graph
+
+        if self.walk_p != 1.0 or self.walk_q != 1.0:
+            # trace-time guard: biased membership search is garbage
+            # on unsorted rows; the naming convention (adj_key(et,
+            # sorted=True)) is the sortedness contract
+            if not self.adj_key.endswith("_sorted"):
+                raise ValueError(
+                    "biased walks (walk_p/walk_q != 1) need an "
+                    "id-sorted adjacency slab: build consts with "
+                    "add_sampling_consts(sorted=True) and pass the "
+                    "matching adj_key(et, sorted=True)"
+                )
+            if "off" in adj:
+                # flat-CSR alias form (chosen by set_sampling_options
+                # or forced by the truncation guard): the rejection-
+                # sampled walk is exact over FULL neighbor lists
+                paths = device_graph.alias_biased_random_walk(
+                    adj, roots, key, self.walk_len,
+                    self.walk_p, self.walk_q,
+                    trials=self.walk_trials or None,
+                )
+            else:
+                paths = device_graph.biased_random_walk(
+                    adj, roots, key, self.walk_len,
+                    self.walk_p, self.walk_q,
+                )
+        else:
+            paths = device_graph.random_walk(
+                adj, roots, key, self.walk_len
+            )
+        ti, ci = ops.walk.pair_indices(
+            self.walk_len + 1, self.left_win, self.right_win
+        )
+        return paths[:, ti].reshape(-1), paths[:, ci].reshape(-1)
 
     def _gathered(self, feats, consts):
         return base.gather_consts(feats, consts, self.feature_dim)
 
+    def _rows(self, encoder, feats, consts):
+        """One node set through an encoder. The ``pair_rows`` scope holds
+        the gathers from the id-embedding tables (and, transposed, the
+        scatter-adds of their gradients); the feature gathers and the
+        dense layers inside keep their own, inner scopes."""
+        with jax.named_scope("pair_rows"):
+            return encoder(self._gathered(feats, consts))
+
     def embed(self, batch, consts=None):
         src, _, _ = self._inputs(batch, consts)
-        return self.target(self._gathered(src, consts))
+        return self._rows(self.target, src, consts)
 
     def __call__(self, batch, consts=None):
         src, pos, negs = self._inputs(batch, consts)
-        emb = self.target(self._gathered(src, consts))  # [B, d]
-        emb_pos = self._context(self._gathered(pos, consts))
-        emb_negs = self._context(self._gathered(negs, consts))
+        emb = self._rows(self.target, src, consts)  # [B, d]
+        emb_pos = self._rows(self._context, pos, consts)
+        emb_negs = self._rows(self._context, negs, consts)
         B = emb.shape[0]
         loss, mrr = base.unsupervised_decoder(
             emb.reshape(B, 1, -1),
@@ -210,6 +228,24 @@ class _ShallowUnsupervised(base.Model):
                 sorted=self.adj_sorted,
             )
         return consts
+
+    def describe_state(self, state) -> None:
+        """The id-embedding tables' width and what the device made of it:
+        the gauges and the route-log line of ``base.describe_table``,
+        read from the placed arrays. The tables are [max_id + 2, dim]
+        parameters that every step gathers rows from and scatter-adds
+        rows into; a TPU keeps a lane-multiple dim rows-major by itself,
+        any other width is the case the line is there to show."""
+        tables = [
+            tower["Embedding_0"]["embeddings"]
+            for _, tower in sorted(state["params"].items())
+            if "Embedding_0" in tower
+        ]
+        if tables:
+            base.describe_table(
+                "embedding", tables[0], tables[0].shape[1],
+                f"x {len(tables)} (id-embedding parameters, each under "
+                "the optimizer's state)")
 
     def _pack(self, graph, src, pos, negs) -> dict:
         return {

@@ -64,8 +64,14 @@ ALIGN_PREFIX = "eg_align:"
 # are the per-node stores of models/base.py ScalableStoreModel: the
 # gathers from the stores and gradient stores with the clearing set, and
 # the scatter-add of gradients with the set of fresh activations.
+# ``walk``, ``negatives`` and ``pair_rows`` are the shallow embedding
+# models' (models/shallow.py): the chained single-neighbour draws of the
+# device walks with the pair indexing, the negatives' draw from the node
+# sampler, and the gathers from the id-embedding tables with, transposed,
+# the scatter-adds of their gradients.
 STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
-               "dense", "loss", "optimizer", "stores_read", "stores_write")
+               "dense", "loss", "optimizer", "stores_read", "stores_write",
+               "walk", "negatives", "pair_rows")
 
 # File ``train(profile_dir=)`` leaves the compiled step's HLO text in,
 # beside the capture: the map from a trace event's instruction name to

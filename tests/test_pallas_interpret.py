@@ -87,6 +87,46 @@ def test_single_hop_exact_vs_reference(monkeypatch):
     np.testing.assert_array_equal(np.asarray(out), ref_pick(adj, nodes, u))
 
 
+@pytest.mark.parametrize("w", [7, 200], ids=["k1", "k2"])
+def test_walk_step_rows_run_as_a_loop_exact_vs_reference(monkeypatch, w):
+    """A walk step is one draw a row: the kernel then issues and awaits
+    a stage's row copies in a loop on the core instead of unrolling them
+    (512 rows a stage at real size). Three stages of 8 with padding, OOB
+    ids and an unsampleable row: picks equal the numpy oracle exactly,
+    and a five-step chain of such draws walks the oracle's path."""
+    monkeypatch.setattr(ps, "_MAX_R", 8)
+    adj = make_adj(24, w, seed=4, unsampleable=(3,))
+    rng = np.random.default_rng(5)
+    nodes = np.array(
+        [0, 1, 3, 23, 22, -4, 30, 5, 6, 7, 8, 9, 10, 11, 2, 12, 13, 14],
+        np.int32,
+    )
+    seed = jnp.asarray([11, 13], jnp.int32)
+    cur, want = nodes, nodes
+    for _ in range(5):
+        u = rng.random((len(nodes), 1), dtype=np.float32)
+        cur = np.asarray(ps.sample_neighbor(
+            adj, jnp.asarray(cur), seed, 1, u=u))[:, 0]
+        want = ref_pick(adj, want, u)[:, 0]
+        np.testing.assert_array_equal(cur, want)
+    # the loop is in the traced kernel: eight copy starts in the
+    # prologue, eight in the look-ahead, eight waits, whatever the
+    # stage's rows
+    text = str(jax.make_jaxpr(lambda n: ps.sample_neighbor(
+        adj, n, seed, 1))(jnp.asarray(nodes)))
+    assert text.count("dma_start") == 16 and text.count("dma_wait") == 8
+    monkeypatch.setattr(ps, "_MAX_R", 64)
+    big = jnp.asarray(np.resize(nodes, 200))
+    text = str(jax.make_jaxpr(lambda n: ps.sample_neighbor(
+        adj, n, seed, 1))(big))
+    assert text.count("dma_start") == 16 and text.count("dma_wait") == 8
+    text = str(jax.make_jaxpr(lambda n: ps.sample_neighbor(
+        adj, n, seed, 2))(big))
+    rows = 64 // (1 if w == 7 else 2)      # a stage's rows, unrolled
+    assert text.count("dma_start") == 2 * rows
+    assert text.count("dma_wait") == rows
+
+
 def test_single_hop_wide_slab_cross_register(monkeypatch):
     """K=2 slab (W=200): uniforms aimed at lanes on both sides of the
     128-lane register boundary must pick exactly the oracle's lanes."""

@@ -16,13 +16,16 @@ from euler_tpu import telemetry as T
 from euler_tpu import trace as TR
 from euler_tpu import train as train_lib
 from euler_tpu.graph import native
-from euler_tpu.models import ScalableSage, SupervisedGraphSage
+from euler_tpu.models import LINE, Node2Vec, ScalableSage, SupervisedGraphSage
 from euler_tpu.parallel import make_mesh
 
 MAX_ID = 16  # fixture ids go up to 16
 # the scopes of the historical-store family's step alone (models/base.py
 # ScalableStoreModel); every other scope is on GraphSAGE's step too
 STORE_SCOPES = {"stores_read", "stores_write"}
+# the scopes of the shallow embedding models' step alone (models/shallow.py)
+WALK_SCOPES = {"walk", "negatives", "pair_rows"}
+FAMILY_SCOPES = STORE_SCOPES | WALK_SCOPES
 TRAIN_THREAD_LEAVES = {"input_stall", "input_other", "h2d", *T.PHASE_PARENT}
 
 
@@ -81,7 +84,7 @@ def test_lowered_train_step_holds_every_step_scope(graph):
     text = jax.jit(m.make_train_step(opt)).lower(
         state, m.sample(graph, roots)).as_text(debug_info=True)
     for scope in TR.STEP_SCOPES:
-        assert (f"/{scope}/" in text) == (scope not in STORE_SCOPES), scope
+        assert (f"/{scope}/" in text) == (scope not in FAMILY_SCOPES), scope
     # the backward pass rides its scope: no scope of its own is needed
     assert "transpose(jvp(" in text
 
@@ -101,7 +104,7 @@ def test_lowered_store_step_holds_every_step_scope(graph):
     state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
     text = jax.jit(m.make_train_step(opt)).lower(
         state, m.sample(graph, roots)).as_text(debug_info=True)
-    for scope in TR.STEP_SCOPES:
+    for scope in set(TR.STEP_SCOPES) - WALK_SCOPES:
         assert f"/{scope}/" in text, scope
     lines = text.splitlines()
     gathers = [ln for ln in lines if "stores_read" in ln and "gather" in ln]
@@ -109,6 +112,35 @@ def test_lowered_store_step_holds_every_step_scope(graph):
     assert gathers and adds
     assert any("/optimizer/" in ln for ln in lines)
     assert not any("/stores_" in ln and "/optimizer/" in ln for ln in lines)
+
+
+@pytest.mark.parametrize("walk_len", [5, 0], ids=["node2vec", "line"])
+def test_lowered_shallow_step_holds_the_walk_scopes(graph, walk_len):
+    """The shallow embedding models' step: the chained draws and the pair
+    indexing under ``walk`` (LINE's one draw of a positive under
+    ``draw``), the negatives' draw under ``negatives``, the gathers from
+    the id-embedding tables and, transposed, the scatter-adds of their
+    gradients under ``pair_rows``, the pair loss under ``loss``, Adam
+    over the tables under ``optimizer``."""
+    kw = dict(node_type=-1, edge_type=[0, 1], max_id=MAX_ID, dim=8,
+              num_negs=3, xent_loss=True, device_sampling=True)
+    m = Node2Vec(walk_len=walk_len, left_win_size=2, right_win_size=2,
+                 **kw) if walk_len else LINE(order=2, **kw)
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    text = jax.jit(m.make_train_step(opt)).lower(
+        state, m.sample(graph, roots)).as_text(debug_info=True)
+    here = {"negatives", "pair_rows", "loss", "optimizer",
+            "walk" if walk_len else "draw"}
+    for scope in TR.STEP_SCOPES:
+        assert (f"/{scope}/" in text) == (scope in here), scope
+    lines = text.splitlines()
+    assert any("/pair_rows/" in ln and "gather" in ln for ln in lines)
+    assert any("transpose(jvp(" in ln and "/pair_rows/" in ln
+               and "scatter" in ln for ln in lines)
+    assert not any("/pair_rows/" in ln and "/optimizer/" in ln
+                   for ln in lines)
 
 
 def test_benchmark_keeps_the_same_scope_names():
@@ -122,7 +154,7 @@ def test_profiled_run_leaves_the_compiled_step_text(graph, tmp_path):
     _train(graph, 6, profile_dir=str(tmp_path), profile_steps=(2, 4))
     text = (tmp_path / TR.STEP_HLO_FILE).read_text()
     assert text.startswith("HloModule jit_train_step")
-    for scope in set(TR.STEP_SCOPES) - STORE_SCOPES:
+    for scope in set(TR.STEP_SCOPES) - FAMILY_SCOPES:
         assert f"/{scope}/" in text, scope
     # the flag the text's compile is keyed with is put back
     assert not jax.config.jax_compilation_cache_include_metadata_in_key
