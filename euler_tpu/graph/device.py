@@ -20,9 +20,11 @@ TPU-native design uploads the adjacency ONCE and samples on device:
   sum(u >= cum) comparison — the vectorized equivalent of the binary
   search, exact same distribution (statistically verified against the
   host engine in tests/test_device_graph.py).
-- ``build_node_sampler`` / ``sample_node`` do the same for weighted
-  global root sampling (reference compact_graph.cc:32-56), via
-  searchsorted over the per-type cumulative weights.
+- ``build_node_sampler`` / ``sample_node`` are the weighted global node
+  sampler (roots, negatives): one Walker alias table per node type, as
+  the reference's CompactGraph::BuildGlobalSampler builds
+  (compact_graph.cc:74-104), and one alias draw a node — an integer
+  slot, a uniform, three gathers, no search.
 
 Everything returned is a dict of numpy arrays meant to live in
 ``state["consts"]`` — replicated (or sharded) over the mesh, aliased
@@ -186,6 +188,32 @@ def build_adjacency(
     }
 
 
+def _build_alias_rows(offsets: np.ndarray, w_flat: np.ndarray):
+    """(prob [E] float32, alias [E] int32, ROW-LOCAL slots) Walker alias
+    tables over the CSR rows ``offsets`` ([R+1] int64) of ``w_flat``, by
+    the native builder (eg_build_alias_csr: scaled in float64, OpenMP
+    over rows). A slot nothing was paired with keeps prob 1 and itself
+    as alias, and so does every slot of a zero-total row."""
+    import ctypes
+
+    from euler_tpu.graph import native
+
+    e = len(w_flat)
+    prob = np.ones(e, dtype=np.float32)
+    alias_local = np.zeros(e, dtype=np.int32)
+    if e:
+        offsets = np.ascontiguousarray(offsets, np.int64)
+        w_flat = np.ascontiguousarray(w_flat, np.float32)
+        native.lib().eg_build_alias_csr(
+            offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+            ctypes.c_int64(len(offsets) - 1),
+            w_flat.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+            prob.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+            alias_local.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
+        )
+    return prob, alias_local
+
+
 def build_alias_adjacency(
     graph,
     edge_types,
@@ -221,11 +249,6 @@ def build_alias_adjacency(
     alias_biased_random_walk's parent-membership bisection (the alias
     draw itself is order-independent, so sorted tables sample the same
     distribution)."""
-    import ctypes
-
-    from euler_tpu.graph import native
-
-    n_rows = max_id + 2
     default = max_id + 1
     counts_all, nbr_flat, w_flat, offsets = (
         _prefetched
@@ -238,17 +261,7 @@ def build_alias_adjacency(
             f"alias adjacency needs int32 slots: {e} edges; shard the "
             "graph first"
         )
-    prob = np.ones(e, dtype=np.float32)
-    alias_local = np.zeros(e, dtype=np.int32)
-    if e:
-        L = native.lib()
-        L.eg_build_alias_csr(
-            offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-            ctypes.c_int64(n_rows),
-            w_flat.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-            prob.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
-            alias_local.ctypes.data_as(ctypes.POINTER(ctypes.c_int32)),
-        )
+    prob, alias_local = _build_alias_rows(offsets, w_flat)
     row_base = np.repeat(offsets[:-1], counts_all)
     alias_ids = (
         nbr_flat[row_base + alias_local].astype(np.int32)
@@ -310,7 +323,8 @@ def _alias_sample_neighbor(adj: dict, nodes, key, count: int):
     return jnp.where(ok[..., None], pick, default)
 
 
-SEG = 1 << 16  # two-level draw segment size: device arrays are float32
+SEG = 1 << 16  # two-level draw segment size (the typed negative sampler,
+# build_typed_node_sampler / sample_node_with_src): device arrays are float32
 # (jax x32), so a SINGLE cumulative over ~16M comparably-weighted nodes
 # collides at float32 resolution (spacing near 1.0 is 2^-24) and tail
 # nodes silently get probability 0. Normalizing the cumulative WITHIN
@@ -385,16 +399,21 @@ def _export_node_arrays(graph, max_id: int, need_types: bool,
 
 
 def build_node_sampler(graph, node_type: int = -1, max_id: int = 0) -> dict:
-    """Weighted global root sampler for one node type (-1 = all types,
-    type picked by weight sum first — reference compact_graph.cc:32-56;
-    with-replacement draws over cum weights give exactly that marginal).
+    """Weighted global node sampler for one node type (-1 = all types:
+    with-replacement draws over all weights give the marginal of the
+    reference's pick-a-type-then-a-node, compact_graph.cc:32-56).
 
-    Returns the two-level layout {"ids": [M] int32, "cum": [M] float32
-    (normalized within SEG-node segments), "seg_cum": [S] float32} over
-    the matching nodes, sorted by id for determinism — exact beyond the
-    ~16M-node float32 cliff a flat cumulative would hit (see SEG). Works
-    against local AND remote graphs (node_weights/node_types scatter per
-    shard since round 3).
+    Returns one Walker alias table, as the reference builds one per node
+    type (CompactGraph::BuildGlobalSampler, compact_graph.cc:74-104):
+    {"ids": [M] int32, "prob": [M] float32, "alias": [M] int32 (slots
+    of this table)} over the matching nodes of weight > 0, sorted by id
+    for determinism. Three one-dimensional tables, 12 B a node; stacked
+    as [M, 3] the minor dimension would pad to 128 lanes on the chip.
+    Each prob is its own threshold in [0, 1], so nothing accumulates
+    and P(node) = w / total holds at any M < 2^31 (a float32 cumulative
+    over ~16M comparable nodes collides: see SEG). Works against local
+    AND remote graphs (node_weights/node_types scatter per shard since
+    round 3).
     """
     ids = np.arange(max_id + 1, dtype=np.int64)
     weights, types = _export_node_arrays(graph, max_id, node_type != -1)
@@ -403,36 +422,39 @@ def build_node_sampler(graph, node_type: int = -1, max_id: int = 0) -> dict:
         ids, weights = ids[mask], weights[mask]
     keep = weights > 0
     ids, weights = ids[keep], weights[keep]
-    if len(ids) == 0:
+    m = len(ids)
+    if m == 0:
         raise ValueError(f"no nodes of type {node_type} with weight > 0")
-    seg_cum, within = _segment_cum(weights)
-    return {
-        "ids": ids.astype(np.int32),
-        "cum": within,
-        "seg_cum": seg_cum,
-    }
+    if m >= 1 << 31:
+        raise ValueError(
+            f"node sampler needs int32 slots: {m} nodes; shard the graph "
+            "first"
+        )
+    prob, alias = _build_alias_rows(np.array([0, m], np.int64), weights)
+    return {"ids": ids.astype(np.int32), "prob": prob, "alias": alias}
 
 
 # ---- jit-side sampling ----
 
 
 def sample_node(sampler: dict, key, count: int):
-    """[count] int32 roots drawn weight-proportionally on device.
-
-    Two-level draw: u1 picks a SEG-node segment from seg_cum, u2
-    bisects that segment's within-normalized cumulative — P(node) =
-    (seg_total/total) * (w/seg_total) = w/total exactly, with every
-    float32 step representable regardless of graph size (see SEG)."""
+    """[count] int32 nodes drawn weight-proportionally on device: one
+    alias draw each (the reference's SampleNode over its
+    FastWeightedCollection). An INTEGER slot i ~ U[0, M) — above 2^24
+    entries floor(u * M) of a float32 uniform skips slots — then keep i
+    with prob[i], else take alias[i]: three gathers of ``count``
+    elements, whatever the weights."""
+    # tolerate plain-numpy tables (tests build them host-side; traced
+    # callers pass device arrays already)
+    ids, prob, alias = (
+        jnp.asarray(sampler[k]) for k in ("ids", "prob", "alias")
+    )
+    m = int(ids.shape[0])
+    _log_route(f"sample_node {count}", "alias draw", f"{m} entries")
     k1, k2 = jax.random.split(key)
-    m = int(sampler["ids"].shape[0])
-    s = jnp.searchsorted(sampler["seg_cum"], jax.random.uniform(k1, (count,)))
-    s = jnp.clip(s, 0, sampler["seg_cum"].shape[0] - 1)
-    lo = (s * SEG).astype(jnp.int32)
-    hi = jnp.minimum(lo + SEG, m).astype(jnp.int32)
-    u2 = jax.random.uniform(k2, (count,))
-    steps = max(min(m, SEG).bit_length(), 1)
-    idx = _bisect_first_ge(sampler["cum"], lo, hi, u2, steps)
-    return sampler["ids"][idx]
+    i = jax.random.randint(k1, (count,), 0, m)
+    u = jax.random.uniform(k2, (count,))
+    return ids[jnp.where(u < prob[i], i, alias[i])]
 
 
 _KERNEL_MESH = None  # (Mesh, data_axis) set by set_kernel_mesh
@@ -811,8 +833,9 @@ def build_typed_node_sampler(graph, num_types: int, max_id: int) -> dict:
 
     Returns {"ids": [M] int32 (nodes sorted by type), "cum": [M] float32
     (cumulative weights normalized within SEG-node sub-segments of each
-    type — the same two-level layout as build_node_sampler, so a single
-    type beyond ~16M nodes keeps exact float32 draws), "off": [T+1]
+    type: the two-level cumulative layout of _segment_cum, so a single
+    type beyond ~16M nodes keeps exact float32 draws; the untyped
+    build_node_sampler is an alias table instead), "off": [T+1]
     int32 type offsets into ids, "seg_cum": [G] float32 (per-type
     normalized cumulative over sub-segment totals), "tseg_off": [T+1]
     int32 type offsets into seg_cum, "types": [N+2] int32 node-type

@@ -156,8 +156,9 @@ def _is_table(path, x) -> bool:
     per-node tables that row-shard (and row-pad) over the model axis.
     Under consts, only the per-node lookup tables (features / labels)
     shard; device-sampling structures (adj / roots / negs and anything
-    else) replicate — their cumulative-weight arrays must stay contiguous
-    and unpadded (zero-padding would unsort the searchsorted input)."""
+    else) replicate — their cumulative-weight and alias arrays must stay
+    contiguous and unpadded (zero-padding would unsort a searchsorted
+    input and add slots to an alias table)."""
     name = _top_key(path)
     if name not in _TABLE_KEYS or np.ndim(x) < 1:
         return False

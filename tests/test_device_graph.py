@@ -159,16 +159,18 @@ def test_typed_negatives_match_src_type(graph, meta):
         assert abs((draws == i).mean() - probs[i]) < 0.03
 
 
-def test_two_level_sampler_multi_segment_exact(graph, monkeypatch):
-    """SEG shrunk to 4 so the tiny fixture spans several segments: the
-    two-level draw (segment pick x within-segment bisect) must reproduce
-    the host sampling weights — the default-SEG distribution tests only
-    ever exercise one segment."""
+def test_alias_node_sampler_exact_on_fixture_weights(graph):
+    """The fixture's node weights are not uniform, so the alias table
+    pairs slots: some prob under 1, some alias other than itself — and
+    the draw (integer slot, keep with prob else alias) must still
+    reproduce the host sampling weights."""
     import jax
 
-    monkeypatch.setattr(device, "SEG", 4)
     sampler = device.build_node_sampler(graph, -1, MAX_ID)
-    assert sampler["seg_cum"].shape[0] > 1
+    assert set(sampler) == {"ids", "prob", "alias"}
+    slots = np.arange(len(sampler["ids"]))
+    assert (sampler["prob"] < 1).any()
+    assert (sampler["alias"] != slots).any()
     draws = np.asarray(
         device.sample_node(sampler, jax.random.PRNGKey(5), 20000)
     )
@@ -214,34 +216,48 @@ def test_two_level_typed_negatives_multi_segment(graph, meta, monkeypatch):
             assert abs((draws == i).mean() - probs[i]) < 0.03
 
 
-def test_two_level_sampler_beyond_float32_cliff():
+class _ArrayGraph:
+    """node_weights / node_types from two arrays: all build_node_sampler
+    asks of a graph."""
+
+    def __init__(self, weights, types=None):
+        self.weights = np.asarray(weights, np.float32)
+        self.types = (
+            np.zeros(len(self.weights), np.int32)
+            if types is None else np.asarray(types, np.int32)
+        )
+
+    def node_weights(self, ids):
+        return self.weights[ids]
+
+    def node_types(self, ids):
+        return self.types[ids]
+
+
+def test_alias_node_sampler_beyond_float32_cliff():
     """>2^24 comparably-weighted nodes — the regime where a FLAT float32
     cumulative provably collides (adjacent values equal, tail nodes
-    silently unsampleable; the round-2 design warned and bailed here).
-    The two-level layout keeps every within-segment step representable
-    and the tail region draws at its exact probability."""
+    silently unsampleable) and where floor(u * M) of a float32 uniform
+    skips slots. The alias table has no cumulative (every prob is its
+    own threshold) and the slot is an integer draw, so the tail region
+    draws at its exact probability and every slot is reachable."""
     import jax
 
     m = (1 << 24) + (1 << 20)  # 17.8M equal-weight nodes
     tail = 1 << 20
 
-    class EqualWeightGraph:
-        def node_weights(self, ids):
-            return np.ones(len(ids), np.float32)
-
-        def node_types(self, ids):
-            return np.zeros(len(ids), np.int32)
-
-    # the flat cumulative this layout replaces DOES collide at this size
+    # the flat cumulative DOES collide at this size
     flat_tail = (
         (np.arange(m - tail, m, dtype=np.float64) + 1) / m
     ).astype(np.float32)
     assert (np.diff(flat_tail) == 0).any()
 
-    sampler = device.build_node_sampler(EqualWeightGraph(), -1, m - 1)
-    # two-level: segment steps stay representable (strictly increasing)
-    seg = sampler["cum"][: (m // device.SEG) * device.SEG]
-    assert (np.diff(seg.reshape(-1, device.SEG), axis=1) > 0).all()
+    sampler = device.build_node_sampler(
+        _ArrayGraph(np.ones(m, np.float32)), -1, m - 1
+    )
+    # equal weights: nothing to pair, every slot keeps itself
+    assert (sampler["prob"] == 1).all()
+    assert sampler["alias"][-1] == m - 1 and sampler["ids"][-1] == m - 1
     draws = np.asarray(
         device.sample_node(sampler, jax.random.PRNGKey(7), 4096)
     )
@@ -250,6 +266,100 @@ def test_two_level_sampler_beyond_float32_cliff():
     assert abs(got - p_tail) < 6 * np.sqrt(p_tail * (1 - p_tail) / 4096)
     # the very tail is reachable, not probability-0
     assert draws.max() >= m - tail
+    # above 2^24 a float32 holds even integers only: a slot made from a
+    # float32 uniform would never be odd there
+    assert (draws[draws > 1 << 24] % 2 == 1).any()
+
+
+def _skewed_weights(m=5000, seed=0):
+    w = np.random.default_rng(seed).pareto(1.2, m).astype(np.float32)
+    return w + np.float32(1e-3)
+
+
+@pytest.mark.parametrize("case", [
+    "table_is_exact", "zero_weight_and_other_types_left_out",
+    "one_node", "deterministic_in_its_key",
+])
+def test_alias_node_sampler(case):
+    import jax
+
+    if case == "table_is_exact":
+        # per node: its own slot's prob plus what the slots aliased to
+        # it give away, over M, is w / total
+        w = _skewed_weights()
+        s = device.build_node_sampler(_ArrayGraph(w), -1, len(w) - 1)
+        m = len(s["ids"])
+        assert m == len(w) and (s["prob"] < 1).any()
+        mass = s["prob"].astype(np.float64)
+        np.add.at(mass, s["alias"], 1.0 - s["prob"].astype(np.float64))
+        w64 = w.astype(np.float64)
+        np.testing.assert_allclose(
+            mass / m, w64 / w64.sum(), rtol=0, atol=1e-6
+        )
+        assert ((s["prob"] >= 0) & (s["prob"] <= 1)).all()
+        assert ((s["alias"] >= 0) & (s["alias"] < m)).all()
+    elif case == "zero_weight_and_other_types_left_out":
+        w = _skewed_weights(400, seed=1)
+        w[::7] = 0
+        types = np.arange(400) % 3
+        s = device.build_node_sampler(_ArrayGraph(w, types), 1, 399)
+        want = np.flatnonzero((types == 1) & (w > 0))
+        np.testing.assert_array_equal(s["ids"], want)
+        assert ((s["alias"] >= 0) & (s["alias"] < len(want))).all()
+        draws = np.asarray(
+            device.sample_node(s, jax.random.PRNGKey(0), 20000)
+        )
+        assert np.isin(draws, want).all()
+        with pytest.raises(ValueError, match="no nodes of type 5"):
+            device.build_node_sampler(_ArrayGraph(w, types), 5, 399)
+    elif case == "one_node":
+        w = np.zeros(9, np.float32)
+        w[6] = 2.5
+        s = device.build_node_sampler(_ArrayGraph(w), -1, 8)
+        assert s["ids"].tolist() == [6] and s["alias"].tolist() == [0]
+        draws = np.asarray(
+            device.sample_node(s, jax.random.PRNGKey(4), 64)
+        )
+        assert (draws == 6).all()
+    else:
+        w = _skewed_weights()
+        s = device.build_node_sampler(_ArrayGraph(w), -1, len(w) - 1)
+        a, b, c = (
+            np.asarray(device.sample_node(s, jax.random.PRNGKey(k), 512))
+            for k in (11, 11, 12)
+        )
+        np.testing.assert_array_equal(a, b)
+        assert (a != c).any()
+        assert a.dtype == np.int32 and a.shape == (512,)
+
+
+def test_sample_node_is_three_gathers_and_no_loop():
+    """A count, not a speed: the draw of one step's negatives at the
+    walk cell's size lowers, for the chip, to at most three gathers
+    (prob[i], alias[i], ids[pick]) and no loop — a search over a
+    cumulative table (seventeen gathers and a `while` before the alias
+    table) cannot come back unseen. Lowered for the TPU platform, which
+    needs no chip: on a CPU jax rolls the threefry rounds of the random
+    bits themselves into a `while`."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    m = 1 << 20
+    sampler = {
+        "ids": jax.ShapeDtypeStruct((m,), jnp.int32),
+        "prob": jax.ShapeDtypeStruct((m,), jnp.float32),
+        "alias": jax.ShapeDtypeStruct((m,), jnp.int32),
+    }
+    text = (
+        jax.jit(lambda s, k: device.sample_node(s, k, 76800))
+        .trace(sampler, jax.random.PRNGKey(0))
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    assert 1 <= len(re.findall(r'"?stablehlo\.gather"?\(', text)) <= 3
+    assert "stablehlo.while" not in text
 
 
 def test_typed_negatives_clamp_out_of_range_types(graph):
@@ -422,10 +532,10 @@ def test_device_sampling_model_parallel_mesh(graph):
     state = m.init_state(
         jax.random.PRNGKey(0), graph, graph.sample_node(8, -1), opt
     )
-    roots_len = state["consts"]["roots"]["cum"].shape[0]
+    roots_len = state["consts"]["roots"]["prob"].shape[0]
     state = pad_tables_for_mesh(state, mesh)
     # sampler arrays unpadded, feature table padded to the model axis
-    assert state["consts"]["roots"]["cum"].shape[0] == roots_len
+    assert state["consts"]["roots"]["prob"].shape[0] == roots_len
     assert state["consts"]["features"].shape[0] % 2 == 0
     shardings = state_sharding(mesh, state)
     state = jax.device_put(state, shardings)
@@ -441,8 +551,8 @@ def test_device_sampling_model_parallel_mesh(graph):
 
 def test_unsup_negs_sampler_survives_model_parallel(graph):
     """consts['negs'] (the unsupervised negative sampler) must replicate
-    unpadded under model parallelism: zero-padding would unsort its
-    cumulative weights and silently corrupt every negative draw."""
+    unpadded under model parallelism: a padded alias table would draw
+    its padding slots, whose prob is 0 and whose alias is slot 0."""
     import jax
 
     from euler_tpu import train as train_lib
@@ -462,11 +572,12 @@ def test_unsup_negs_sampler_survives_model_parallel(graph):
     state = m.init_state(
         jax.random.PRNGKey(0), graph, graph.sample_node(8, -1), opt
     )
-    negs_len = state["consts"]["negs"]["cum"].shape[0]
+    before = {k: np.asarray(v) for k, v in state["consts"]["negs"].items()}
     state = pad_tables_for_mesh(state, mesh)
-    assert state["consts"]["negs"]["cum"].shape[0] == negs_len
-    cum = np.asarray(state["consts"]["negs"]["cum"])
-    assert (np.diff(cum) >= 0).all(), "cum must stay sorted"
+    for k in ("ids", "prob", "alias"):
+        np.testing.assert_array_equal(
+            np.asarray(state["consts"]["negs"][k]), before[k]
+        )
     shardings = state_sharding(mesh, state)
     state = jax.device_put(state, shardings)
     step = jax.jit(
@@ -879,7 +990,8 @@ def test_remote_graph_export_matches_local(graph, tmp_path):
             rs = device.build_node_sampler(remote, nt, MAX_ID)
             ls = device.build_node_sampler(graph, nt, MAX_ID)
             np.testing.assert_array_equal(rs["ids"], ls["ids"])
-            np.testing.assert_allclose(rs["cum"], ls["cum"], rtol=1e-6)
+            np.testing.assert_allclose(rs["prob"], ls["prob"], rtol=1e-6)
+            np.testing.assert_array_equal(rs["alias"], ls["alias"])
         rt = device.build_typed_node_sampler(remote, 2, MAX_ID)
         lt = device.build_typed_node_sampler(graph, 2, MAX_ID)
         for k in ("ids", "off", "types"):

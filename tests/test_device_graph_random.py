@@ -314,16 +314,15 @@ def test_packed_layout_matches_slabs(adj):
     assert (cum_lanes[:, w:] == 1.0).all()
 
 
-def test_two_level_root_sampler_distribution_at_scale(graph, monkeypatch):
-    """Random-graph analog of the fixture-level multi-segment test:
-    non-uniform node weights, SEG shrunk to 16 so the 300-node sampler
-    spans ~19 segments — the two-level draw (segment pick x in-segment
-    bisect) must reproduce every node's weight share."""
+def test_alias_root_sampler_distribution_at_scale(graph):
+    """Random-graph analog of the fixture-level alias test: 300 nodes
+    of non-uniform weight, so the alias table pairs most slots — the
+    draw must reproduce every node's weight share."""
     from euler_tpu.graph import device
 
-    monkeypatch.setattr(device, "SEG", 16)
     s = device.build_node_sampler(graph, -1, N - 1)
-    assert s["seg_cum"].shape[0] > 10
+    assert (s["prob"] < 1).sum() > 10
+    assert (s["alias"] != np.arange(len(s["ids"]))).sum() > 10
     draws = np.asarray(
         device.sample_node(s, jax.random.PRNGKey(3), 60000)
     )
