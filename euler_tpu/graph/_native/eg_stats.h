@@ -212,6 +212,15 @@ enum CounterId : int {
   kCtrEpochDrain,
   kCtrEpochStaleEvict,
   kCtrDeltaLoadFail,
+  // Full-neighbourhood expansion ledger (graph/device.py
+  // multi_hop_neighbor, counted inside the jitted step and added here by
+  // train() once a log window): the padded slots the hops worked on, the
+  // true edges among them (the mask's sum), and the unique neighbours a
+  // hop's static cap had no room for, which the step drops — any bump of
+  // the last means the model trained on less than the whole neighbourhood.
+  kCtrExpandSlots,
+  kCtrExpandEdges,
+  kCtrExpandOverflowNodes,
   kCtrCount,
 };
 
@@ -233,6 +242,7 @@ const char* const kCounterNames[kCtrCount] = {
     "async_submits",      "async_inflight_peak", "async_continuations",
     "epoch_flips",        "epoch_drains",
     "epoch_stale_hits_evicted", "delta_loads_failed",
+    "expand_slots",       "expand_edges",     "expand_overflow_nodes",
 };
 
 class Counters {

@@ -944,6 +944,18 @@ def sample_node_with_src(tsampler: dict, src, key, count: int):
     return jnp.where(empty, default, out)
 
 
+@functools.lru_cache(maxsize=64)
+def _log_expand_route(sizes: tuple) -> None:
+    """One line per distinct expansion shape, said while tracing (as the
+    draw paths say theirs): the padded slots every hop of the full-
+    neighbourhood expansion works on."""
+    log.info(
+        "expand path: full neighbourhood %s slots (sort dedup, XLA)",
+        " -> ".join(map(str, sizes)),
+    )
+
+
+@jax.named_scope("expand")
 def multi_hop_neighbor(adjs, roots, node_caps):
     """Full-neighbor multi-hop expansion with per-hop dedup, inside jit
     (device analog of ops.get_multi_hop_neighbor; deterministic — no
@@ -963,9 +975,13 @@ def multi_hop_neighbor(adjs, roots, node_caps):
     heaviest neighbors at build_adjacency time, and a hop with more than
     node_caps[h] unique neighbors drops the largest-id overflow nodes
     (their edges are masked out) instead of raising — caps must be sized
-    generously, exactly like the host's max_nodes_per_hop.
+    generously, exactly like the host's max_nodes_per_hop. The drop is
+    counted, not silent: each hop carries "overflow", the number of its
+    unique neighbors that found no room under the cap (0 where the cap
+    holds), and "edges", the mask's sum (the true edges that entered).
     """
     cur = jnp.asarray(roots, dtype=jnp.int32).reshape(-1)
+    sizes = [cur.shape[0]]
     hops = []
     for adj, cap in zip(adjs, node_caps):
         default = adj["nbr"].shape[0] - 1
@@ -997,6 +1013,9 @@ def multi_hop_neighbor(adjs, roots, node_caps):
             & (rank < cap)
             & (flat != default)
         ).astype(jnp.float32)
+        # unique real ids of this hop (padding entries all hold the
+        # default id) against the room the cap gives them
+        unique = jnp.sum(first & (s != default), dtype=jnp.int32)
         hops.append(
             {
                 "nodes": nodes,
@@ -1004,9 +1023,13 @@ def multi_hop_neighbor(adjs, roots, node_caps):
                 "dst": dst,
                 "mask": mask,
                 "w": mask,
+                "edges": jnp.sum(mask),
+                "overflow": jnp.maximum(unique - cap, 0),
             }
         )
+        sizes.append(C * W)
         cur = nodes
+    _log_expand_route(tuple(sizes))
     return hops
 
 

@@ -36,6 +36,10 @@ class ModelOutput:
     loss: Any
     metric_name: str
     metric: Any  # scalar (mrr/acc) or f1 counts [tp, fp, fn]
+    # per-step event counts of the model's ``step_counters``, in that
+    # order ([k] float32), or None: they leave the step beside the metric
+    # and train() adds them into the telemetry ledger once a log window
+    counters: Any = None
 
 
 @jax.named_scope("loss")
@@ -247,6 +251,9 @@ class Model:
     # float32. 'bfloat16' halves the table's HBM footprint and gather
     # bytes; rows are cast back to float32 at the gather.
     feature_dtype: Optional[str] = None
+    # names of the native counters (eg_stats.h) a step of this model
+    # counts into, in the order of ModelOutput.counters; () = none
+    step_counters: Sequence[str] = ()
 
     def __init__(self):
         self.module: nn.Module = None
@@ -633,6 +640,8 @@ class Model:
             new_state = {"params": params, "opt_state": opt_state}
             if "consts" in state:
                 new_state["consts"] = consts
+            if out.counters is not None:
+                return new_state, loss, (out.metric, out.counters)
             return new_state, loss, out.metric
 
         return train_step

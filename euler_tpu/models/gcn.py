@@ -18,6 +18,7 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -80,6 +81,19 @@ class _SupervisedGCNModule(nn.Module):
             feats = [{"gids": i} for i in node_sets]
         return feats, hops
 
+    @staticmethod
+    def _expand_counters(adjs):
+        """[slots, true edges, unique nodes past a cap] of a device
+        expansion (SupervisedGCN.step_counters); None for host-built
+        adjacencies, whose expansion raises where a cap does not hold."""
+        if not all("overflow" in a for a in adjs):
+            return None
+        return jnp.stack([
+            jnp.float32(sum(a["mask"].shape[0] for a in adjs)),
+            sum(a["edges"] for a in adjs),
+            sum(a["overflow"] for a in adjs).astype(jnp.float32),
+        ])
+
     def _forward(self, batch, consts):
         hops, adjs = self._hops_adjs(batch, consts)
         hidden = [
@@ -88,14 +102,15 @@ class _SupervisedGCNModule(nn.Module):
             )
             for f in hops
         ]
-        return self.encoder(hidden, adjs), hops
+        return self.encoder(hidden, adjs), hops, self._expand_counters(adjs)
 
     def embed(self, batch, consts=None):
         return self._forward(batch, consts)[0]
 
     def __call__(self, batch, consts=None):
-        embedding, hops = self._forward(batch, consts)
-        logits = self.predict(embedding)
+        embedding, hops, counters = self._forward(batch, consts)
+        with jax.named_scope("dense"):
+            logits = self.predict(embedding)
         labels = base.lookup_labels(batch, consts, hops[0].get("gids"))
         loss, predictions = base.supervised_decoder(
             logits, labels, self.sigmoid_loss
@@ -105,6 +120,7 @@ class _SupervisedGCNModule(nn.Module):
             loss=loss,
             metric_name="f1",
             metric=metrics.f1_counts(labels, predictions),
+            counters=counters,
         )
 
 
@@ -148,6 +164,11 @@ class SupervisedGCN(base.Model):
             device_features, feature_idx, max_id
         )
         self.init_device_sampling(device_sampling)
+        if self.device_sampling:
+            # a hop past its static cap drops nodes: counted in the step
+            self.step_counters = (
+                "expand_slots", "expand_edges", "expand_overflow_nodes"
+            )
         self.label_idx = label_idx
         self.label_dim = label_dim
         self.metapath = [list(m) for m in metapath]

@@ -6,6 +6,10 @@ from ops.get_multi_hop_neighbor (adj_src/adj_dst index the current/next hop
 node arrays) and aggregation is jax.ops.segment_sum with static segment
 counts — the XLA-native form of sparse x dense. Padding edges carry
 edge_mask 0 and contribute nothing.
+
+The work over the edge list (the gather by ``dst``, the mask, the degree,
+the segment sum, the division) carries the ``segment_agg`` named scope
+(trace.STEP_SCOPES); the matmuls stay under ``dense`` (nn/layers.py).
 """
 
 from __future__ import annotations
@@ -40,13 +44,14 @@ class GCNAggregator(nn.Module):
         self_emb, neigh_emb, adj = inputs
         src, dst, edge_mask = adj["src"], adj["dst"], adj["mask"]
         n = self_emb.shape[0]
-        deg = _degree(src, edge_mask, n)[:, None]
-        msgs = neigh_emb[dst] * edge_mask[:, None]
-        agg = _gather_sum(msgs, src, n)
-        if self.renorm:
-            agg = (self_emb + agg) / (1.0 + deg)
-        else:
-            agg = self_emb + agg / jnp.maximum(deg, 1e-7)
+        with jax.named_scope("segment_agg"):
+            deg = _degree(src, edge_mask, n)[:, None]
+            msgs = neigh_emb[dst] * edge_mask[:, None]
+            agg = _gather_sum(msgs, src, n)
+            if self.renorm:
+                agg = (self_emb + agg) / (1.0 + deg)
+            else:
+                agg = self_emb + agg / jnp.maximum(deg, 1e-7)
         return Dense(self.dim, self.activation, use_bias=False)(agg)
 
 
@@ -61,9 +66,10 @@ class MeanAggregator(nn.Module):
         src, dst, edge_mask = adj["src"], adj["dst"], adj["mask"]
         n = self_emb.shape[0]
         dim = self.dim // 2 if self.concat else self.dim
-        deg = _degree(src, edge_mask, n)[:, None]
-        msgs = neigh_emb[dst] * edge_mask[:, None]
-        agg = _gather_sum(msgs, src, n) / jnp.maximum(deg, 1e-7)
+        with jax.named_scope("segment_agg"):
+            deg = _degree(src, edge_mask, n)[:, None]
+            msgs = neigh_emb[dst] * edge_mask[:, None]
+            agg = _gather_sum(msgs, src, n) / jnp.maximum(deg, 1e-7)
         from_self = Dense(dim, self.activation, use_bias=False)(self_emb)
         from_neigh = Dense(dim, self.activation, use_bias=False)(agg)
         if self.concat:
@@ -71,6 +77,7 @@ class MeanAggregator(nn.Module):
         return from_self + from_neigh
 
 
+@jax.named_scope("segment_agg")
 def segment_softmax(logits, segments, num_segments, mask):
     """Numerically-stable softmax of edge logits within each src segment.
     Masked edges get zero probability."""

@@ -550,6 +550,23 @@ _RESOURCE_FAMILIES = {
 }
 
 
+# Counters of the native ledger that are also families of their own
+# (beside their eg_counter_total{name=...} series): a rate or a ratio of
+# these is what a dashboard plots.
+_COUNTER_FAMILIES = {
+    "expand_slots": ("eg_expand_slots",
+                     "Padded slots the full-neighbourhood expansion "
+                     "(graph/device.py multi_hop_neighbor) worked on"),
+    "expand_edges": ("eg_expand_edges",
+                     "True edges among eg_expand_slots (the mask's sum): "
+                     "their ratio is the expansion's slot fill"),
+    "expand_overflow_nodes": ("eg_expand_overflow_nodes",
+                              "Unique neighbours a hop's static cap had "
+                              "no room for, dropped with their edges; 0 "
+                              "where the whole neighbourhood is kept"),
+}
+
+
 def _fmt_labels(labels: dict) -> str:
     if not labels:
         return ""
@@ -596,6 +613,15 @@ def _render(sources: list) -> str:
             labels = dict(base)
             labels["name"] = name
             lines.append(f"eg_counter_total{_fmt_labels(labels)} {v}")
+
+    for ckey, (fam, help_text) in _COUNTER_FAMILIES.items():
+        lines.append(f"# HELP {fam} {help_text}")
+        lines.append(f"# TYPE {fam} counter")
+        for data, base in sources:
+            lines.append(
+                f"{fam}{_fmt_labels(dict(base))} "
+                f"{data['counters'].get(ckey, 0)}"
+            )
 
     lines.append("# HELP eg_stat_calls_total Span-timer call counts "
                  "per engine op")

@@ -68,10 +68,15 @@ ALIGN_PREFIX = "eg_align:"
 # models' (models/shallow.py): the chained single-neighbour draws of the
 # device walks with the pair indexing, the negatives' draw from the node
 # sampler, and the gathers from the id-embedding tables with, transposed,
-# the scatter-adds of their gradients.
+# the scatter-adds of their gradients. ``expand`` and ``segment_agg``
+# are the full-neighbourhood family's (models/gcn.py): all of
+# graph/device.py multi_hop_neighbor (slab-row gathers, the sort, the
+# rank, the two scatters), and the sparse aggregators' work over the
+# padded edge list (nn/sparse_aggregators.py: the gather by ``dst``, the
+# mask, the degree, the segment sum, the division).
 STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
                "dense", "loss", "optimizer", "stores_read", "stores_write",
-               "walk", "negatives", "pair_rows")
+               "walk", "negatives", "pair_rows", "expand", "segment_agg")
 
 # File ``train(profile_dir=)`` leaves the compiled step's HLO text in,
 # beside the capture: the map from a trace event's instruction name to

@@ -366,6 +366,12 @@ def train(
     # host every step would sync the pipeline and stall the prefetch overlap
     # (JAX dispatch is async; only materialize at the log boundary).
     window_metrics = []
+    # a model that counts events inside its step (Model.step_counters)
+    # returns (metric, counts) for its metric: the counts ride the
+    # window's one pull and are added into the native ledger there
+    counter_names = tuple(getattr(model, "step_counters", ()))
+    if counter_names:
+        from euler_tpu.graph.native import counter_add
     last_loss = None
     steps_done = start_step
 
@@ -376,6 +382,11 @@ def train(
         # accumulated on the host.
         devprof.count_d2h((window_metrics, last_loss))
         metrics, loss = jax.device_get((window_metrics, last_loss))
+        if counter_names:
+            metrics, counts = zip(*metrics)
+            for cname, total in zip(
+                    counter_names, np.sum(counts, axis=0, dtype=np.float64)):
+                counter_add(cname, int(total))
         acc = _metric_zero(name)
         for m in metrics:
             acc = _metric_accumulate(name, acc, m)
@@ -498,7 +509,11 @@ def train(
                 mark = t_host = leaf("dispatch", mark, cur, leaves)
             # the window's values start for the host as they are produced,
             # so the flush finds all but the last step's there already
-            metric.copy_to_host_async()
+            if counter_names:  # (metric, the step's counts)
+                metric[1].copy_to_host_async()
+                metric[0].copy_to_host_async()
+            else:
+                metric.copy_to_host_async()
             window_metrics.append(metric)
             if len(window_metrics) == log_every:
                 last_loss.copy_to_host_async()

@@ -59,6 +59,9 @@ COUNTER_NAMES = {
     # stale cache generations evicted on touch, and refused delta loads
     "epoch_flips", "epoch_drains", "epoch_stale_hits_evicted",
     "delta_loads_failed",
+    # full-neighbourhood expansion ledger (PR 37): padded slots, true
+    # edges and unique neighbours past a hop's cap, counted in the step
+    "expand_slots", "expand_edges", "expand_overflow_nodes",
 }
 FAULT_NAMES = {
     "dial", "send_frame", "recv_frame", "service_reply", "registry_reply",

@@ -25,7 +25,10 @@ MAX_ID = 16  # fixture ids go up to 16
 STORE_SCOPES = {"stores_read", "stores_write"}
 # the scopes of the shallow embedding models' step alone (models/shallow.py)
 WALK_SCOPES = {"walk", "negatives", "pair_rows"}
-FAMILY_SCOPES = STORE_SCOPES | WALK_SCOPES
+# the scopes of the full-neighbourhood family's step alone (models/gcn.py:
+# graph/device.py multi_hop_neighbor and nn/sparse_aggregators.py)
+EXPAND_SCOPES = {"expand", "segment_agg"}
+FAMILY_SCOPES = STORE_SCOPES | WALK_SCOPES | EXPAND_SCOPES
 TRAIN_THREAD_LEAVES = {"input_stall", "input_other", "h2d", *T.PHASE_PARENT}
 
 
@@ -104,7 +107,7 @@ def test_lowered_store_step_holds_every_step_scope(graph):
     state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
     text = jax.jit(m.make_train_step(opt)).lower(
         state, m.sample(graph, roots)).as_text(debug_info=True)
-    for scope in set(TR.STEP_SCOPES) - WALK_SCOPES:
+    for scope in set(TR.STEP_SCOPES) - WALK_SCOPES - EXPAND_SCOPES:
         assert f"/{scope}/" in text, scope
     lines = text.splitlines()
     gathers = [ln for ln in lines if "stores_read" in ln and "gather" in ln]
@@ -112,6 +115,46 @@ def test_lowered_store_step_holds_every_step_scope(graph):
     assert gathers and adds
     assert any("/optimizer/" in ln for ln in lines)
     assert not any("/stores_" in ln and "/optimizer/" in ln for ln in lines)
+
+
+def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
+    """The full-neighbourhood family's step: all of the device expansion
+    (slab-row gathers, the sort, the scatters) under ``expand``, the
+    sparse aggregator's work over the edge list (the gather by ``dst``,
+    the segment sums, forward and transposed) under ``segment_agg``, its
+    matmuls under ``dense``, the rows under ``gather_features``."""
+    from euler_tpu.models import SupervisedGCN
+
+    m = SupervisedGCN(
+        label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]], dim=16,
+        max_nodes_per_hop=[32, 64], max_edges_per_hop=[64, 256],
+        aggregator="mean", feature_idx=0, feature_dim=2, max_id=MAX_ID,
+        device_features=True, device_sampling=True,
+    )
+    assert m.step_counters == (
+        "expand_slots", "expand_edges", "expand_overflow_nodes")
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    step = jax.jit(m.make_train_step(opt))
+    text = step.lower(state, m.sample(graph, roots)).as_text(debug_info=True)
+    here = EXPAND_SCOPES | {"gather_features", "gather_labels", "dense",
+                            "loss", "optimizer"}
+    for scope in TR.STEP_SCOPES:
+        assert (f"/{scope}/" in text) == (scope in here), scope
+    lines = text.splitlines()
+    assert any("/expand/" in ln and "sort" in ln for ln in lines)
+    assert any("/expand/" in ln and "scatter" in ln for ln in lines)
+    assert any("/segment_agg/" in ln and "scatter" in ln for ln in lines)
+    assert not any("/segment_agg/" in ln and "dot_general" in ln
+                   for ln in lines)
+    assert not any("/expand/" in ln and "/segment_agg/" in ln for ln in lines)
+    # the step's counts leave beside the metric: slots, true edges, and
+    # the unique neighbours past a cap (none: the caps hold)
+    _, _, (metric, counts) = step(state, m.sample(graph, roots))
+    slots, edges, overflow = np.asarray(counts)
+    assert metric.shape == (3,) and overflow == 0
+    assert 0 < edges <= slots
 
 
 @pytest.mark.parametrize("walk_len", [5, 0], ids=["node2vec", "line"])
