@@ -15,6 +15,8 @@ TPU adaptations:
 
 from __future__ import annotations
 
+import functools
+import logging
 from typing import Optional, Sequence
 
 import flax.linen as nn
@@ -24,8 +26,18 @@ import numpy as np
 
 from euler_tpu import ops
 from euler_tpu.models import base
-from euler_tpu.nn import metrics
+from euler_tpu.nn import metrics, sparse_aggregators
 from euler_tpu.nn.encoders import GCNEncoder, ShallowEncoder
+
+log = logging.getLogger("euler_tpu")
+
+
+@functools.lru_cache(maxsize=64)
+def _log_message_route(hop: int, slots: int, route: str) -> None:
+    """One line per distinct shape and outcome, said while tracing (as
+    graph/device.py says its draw and expand paths): where layer 0's
+    messages of a hop's edge list are gathered from."""
+    log.info("message path: hop %d %d slots -> %s", hop, slots, route)
 
 
 class _SupervisedGCNModule(nn.Module):
@@ -94,15 +106,69 @@ class _SupervisedGCNModule(nn.Module):
             sum(a["overflow"] for a in adjs).astype(jnp.float32),
         ])
 
+    def _hop_rows_why(self, batch, consts):
+        """Why layer 0's messages have to be read out of the hops' own
+        rows, or None where a hop's rows are nothing but rows of the
+        device-resident feature table (a device expansion's ``gids``, a
+        node encoder that is the identity on the dense rows) and the
+        aggregator takes them a slot: then ``_forward`` gathers the
+        messages from the stored table in one pass."""
+        if "hops" in batch:
+            return "host-expanded batch"
+        if not consts or "features" not in consts:
+            return "no device-resident feature table"
+        if self.max_id >= 0:
+            return "use_id: an id embedding beside the rows"
+        if self.sparse_feature_max_ids:
+            return "sparse features beside the rows"
+        if self.use_residual:
+            return "use_residual: the rows are projected"
+        if not sparse_aggregators.get(self.aggregator).reads_slot_rows:
+            return (f"{self.aggregator} aggregator: it projects the "
+                    "hop's rows before the gather")
+        return None
+
     def _forward(self, batch, consts):
         hops, adjs = self._hops_adjs(batch, consts)
+        why = self._hop_rows_why(batch, consts)
+        one_pass = why is None
+        if one_pass:
+            lanes = consts["features"].shape[-1]
+            route = f"one pass from the stored table ({lanes} lanes)"
+        else:
+            route = f"from the hop's rows ({why})"
+        for h, adj in enumerate(adjs):
+            _log_message_route(h + 1, adj["mask"].shape[0], route)
+        # the outermost hop's set has one reader, layer 0's messages:
+        # where those come from the table its rows are never gathered
         hidden = [
             self.node_encoder(
                 base.gather_consts(f, consts, self.feature_dim)
             )
-            for f in hops
+            for f in (hops[:-1] if one_pass else hops)
         ]
-        return self.encoder(hidden, adjs), hops, self._expand_counters(adjs)
+        first_neigh = None
+        if one_pass:
+            hidden.append(None)
+            first_neigh = [
+                self._slot_rows(hop["gids"], adj, consts["features"])
+                for hop, adj in zip(hops[1:], adjs)
+            ]
+        embedding = self.encoder(hidden, adjs, first_neigh)
+        return embedding, hops, self._expand_counters(adjs)
+
+    def _slot_rows(self, nodes, adj, table):
+        """Layer 0's messages of one hop's edge list, before the mask:
+        the stored table's row of every slot's own node, ``nodes[dst]``,
+        in one pass, the pad lanes cut after that gather (never between
+        two gathers: a 50-wide intermediate is laid column-major and a
+        row gather out of it reads a row across 50 separated columns,
+        PERF.md section 6, PR 38). ``table[nodes][..., :F][dst]`` to the
+        bit."""
+        ids = sparse_aggregators.slot_ids(nodes, adj)
+        with jax.named_scope("gather_features"):
+            rows = base.gather_rows(table, ids, self.feature_dim)
+        return sparse_aggregators.SlotRows(rows)
 
     def embed(self, batch, consts=None):
         return self._forward(batch, consts)[0]
@@ -254,8 +320,6 @@ class _ScalableGCNModule(nn.Module):
             embedding_dim=self.embedding_dim,
             combiner="add" if self.use_residual else "concat",
         )
-        from euler_tpu.nn import sparse_aggregators
-
         agg_cls = sparse_aggregators.get(self.aggregator)
         self.aggs = [
             agg_cls(

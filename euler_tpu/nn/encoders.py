@@ -166,7 +166,15 @@ class SparseSageEncoder(nn.Module):
 
 class GCNEncoder(nn.Module):
     """Full-neighbor multi-hop GCN over padded COO adjacency
-    (reference encoders.py:165-215)."""
+    (reference encoders.py:165-215).
+
+    ``first_neigh``, where given, is layer 0's neighbour input a hop in
+    place of ``hidden[hop + 1]``: ``sparse_aggregators.SlotRows``, the
+    messages' rows gathered one a slot from the stored feature table
+    under ``gather_features`` (models/gcn.py ``_forward``). The last
+    entry of ``hidden``, which only layer 0 would read as a neighbour
+    input, may then be None. Later layers read the layer before through
+    ``dst`` under ``segment_agg``."""
 
     num_layers: int
     dim: int
@@ -174,7 +182,7 @@ class GCNEncoder(nn.Module):
     use_residual: bool = False
 
     @nn.compact
-    def __call__(self, hidden: list, adjs: list):
+    def __call__(self, hidden: list, adjs: list, first_neigh=None):
         assert len(hidden) == self.num_layers + 1
         assert len(adjs) == self.num_layers
         agg_cls = sparse_aggs.get(self.aggregator)
@@ -188,7 +196,10 @@ class GCNEncoder(nn.Module):
         for layer in range(self.num_layers):
             next_hidden = []
             for hop in range(self.num_layers - layer):
-                h = aggs[layer]((hidden[hop], hidden[hop + 1], adjs[hop]))
+                neigh = hidden[hop + 1]
+                if layer == 0 and first_neigh is not None:
+                    neigh = first_neigh[hop]
+                h = aggs[layer]((hidden[hop], neigh, adjs[hop]))
                 if self.use_residual:
                     h = hidden[hop] + h
                 next_hidden.append(h)

@@ -10,11 +10,23 @@ edge_mask 0 and contribute nothing.
 The work over the edge list (the gather by ``dst``, the mask, the degree,
 the segment sum, the division) carries the ``segment_agg`` named scope
 (trace.STEP_SCOPES); the matmuls stay under ``dense`` (nn/layers.py).
+
+An aggregator's neighbour input is the hop's rows ``[m, F]``, which it
+reads through ``dst``, or ``SlotRows``: rows that already lie one a slot
+of the edge list. ``GCNAggregator`` and ``MeanAggregator`` take either
+(``reads_slot_rows``). Layer 0 of the device-expanded full-neighbourhood
+step hands them ``SlotRows`` where a hop's rows are rows of the
+device-resident feature table (models/gcn.py ``_forward``): the slots'
+node ids ``nodes[dst]`` are composed here (``slot_ids``, under
+``segment_agg``), the rows are gathered from the stored table by those
+ids in one pass under ``gather_features``, and the hop's set rows are
+not gathered for the messages' sake at all. Attention projects the hop's
+rows before its gather by ``dst`` and keeps the hop's rows.
 """
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
@@ -31,6 +43,36 @@ def _gather_sum(values, adj_src, num_nodes):
     return jax.ops.segment_sum(values, adj_src, num_segments=num_nodes)
 
 
+class SlotRows(NamedTuple):
+    """Neighbour rows handed to an aggregator one a SLOT of the edge list
+    ([slots, F], in the adjacency's own order) in place of one a node of
+    the hop's set: the aggregator reads them as they lie, with no gather
+    by ``dst``. Where a hop's rows are nothing but rows of a stored table
+    the caller gathers them by ``slot_ids`` in one pass."""
+
+    rows: jax.Array
+
+
+def slot_ids(nodes, adj):
+    """The id of every slot's neighbour, ``nodes[dst]``: two chained row
+    gathers (a table by ``nodes``, the result by ``dst``) are one gather
+    by these ids. A masked slot names whatever node its clipped ``dst``
+    points at; its row is multiplied by the mask's nought as before."""
+    with jax.named_scope("segment_agg"):
+        return nodes[adj["dst"]]
+
+
+def _messages(neigh_emb, adj):
+    """[slots, F] masked messages of an edge list (inside
+    ``segment_agg``): the hop's rows read through ``dst``, or rows that
+    already lie one a slot (``SlotRows``)."""
+    if isinstance(neigh_emb, SlotRows):
+        rows = neigh_emb.rows
+    else:
+        rows = neigh_emb[adj["dst"]]
+    return rows * adj["mask"][:, None]
+
+
 class GCNAggregator(nn.Module):
     """(self + sum(neigh)/deg) @ W, or renorm (self + sum)/(1+deg) @ W
     (reference sparse_aggregators.py:37-55 uses binary adjacency)."""
@@ -38,16 +80,16 @@ class GCNAggregator(nn.Module):
     dim: int
     activation: Optional[Callable] = nn.relu
     renorm: bool = False
+    reads_slot_rows = True
 
     @nn.compact
     def __call__(self, inputs):
         self_emb, neigh_emb, adj = inputs
-        src, dst, edge_mask = adj["src"], adj["dst"], adj["mask"]
+        src, edge_mask = adj["src"], adj["mask"]
         n = self_emb.shape[0]
         with jax.named_scope("segment_agg"):
             deg = _degree(src, edge_mask, n)[:, None]
-            msgs = neigh_emb[dst] * edge_mask[:, None]
-            agg = _gather_sum(msgs, src, n)
+            agg = _gather_sum(_messages(neigh_emb, adj), src, n)
             if self.renorm:
                 agg = (self_emb + agg) / (1.0 + deg)
             else:
@@ -59,17 +101,18 @@ class MeanAggregator(nn.Module):
     dim: int
     activation: Optional[Callable] = nn.relu
     concat: bool = False
+    reads_slot_rows = True
 
     @nn.compact
     def __call__(self, inputs):
         self_emb, neigh_emb, adj = inputs
-        src, dst, edge_mask = adj["src"], adj["dst"], adj["mask"]
+        src, edge_mask = adj["src"], adj["mask"]
         n = self_emb.shape[0]
         dim = self.dim // 2 if self.concat else self.dim
         with jax.named_scope("segment_agg"):
             deg = _degree(src, edge_mask, n)[:, None]
-            msgs = neigh_emb[dst] * edge_mask[:, None]
-            agg = _gather_sum(msgs, src, n) / jnp.maximum(deg, 1e-7)
+            agg = _gather_sum(_messages(neigh_emb, adj), src, n)
+            agg = agg / jnp.maximum(deg, 1e-7)
         from_self = Dense(dim, self.activation, use_bias=False)(self_emb)
         from_neigh = Dense(dim, self.activation, use_bias=False)(agg)
         if self.concat:
@@ -142,6 +185,8 @@ class AttentionAggregator(nn.Module):
     num_heads: int = 4
     activation: Optional[Callable] = nn.relu
     renorm: bool = False
+    # a head projects the hop's rows BEFORE its gather by ``dst``
+    reads_slot_rows = False
 
     @nn.compact
     def __call__(self, inputs):

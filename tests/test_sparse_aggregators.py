@@ -86,3 +86,212 @@ def test_gcn_encoder_full_pipeline(graph, aggregator):
     out = jax.jit(enc.apply)(params, feats, adjs)
     assert out.shape == (2, 8)
     assert np.isfinite(np.asarray(out)).all()
+
+
+# ---------------------------------------------------------------------------
+# Layer 0's messages: one pass from the stored table, or the hop's rows
+# (models/gcn.py _SupervisedGCNModule._forward, OBSERVABILITY.md "message
+# path")
+# ---------------------------------------------------------------------------
+
+MAX_ID = 16  # fixture ids go up to 16
+
+
+def _gcn(aggregator="mean", **kw):
+    from euler_tpu.models import SupervisedGCN
+
+    kw.setdefault("device_features", True)
+    kw.setdefault("device_sampling", kw["device_features"])
+    return SupervisedGCN(
+        label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]], dim=16,
+        max_nodes_per_hop=[24, 40], max_edges_per_hop=[64, 256],
+        aggregator=aggregator, feature_idx=0, feature_dim=2, max_id=MAX_ID,
+        **kw,
+    )
+
+
+def _state_batch(m, graph):
+    from euler_tpu import train as train_lib
+
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    return opt, state, jax.tree.map(jnp.asarray, m.sample(graph, roots))
+
+
+def _plain_loss(m, params, batch, consts):
+    """The step's loss with every hop's rows gathered by the hop's own
+    set, cut to ``feature_dim`` and then read through ``dst``: the plain
+    form ``table[nodes][..., :F][dst]``, written out here with the
+    encoder handed nothing but ``(hidden, adjs)``."""
+    import flax.linen as nn
+
+    from euler_tpu.models import base
+    from euler_tpu.nn.encoders import ShallowEncoder
+
+    mod = m.module
+    hops, adjs = mod.apply(
+        {"params": params}, batch, consts, method=mod._hops_adjs)
+    node_encoder = ShallowEncoder(
+        dim=mod.dim if mod.use_residual else None,
+        feature_dim=mod.feature_dim, max_id=mod.max_id,
+        embedding_dim=mod.embedding_dim,
+        combiner="add" if mod.use_residual else "concat",
+    )
+    hidden = []
+    for f in hops:
+        f = dict(f)
+        if "dense" not in f:
+            f["dense"] = consts["features"][f["gids"]][
+                ..., :mod.feature_dim].astype(jnp.float32)
+        hidden.append(node_encoder.apply(
+            {"params": params.get("node_encoder", {})}, f))
+    emb = GCNEncoder(
+        num_layers=mod.num_layers, dim=mod.dim, aggregator=mod.aggregator,
+        use_residual=mod.use_residual,
+    ).apply({"params": params["encoder"]}, hidden, adjs)
+    logits = nn.Dense(mod.num_classes).apply(
+        {"params": params["predict"]}, emb)
+    labels = base.lookup_labels(batch, consts, hops[0].get("gids"))
+    return base.supervised_decoder(logits, labels, mod.sigmoid_loss)[0]
+
+
+def _assert_same_to_the_bit(m, state, batch, every_leaf_moves=True):
+    args = state["params"], batch, state.get("consts")
+    got = jax.jit(jax.value_and_grad(
+        lambda p, b, c: m._apply(p, b, c).loss))(*args)
+    want = jax.jit(jax.value_and_grad(
+        lambda p, b, c: _plain_loss(m, p, b, c)))(*args)
+    assert np.asarray(got[0]) == np.asarray(want[0])
+    leaves = jax.tree_util.tree_leaves_with_path(got[1])
+    assert len(leaves) >= 4
+    for (path, g), w in zip(leaves, jax.tree.leaves(want[1])):
+        assert not every_leaf_moves or np.abs(np.asarray(g)).sum() > 0, path
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w), str(path))
+
+
+@pytest.mark.parametrize("aggregator", ["mean", "gcn"])
+def test_one_pass_messages_equal_the_plain_form_to_the_bit(
+        graph, aggregator):
+    """Device expansion, device features, a node encoder that is the
+    identity: the messages of both hops come from the stored table in one
+    pass by ``nodes[dst]``, and the loss and every parameter's gradient
+    are those of ``table[nodes][..., :F][dst]``, bit for bit."""
+    m = _gcn(aggregator)
+    _, state, batch = _state_batch(m, graph)
+    assert m.module._hop_rows_why(batch, state["consts"]) is None
+    _assert_same_to_the_bit(m, state, batch)
+
+
+@pytest.mark.parametrize("kw, why", [
+    (dict(device_features=False), "host-expanded batch"),
+    (dict(device_sampling=False), "host-expanded batch"),
+    (dict(use_id=True), "use_id"),
+    (dict(use_residual=True), "use_residual"),
+    (dict(aggregator="attention"), "attention aggregator"),
+], ids=["host_rows", "host_expanded", "use_id", "use_residual", "attention"])
+def test_other_configurations_keep_the_hops_own_rows(graph, kw, why):
+    """What must keep today's path does: it says why, its loss and
+    gradients are the plain form's to the bit, and a step trains."""
+    m = _gcn(**kw)
+    opt, state, batch = _state_batch(m, graph)
+    assert why in m.module._hop_rows_why(batch, state.get("consts"))
+    # a head's gate on an all-alike softmax has a gradient of nought
+    attention = kw.get("aggregator") == "attention"
+    _assert_same_to_the_bit(m, state, batch, every_leaf_moves=not attention)
+    new, loss, _ = jax.jit(m.make_train_step(opt))(state, batch)
+    assert np.isfinite(float(loss))
+    moved = jax.tree.map(
+        lambda a, b: bool(np.any(np.asarray(a) != np.asarray(b))),
+        state["params"], new["params"])
+    assert any(jax.tree.leaves(moved))
+    assert attention or all(jax.tree.leaves(moved))
+
+
+def test_scalable_gcn_trains_a_step_on_its_own_path(graph, caplog):
+    """ScalableGCN's module never sees a hop's set: its ``dst`` is
+    ``arange`` and its neighbour rows are gathered by the slot's id
+    already, so it takes no route and says none."""
+    import logging
+
+    from euler_tpu import train as train_lib
+    from euler_tpu.models import ScalableGCN
+
+    m = ScalableGCN(
+        label_idx=2, label_dim=3, edge_type=[0, 1], num_layers=2, dim=16,
+        max_id=MAX_ID, max_neighbors=4, aggregator="mean", feature_idx=0,
+        feature_dim=2, device_features=True, device_sampling=True,
+    )
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        out = jax.jit(m.make_train_step(opt))(state, m.sample(graph, roots))
+    new, loss = out[0], out[1]
+    assert np.isfinite(float(loss))
+    assert any(
+        np.any(np.asarray(a) != np.asarray(b)) for a, b in zip(
+            jax.tree.leaves(state["params"]), jax.tree.leaves(new["params"])))
+    assert not [r for r in caplog.records if "message path" in r.getMessage()]
+
+
+@pytest.mark.parametrize("slot_rows", [False, True], ids=["rows", "slots"])
+@pytest.mark.parametrize("aggregator", ["mean", "gcn"])
+def test_aggregator_reads_rows_by_dst_or_as_they_lie(aggregator, slot_rows):
+    """``(self, neigh, adj)`` with the hop's rows, or with ``SlotRows``
+    that already lie one a slot: the same masked mean, to the bit."""
+    rng = np.random.default_rng(3)
+    self_emb = jnp.asarray(rng.normal(size=(2, 4)), jnp.float32)
+    neigh_emb = jnp.asarray(rng.normal(size=(3, 4)), jnp.float32)
+    adj = _toy_adj()
+    agg = sparse_aggregators.get(aggregator)(dim=4, activation=None)
+    params = agg.init(jax.random.PRNGKey(0), (self_emb, neigh_emb, adj))
+    neigh = neigh_emb
+    if slot_rows:
+        neigh = sparse_aggregators.SlotRows(neigh_emb[adj["dst"]])
+    out = agg.apply(params, (self_emb, neigh, adj))
+    msgs = neigh_emb[adj["dst"]] * adj["mask"][:, None]
+    deg = jax.ops.segment_sum(adj["mask"], adj["src"], num_segments=2)
+    mean = jax.ops.segment_sum(msgs, adj["src"], num_segments=2) / (
+        jnp.maximum(deg, 1e-7)[:, None])
+    kernels = jax.tree.leaves(params)
+    if aggregator == "gcn":
+        want = (self_emb + mean) @ kernels[0]
+    else:
+        want = self_emb @ kernels[0] + mean @ kernels[1]
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+
+
+def test_message_path_is_said_once_a_shape_in_both_forms(graph, caplog):
+    import logging
+
+    from euler_tpu.models import gcn as gcn_models
+
+    def said(m):
+        _, state, batch = _state_batch(m, graph)
+        gcn_models._log_message_route.cache_clear()
+        caplog.clear()
+        with caplog.at_level(logging.INFO, logger="euler_tpu"):
+            step = jax.jit(lambda s, b: m._apply(
+                s["params"], b, s.get("consts")).loss)
+            step(state, batch)
+            step(state, batch)
+            # a second trace of the same shapes says nothing new
+            jax.jit(lambda s, b: m._apply(
+                s["params"], b, s.get("consts")).embedding)(state, batch)
+        return [r.getMessage() for r in caplog.records
+                if r.getMessage().startswith("message path:")]
+
+    m = _gcn("mean")
+    W = m.build_consts(graph)["adj"][m.adj_key([0, 1])]["nbr"].shape[1]
+    assert said(m) == [
+        f"message path: hop 1 {8 * W} slots -> one pass from the stored "
+        "table (128 lanes)",
+        f"message path: hop 2 {24 * W} slots -> one pass from the stored "
+        "table (128 lanes)",
+    ]
+    assert said(_gcn("mean", use_residual=True)) == [
+        f"message path: hop {h} {n * W} slots -> from the hop's rows "
+        "(use_residual: the rows are projected)"
+        for h, n in ((1, 8), (2, 24))
+    ]

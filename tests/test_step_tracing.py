@@ -117,20 +117,27 @@ def test_lowered_store_step_holds_every_step_scope(graph):
     assert not any("/stores_" in ln and "/optimizer/" in ln for ln in lines)
 
 
-def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
-    """The full-neighbourhood family's step: all of the device expansion
-    (slab-row gathers, the sort, the scatters) under ``expand``, the
-    sparse aggregator's work over the edge list (the gather by ``dst``,
-    the segment sums, forward and transposed) under ``segment_agg``, its
-    matmuls under ``dense``, the rows under ``gather_features``."""
+def _gcn_model(**kw):
     from euler_tpu.models import SupervisedGCN
 
-    m = SupervisedGCN(
+    return SupervisedGCN(
         label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]], dim=16,
         max_nodes_per_hop=[32, 64], max_edges_per_hop=[64, 256],
         aggregator="mean", feature_idx=0, feature_dim=2, max_id=MAX_ID,
-        device_features=True, device_sampling=True,
+        device_features=True, device_sampling=True, **kw,
     )
+
+
+def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
+    """The full-neighbourhood family's step: all of the device expansion
+    (slab-row gathers, the sort, the scatters) under ``expand``, the
+    sparse aggregator's work over the edge list (the composition of the
+    slots' ids ``nodes[dst]``, the mask, the segment sums, forward and
+    transposed; layer 1's gather by ``dst``) under ``segment_agg``, its
+    matmuls under ``dense``, and under ``gather_features`` the rows: the
+    roots' and hop 1's sets, and layer 0's messages of both hops, read
+    from the stored table one a slot."""
+    m = _gcn_model()
     assert m.step_counters == (
         "expand_slots", "expand_edges", "expand_overflow_nodes")
     opt = train_lib.get_optimizer("adam", 0.01)
@@ -144,8 +151,12 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
         assert (f"/{scope}/" in text) == (scope in here), scope
     lines = text.splitlines()
     assert any("/expand/" in ln and "sort" in ln for ln in lines)
-    assert any("/expand/" in ln and "scatter" in ln for ln in lines)
+    # the rank's scatter and the set's: every product of the expansion
+    # stays a product of the step
+    assert sum("/expand/scatter" in ln for ln in lines) >= 2
     assert any("/segment_agg/" in ln and "scatter" in ln for ln in lines)
+    assert any("/segment_agg/" in ln and "gather" in ln for ln in lines)
+    assert any("/gather_features/" in ln and "gather" in ln for ln in lines)
     assert not any("/segment_agg/" in ln and "dot_general" in ln
                    for ln in lines)
     assert not any("/expand/" in ln and "/segment_agg/" in ln for ln in lines)
@@ -155,6 +166,47 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
     slots, edges, overflow = np.asarray(counts)
     assert metric.shape == (3,) and overflow == 0
     assert 0 < edges <= slots
+
+
+@pytest.mark.parametrize("one_pass", [True, False],
+                         ids=["stored_table", "hops_rows"])
+def test_lowered_gcn_step_gathers_the_outer_hops_rows_once(graph, one_pass):
+    """Where layer 0's messages come from the stored table in one pass
+    the step holds no tensor of the outer hop's set rows, at the feature
+    width or the stored one, and no row gather of that many rows; the
+    same model with a projected node encoder (``use_residual``) keeps
+    the hop's rows and shows that the look would find them."""
+    import re
+
+    m = _gcn_model(use_residual=not one_pass)
+    cap, slots = 64, 32 * 5  # the outer hop's set; hop 1's 32 x 5 slots
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    text = jax.jit(m.make_train_step(opt)).lower(
+        state, m.sample(graph, roots)).as_text(debug_info=True)
+    assert f"tensor<{slots}xi32>" in text  # the slab of the fixture is 5 wide
+    # an op's line ends in the number of its location; the location's own
+    # line holds the scopes it was traced under
+    names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]*)"', text, re.M))
+    gathers = [
+        (names.get(ref, ""), out)
+        for out, ref in re.findall(
+            r'"stablehlo\.gather".* -> (tensor<\S+>) loc\((#loc\d+)\)', text)]
+    assert len(gathers) > 8
+    set_rows = re.findall(rf"tensor<{cap}x(?:2|128)xf32>", text)
+    row_gathers = [g for g in gathers if g[1].startswith(f"tensor<{cap}x")]
+    assert bool(set_rows) == (not one_pass)
+    assert bool(row_gathers) == (not one_pass)
+    # the messages' rows, one a slot, under gather_features, by ids
+    # composed under segment_agg
+    assert any(
+        "/gather_features/" in name and out == f"tensor<{slots}x128xf32>"
+        for name, out in gathers) == one_pass
+    assert any(
+        name.endswith("/segment_agg/gather")
+        and out == f"tensor<{slots}xi32>"
+        for name, out in gathers) == one_pass
 
 
 @pytest.mark.parametrize("walk_len", [5, 0], ids=["node2vec", "line"])
