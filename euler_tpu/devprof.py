@@ -2,10 +2,12 @@
 counters (OBSERVABILITY.md "Device plane").
 
 Four observability planes (PR 5-8) instrumented the host and the wire;
-this module watches the DEVICE half of the step: every XLA backend
-compile (count + log2-µs latency histogram), every recompile after a
-function's warmup (the classic silent 100x — a shape/dtype drift makes
-jit quietly rebuild the program), device memory in use, and the
+this module watches the DEVICE half of the step: every jit's way to an
+executable as jax times it (the jaxpr trace, the lowering to an MLIR
+module, the XLA backend compile: count + log2-µs latency histograms),
+every recompile after a function's warmup (the classic silent 100x — a
+shape/dtype drift makes jit quietly rebuild the program), device memory
+in use, what the compiled train step needs in temporaries, and the
 host<->device transfer volume. Everything lands in the existing native
 surfaces through the eg_counter_add / eg_phase_record / eg_devprof ABI,
 so metrics_text(), the STATS scrape, blackbox postmortems and
@@ -18,17 +20,27 @@ scripts/metrics_dump.py report the device plane with zero new plumbing:
     devprof.sample_device_mem()      one-shot HBM/buffer gauge refresh
     devprof.record_feature_table(w, stored)   feature-table width gauges
     devprof.record_store_table(w, stored)     per-node store width gauges
+    devprof.record_step_memory(compiled)      the step's temporaries gauge
     devprof.count_h2d(batch)         transfer-byte bracketing
     devprof.set_devprof(False)       process-global kill-switch
 
 Compile COUNTS ride ``device_compiles`` / ``device_recompiles`` /
 ``serve_recompiles`` (eg_stats.h), compile LATENCY rides the
-``phase:compile`` histogram (eg_phase.h), memory gauges ride the
-blackbox resource section (eg_blackbox.h + eg_devprof.h). The compile
-detector is a ``jax.monitoring`` event listener (exact backend compile
-durations; a persistent-cache hit fires it too, with the retrieval
-time). Attribution (WHICH function recompiled, WHAT drifted) comes from
-:class:`Watched`'s jit-cache-size delta plus the arg shape signature.
+``phase:compile`` histogram (eg_phase.h) with ``phase:trace`` and
+``phase:lower`` beside it, memory gauges ride the blackbox resource
+section (eg_blackbox.h + eg_devprof.h). The detector is a pair of
+``jax.monitoring`` listeners: jax stamps the start of each timed stretch
+(a scalar event) and hands over its duration at the end (exact backend
+compile durations; a persistent-cache hit fires it too, with the
+retrieval time), each with the function's name. jax times a jit traced
+inside another jit's trace as well, and an eager op's whole compile
+inside a trace: a stretch records its SELF time (its duration less the
+stretches and set-up spans it holds, ``telemetry.span_open`` /
+``span_close``), so the three sums are a union and never exceed the wall
+time they cover on a thread. Attribution (WHICH function recompiled,
+WHAT drifted) comes from :class:`Watched`'s jit-cache-size delta plus
+the arg shape signature; :func:`function_compile_ms` says what one
+function's trace / lower / compile took.
 """
 
 from __future__ import annotations
@@ -47,11 +59,23 @@ log = logging.getLogger("euler_tpu.devprof")
 # per compile, duration in seconds). Pinned by tests against the live
 # jax in the image.
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+# Its two siblings: tracing a jitted function to a jaxpr, and lowering
+# the jaxpr to an MLIR module. Each event's phase:
+EVENT_PHASE = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    COMPILE_EVENT: "compile",
+}
 
 _LEDGER_CAP = 256
+# {function: {phase: self ms}} of the listener's events, for the
+# first-step summary; bounded: a program has a few hundred jits
+_FN_CAP = 1024
+_fn_ms: dict = {}
 
 _enabled = True
 _installed = False
+_start_installed = False  # the start listener, armed with the other
 _lock = threading.Lock()
 _ledger: list = []
 _sampler_stop = None
@@ -77,22 +101,59 @@ def set_devprof(on: bool) -> None:
     _enabled = bool(on)
 
 
-def _on_event_duration(event: str, duration: float, **kw) -> None:
-    # Called from inside jax's compile path — must never raise.
+def _fn_key(fun_name) -> str:
+    # the lowering and the compile name the module: "jit(<function>)"
+    name = str(fun_name)
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name
+
+
+def _on_event_start(event: str, value: float, **kw) -> None:
+    # jax stamps the start of a timed stretch — must never raise.
     try:
-        if not _enabled or event != COMPILE_EVENT:
-            return
-        native.counter_add("device_compiles")
-        telemetry.record_phase("compile", duration * 1e6)
+        phase = EVENT_PHASE.get(event)
+        # (inert once the duration listener is out: a start with no end
+        # would leave its span open)
+        if _enabled and _installed and phase is not None:
+            telemetry.span_open(phase, {"fn": _fn_key(kw.get("fun_name"))})
     except Exception:  # pragma: no cover - defensive
         pass
 
 
+def _on_event_duration(event: str, duration: float, **kw) -> None:
+    # Called from inside jax's compile path — must never raise.
+    try:
+        phase = EVENT_PHASE.get(event)
+        if not _enabled or phase is None:
+            return
+        if phase == "compile":
+            native.counter_add("device_compiles")
+        span = telemetry.open_span(phase)
+        if span is None:  # armed inside the stretch: no start was seen
+            telemetry.record_phase(phase, duration * 1e6)
+            return
+        self_us = telemetry.span_close(span, us=duration * 1e6)
+        fn = span.args["fn"]
+        if fn in _fn_ms or len(_fn_ms) < _FN_CAP:
+            ms = _fn_ms.setdefault(fn, {})
+            ms[phase] = ms.get(phase, 0.0) + self_us / 1e3
+    except Exception:  # pragma: no cover - defensive
+        pass
+
+
+def function_compile_ms(name: str) -> dict:
+    """{"trace": ms, "lower": ms, "compile": ms} the listener has seen
+    for the jitted function ``name`` (self times; a phase it has not
+    seen is absent)."""
+    return dict(_fn_ms.get(name, ()))
+
+
 def install(sample_ms: int = 0) -> None:
     """Arm the device plane (idempotent): register the jax.monitoring
-    compile listener; with ``sample_ms > 0`` also start the background
+    listeners; with ``sample_ms > 0`` also start the background
     device-memory sampler."""
-    global _installed
+    global _installed, _start_installed
     with _lock:
         if not _installed:
             import jax.monitoring
@@ -100,6 +161,11 @@ def install(sample_ms: int = 0) -> None:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_event_duration
             )
+            # (a caller that took the duration listener out by hand has
+            # left this one in)
+            if not _start_installed:
+                jax.monitoring.register_scalar_listener(_on_event_start)
+                _start_installed = True
             _installed = True
     if sample_ms > 0:
         start_sampler(sample_ms)
@@ -111,15 +177,19 @@ def uninstall() -> None:
     process that goes on after the run that armed the plane (a test
     worker): a listener left in records ``compile`` spans into whatever
     runs next."""
-    global _installed
+    global _installed, _start_installed
     with _lock:
-        if _installed:
+        if _installed or _start_installed:
             import jax.monitoring
 
+        if _installed:
             jax.monitoring.unregister_event_duration_listener(
                 _on_event_duration
             )
             _installed = False
+        if _start_installed:
+            jax.monitoring.unregister_scalar_listener(_on_event_start)
+            _start_installed = False
     stop_sampler()
 
 
@@ -202,10 +272,12 @@ def recompile_ledger() -> list:
 
 
 def devprof_reset() -> None:
-    """Clear the recompile ledger (native gauges/counters reset with
-    telemetry_reset()/counters_reset())."""
+    """Clear the recompile ledger and the per-function compile times
+    (native gauges/counters reset with telemetry_reset()/
+    counters_reset())."""
     with _lock:
         del _ledger[:]
+    _fn_ms.clear()
 
 
 class Watched:
@@ -338,6 +410,27 @@ def record_store_table(width: int, stored_width: int) -> None:
         lib().eg_devprof_set_store_table(int(width), int(stored_width))
 
 
+def record_step_memory(compiled) -> dict | None:
+    """The gauge ``step_temp_bytes`` of the resource section, from a
+    compiled train step's ``memory_analysis()``: what the program needs
+    beside its arguments and results, which ``memory_stats()`` does not
+    count (train.write_step_hlo calls this once, in a profiled run).
+    Returns the analysis' sizes in bytes, None where the backend gives
+    none."""
+    if not _enabled:
+        return None
+    try:
+        ma = compiled.memory_analysis()
+        sizes = {
+            k: int(getattr(ma, k + "_size_in_bytes"))
+            for k in ("temp", "argument", "output", "alias")
+        }
+    except Exception:  # noqa: BLE001 - backend without the analysis
+        return None
+    lib().eg_devprof_set_step_temp(sizes["temp"])
+    return sizes
+
+
 def start_sampler(period_ms: int = 1000) -> None:
     """Background device-memory sampler (daemon; idempotent): refreshes
     the native gauges every ``period_ms`` so the blackbox resource ring
@@ -418,8 +511,35 @@ def count_d2h(tree) -> int:
 
 
 # ---------------------------------------------------------------------------
-# summaries (run_loop first-step line, scripts/devprof_dump.py)
+# summaries (train()'s first-step line, scripts/devprof_dump.py)
 # ---------------------------------------------------------------------------
+
+
+def first_step_line(step_name: str) -> str:
+    """The once-a-run line ``train()`` logs at its first dispatched
+    step: the compiles so far (with the persistent compile cache warm
+    their time drops to ~0 on the second launch), every set-up leaf with
+    its seconds (and GB where its spans named bytes), and what the step
+    function's own trace / lower / compile took beside all jits'."""
+    data = telemetry.telemetry_json()
+    cs = compile_summary(data)
+    leaves = ", ".join(
+        f"{name.removeprefix('setup_')} {secs:.1f} s"
+        + (f" ({nbytes / 1e9:.2f} GB)" if nbytes else "")
+        for name, (secs, nbytes) in telemetry.setup_summary(data).items()
+    )
+    fn = function_compile_ms(step_name)
+    step = " / ".join(
+        f"{phase} {fn.get(phase, 0.0) / 1e3:.1f}"
+        for phase in ("trace", "lower", "compile")
+    )
+    return (
+        f"first step dispatched: {cs['compile_events']} XLA compile(s), "
+        f"{cs['compile_ms_total']:.0f} ms compile time; all jits: trace "
+        f"{cs['trace_ms_total'] / 1e3:.1f} s, lower "
+        f"{cs['lower_ms_total'] / 1e3:.1f} s; {step_name}: {step} s; "
+        f"set-up: {leaves or 'no span recorded'}"
+    )
 
 
 def compile_summary(data: dict | None = None) -> dict:
@@ -428,8 +548,8 @@ def compile_summary(data: dict | None = None) -> dict:
     The run_loop logs this after the first step so a relaunch with a
     warm compilation cache is visibly cheap."""
     data = data or telemetry.telemetry_json()
-    h = data["hist"].get("phase:compile") or {"b": [0], "count": 0,
-                                              "sum_us": 0}
+    none = {"b": [0], "count": 0, "sum_us": 0}
+    h = data["hist"].get("phase:compile") or none
     pct = telemetry.percentiles(h, (50, 99)) if h["count"] else {}
     res = data.get("resource", {})
     return {
@@ -440,9 +560,14 @@ def compile_summary(data: dict | None = None) -> dict:
         "compile_ms_total": round(h["sum_us"] / 1000.0, 1),
         "compile_ms_p50": round(pct.get(50, 0.0) / 1000.0, 1),
         "compile_ms_p99": round(pct.get(99, 0.0) / 1000.0, 1),
+        "trace_ms_total": round(
+            (data["hist"].get("phase:trace") or none)["sum_us"] / 1000.0, 1),
+        "lower_ms_total": round(
+            (data["hist"].get("phase:lower") or none)["sum_us"] / 1000.0, 1),
         "h2d_bytes": data["counters"].get("h2d_bytes", 0),
         "d2h_bytes": data["counters"].get("d2h_bytes", 0),
         "device_mem_bytes": res.get("device_mem_bytes", 0),
         "device_mem_peak_bytes": res.get("device_mem_peak_bytes", 0),
         "device_buffers": res.get("device_buffers", 0),
+        "step_temp_bytes": res.get("step_temp_bytes", 0),
     }

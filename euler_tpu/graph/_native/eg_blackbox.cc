@@ -624,6 +624,9 @@ void Blackbox::ResourceJsonBody(std::string* out) {
   AppendKey(out, "store_table_stored_width");
   AppendI64(out, Devprof::Global().store_table_stored_width());
   out->push_back(',');
+  AppendKey(out, "step_temp_bytes");
+  AppendI64(out, Devprof::Global().step_temp_bytes());
+  out->push_back(',');
   AppendKey(out, "history_depth");
   uint64_t hh = hist_head_.load(std::memory_order_acquire);
   AppendU64(out, hh > kBbHistorySlots ? kBbHistorySlots : hh);

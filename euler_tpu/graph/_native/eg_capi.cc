@@ -953,6 +953,15 @@ void eg_devprof_set_store_table(int64_t width, int64_t stored_width) {
   EG_API_GUARD()
 }
 
+// The compiled train step's temporaries in bytes (train.write_step_hlo
+// sets it once, in a profiled run, from the step it compiles anyway).
+void eg_devprof_set_step_temp(int64_t bytes) {
+  try {
+    eg::Devprof::Global().SetStepTemp(bytes);
+  }
+  EG_API_GUARD()
+}
+
 // Refresh the live serve-SLO gauges (µs): euler_tpu/serving/slo.py
 // pushes its windowed p50/p99 and lifetime violations every few
 // records, so a scrape reads serving latency without draining.

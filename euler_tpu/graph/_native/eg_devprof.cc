@@ -77,9 +77,9 @@ void Devprof::Reset() {
   mem_bytes_.store(0, std::memory_order_relaxed);
   mem_peak_bytes_.store(0, std::memory_order_relaxed);
   buffers_.store(0, std::memory_order_relaxed);
-  // the feature-table and store-table widths stay: they are set once,
-  // when the tables are built, and the tables outlive a reset of the
-  // measurements
+  // the feature-table and store-table widths and the step's temporaries
+  // stay: they are set once, when the tables are built and the step is
+  // compiled, and both outlive a reset of the measurements
   slo_p50_us_.store(0, std::memory_order_relaxed);
   slo_p99_us_.store(0, std::memory_order_relaxed);
   slo_violations_.store(0, std::memory_order_relaxed);

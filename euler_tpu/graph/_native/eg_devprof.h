@@ -55,6 +55,13 @@ class Devprof {
   // with stores is handed to train().
   void SetStoreTable(int64_t width, int64_t stored_width);
 
+  // What the compiled train step needs beside its arguments and
+  // results: memory_analysis().temp_size_in_bytes (train.write_step_hlo,
+  // profiled runs). device.memory_stats() does not count it.
+  void SetStepTemp(int64_t bytes) {
+    step_temp_bytes_.store(bytes, std::memory_order_relaxed);
+  }
+
   int64_t mem_bytes() const {
     return mem_bytes_.load(std::memory_order_relaxed);
   }
@@ -76,6 +83,9 @@ class Devprof {
   int64_t store_table_stored_width() const {
     return store_stored_width_.load(std::memory_order_relaxed);
   }
+  int64_t step_temp_bytes() const {
+    return step_temp_bytes_.load(std::memory_order_relaxed);
+  }
 
   // Append `,"serve_slo":{"p50_us":..,"p99_us":..,"violations":..,
   // "count":..}` to an in-progress JSON object (Telemetry::Json calls
@@ -94,6 +104,7 @@ class Devprof {
   std::atomic<int64_t> feature_stored_width_{0};
   std::atomic<int64_t> store_width_{0};
   std::atomic<int64_t> store_stored_width_{0};
+  std::atomic<int64_t> step_temp_bytes_{0};
   std::atomic<uint64_t> slo_p50_us_{0};
   std::atomic<uint64_t> slo_p99_us_{0};
   std::atomic<uint64_t> slo_violations_{0};

@@ -14,9 +14,10 @@
 //   * per-phase µs HISTOGRAMS (input_stall / sample / h2d / device /
 //     host / step, the training thread's LEAVES input_other /
 //     dispatch / fence / hook / log_flush / checkpoint / host_other
-//     that tile one iteration, and stall) — recorded by the Python
-//     training loop and prefetch pipeline through the eg_phase_record
-//     ABI;
+//     that tile one iteration, stall, the compile listener's trace /
+//     lower beside compile, and the set-up leaves setup_*) — recorded
+//     by the Python training loop, prefetch pipeline, devprof listener
+//     and set-up path through the eg_phase_record ABI;
 //   * prefetch pipeline VALUE histograms (queue depth at dequeue,
 //     workers busy at dequeue) — dimensionless log2 buckets, so
 //     count/sum give dequeues and mean depth and the bucket shape
@@ -64,6 +65,22 @@ enum StepPhase : int {
   kPhaseHostOther,       // the rest of the host tail
   kPhaseStall,           // one sample per journalled stall: the step's
                          // excess over the running median (never a span)
+  // The listener's other two (euler_tpu/devprof.py): what jax.monitoring
+  // hands over beside the backend compile. Self time: a jit traced inside
+  // another's trace is counted once, so the three sums are a union.
+  kPhaseTrace,           // jaxpr trace of a jitted function
+  kPhaseLower,           // jaxpr -> MLIR module
+  // Set-up (OBSERVABILITY.md "Set-up phases"): once or a few times a
+  // process, before train()'s first iteration. The leaves are self
+  // times on one thread (a span that holds another records what is left
+  // of it); telemetry.py PHASE_PARENT names `setup` as their parent (a
+  // name only: their sum is read from the leaves, no cell of its own).
+  kPhaseSetupGraphLoad,  // Graph._connect: the native parse of the .dat
+  kPhaseSetupTableExport,  // whole-table get_dense_feature / sparse export
+  kPhaseSetupAdjacency,  // build_adjacency, alias and node-sampler tables
+  kPhaseSetupPack,       // pallas_sampling.pack_adjacency
+  kPhaseSetupUpload,     // the host's part of handing tables to the device
+  kPhaseSetupStatePlace,  // train(): init_state, placement, describe_state
   kPhaseCount,
 };
 
@@ -71,7 +88,10 @@ const char* const kPhaseNames[kPhaseCount] = {
     "input_stall", "sample",   "h2d",        "device",
     "host",        "step",     "compile",    "input_other",
     "dispatch",    "fence",    "hook",       "log_flush",
-    "checkpoint",  "host_other", "stall",
+    "checkpoint",  "host_other", "stall",    "trace",
+    "lower",       "setup_graph_load",       "setup_table_export",
+    "setup_adjacency",         "setup_pack", "setup_upload",
+    "setup_state_place",
 };
 
 // The program's periodic jobs. Each stamps the begin and the end of its

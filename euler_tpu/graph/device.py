@@ -45,9 +45,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from euler_tpu.telemetry import setup_spanned
+
 log = logging.getLogger("euler_tpu")
 
 
+@setup_spanned("setup_adjacency")
 def _fetch_flat_csr(graph, edge_types, max_id: int, chunk: int,
                     sorted: bool = False):
     """Chunked full-neighbor export shared by the slab and alias
@@ -77,6 +80,7 @@ def _fetch_flat_csr(graph, edge_types, max_id: int, chunk: int,
     return counts_all, nbr_flat, w_flat, offsets
 
 
+@setup_spanned("setup_adjacency")
 def build_adjacency(
     graph,
     edge_types,
@@ -214,6 +218,7 @@ def _build_alias_rows(offsets: np.ndarray, w_flat: np.ndarray):
     return prob, alias_local
 
 
+@setup_spanned("setup_adjacency")
 def build_alias_adjacency(
     graph,
     edge_types,
@@ -398,6 +403,7 @@ def _export_node_arrays(graph, max_id: int, need_types: bool,
     return weights, types
 
 
+@setup_spanned("setup_adjacency")
 def build_node_sampler(graph, node_type: int = -1, max_id: int = 0) -> dict:
     """Weighted global node sampler for one node type (-1 = all types:
     with-replacement draws over all weights give the marginal of the
@@ -825,6 +831,7 @@ def alias_biased_random_walk(adj, roots, key, walk_len: int, p: float,
     return jnp.stack(cols, axis=1)
 
 
+@setup_spanned("setup_adjacency")
 def build_typed_node_sampler(graph, num_types: int, max_id: int) -> dict:
     """Per-node-type weighted samplers packed into one flat layout for the
     device sample_node_with_src (reference sample_node_with_src semantics:

@@ -13,6 +13,7 @@ the bit patterns pass through unchanged (default ids like -1 wrap).
 from __future__ import annotations
 
 import ctypes
+import os
 import threading
 
 import numpy as np
@@ -47,6 +48,22 @@ def _default_u64(default_node: int) -> int:
     return int(np.int64(default_node).view(np.uint64))
 
 
+def _partition_bytes(directory: str, shard_idx: int, shard_num: int) -> int:
+    """Bytes of the ``.dat`` partitions the native loader parses from a
+    local directory (its rule: remote_fs.in_shard); 0 where the
+    directory cannot be listed (the load then says why)."""
+    from euler_tpu.graph.remote_fs import in_shard
+
+    try:
+        return sum(
+            os.path.getsize(os.path.join(directory, name))
+            for name in os.listdir(directory)
+            if in_shard(name, shard_idx, shard_num)
+        )
+    except OSError:
+        return 0
+
+
 def str2bool(v) -> bool:
     """ONE truthy-string rule for every bool that can arrive as text
     (config strings here, CLI flags in run_loop) — two parsers with
@@ -64,8 +81,6 @@ def parse_config(source: str) -> dict:
     (reference euler/client/graph_config.cc:33-56) plus the semicolon
     string form used across its C ABI (create_graph.cc:50-60).
     """
-    import os
-
     # a path wins over the inline form when both could apply (paths may
     # legitimately contain '='; inline strings are never existing files)
     if os.path.exists(source) or "=" not in source:
@@ -528,6 +543,9 @@ class Graph:
                 err = self._lib.eg_last_error().decode()
                 raise RuntimeError(f"remote graph init failed: {err}")
             return
+        # (telemetry imports this package's native loader: not at the top)
+        from euler_tpu.telemetry import setup_span
+
         h = self._lib.eg_create()
         if buffers is not None:
             n = len(buffers)
@@ -543,14 +561,22 @@ class Graph:
                 lens[i] = len(blob)
             # `buffers` stays referenced through the call; the engine
             # copies during parse, so the bytes can drop right after
-            rc = self._lib.eg_load_buffers(h, bufs, lens, names, n)
+            with setup_span("setup_graph_load", sum(lens)):
+                rc = self._lib.eg_load_buffers(h, bufs, lens, names, n)
         elif directory is not None:
-            rc = self._lib.eg_load(
-                h, directory.encode(), shard_idx, shard_num
-            )
+            with setup_span(
+                "setup_graph_load",
+                _partition_bytes(directory, shard_idx, shard_num),
+            ):
+                rc = self._lib.eg_load(
+                    h, directory.encode(), shard_idx, shard_num
+                )
         elif files:
             arr = (ctypes.c_char_p * len(files))(*[f.encode() for f in files])
-            rc = self._lib.eg_load_files(h, arr, len(files))
+            with setup_span(
+                "setup_graph_load", sum(map(os.path.getsize, files))
+            ):
+                rc = self._lib.eg_load_files(h, arr, len(files))
         else:
             self._lib.eg_destroy(h)
             raise ValueError("pass directory= or files=")

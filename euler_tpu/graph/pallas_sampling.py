@@ -69,6 +69,8 @@ import os
 
 import numpy as np
 
+from euler_tpu.telemetry import setup_spanned
+
 LANES = 128
 # Kernel budgets. Each is a promise that a shape eligible()/eligible2()
 # admits is one Mosaic compiles — checked on the chip at every corner by
@@ -241,6 +243,7 @@ def eligible(m: int, count: int) -> bool:
     )
 
 
+@setup_spanned("setup_pack")
 def pack_adjacency(adj: dict, max_bytes: int = MAX_PACKED_BYTES):
     """[2KN, 128] int32, K = ceil(W/128): node i occupies rows
     2K*i..2K*i+2K-1 — its K neighbor-id rows (pad: default id) then its

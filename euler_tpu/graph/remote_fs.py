@@ -88,6 +88,20 @@ def partition_index(name: str) -> int:
     return int(m.group(1)) if m else -1
 
 
+def in_shard(name: str, shard_idx: int, shard_num: int) -> bool:
+    """True where file ``name`` is a ``.dat`` partition of this shard —
+    the ONE copy of the selection rule, shared by staged and streamed
+    ingest and by the local load's byte count, so that none of them can
+    pick another file set. It matches the native loader exactly
+    (eg_engine.cc Engine::Load): a name without a ``_<p>.dat`` suffix
+    belongs to partition 0, so under sharding it goes to shard 0, not
+    to no shard."""
+    if not name.endswith(".dat"):
+        return False
+    p = max(partition_index(name), 0)
+    return shard_num <= 1 or p % shard_num == shard_idx
+
+
 def _fetch(fs, remote: str, local: str) -> None:
     # tmp name unique per process AND thread: concurrent stagers (worker
     # processes or threads on one host) must never interleave writes into
@@ -103,12 +117,8 @@ def _fetch(fs, remote: str, local: str) -> None:
 
 def _shard_partitions(fs, root: str, shard_idx: int, shard_num: int,
                       url: str | None = None):
-    """List this shard's ``.dat`` partition entries under ``root`` —
-    the ONE copy of the selection rule, shared by staged and streamed
-    ingest so the two modes can never pick different file sets. It
-    matches the native loader exactly (eg_engine.cc Engine::Load): a
-    name without a ``_<p>.dat`` suffix belongs to partition 0, so under
-    sharding it goes to shard 0, not to no shard.
+    """List this shard's ``.dat`` partition entries under ``root``
+    (the rule: :func:`in_shard`).
 
     Returns (partition entries, meta.json entry or None).
     """
@@ -118,15 +128,8 @@ def _shard_partitions(fs, root: str, shard_idx: int, shard_num: int,
         name = os.path.basename(ent["name"])
         if name == "meta.json":
             meta = ent
-            continue
-        if not name.endswith(".dat"):
-            continue
-        p = partition_index(name)
-        if p < 0:
-            p = 0
-        if shard_num > 1 and p % shard_num != shard_idx:
-            continue
-        picked.append(ent)
+        elif in_shard(name, shard_idx, shard_num):
+            picked.append(ent)
     if not picked:
         # report the URL the caller actually passed, not the
         # scheme-stripped root — the error must map back to the config

@@ -26,6 +26,7 @@ import optax
 
 from euler_tpu import devprof
 from euler_tpu.nn import metrics
+from euler_tpu.telemetry import setup_span
 
 log = logging.getLogger("euler_tpu")
 
@@ -116,17 +117,34 @@ def upload_sparse_tables(
     from euler_tpu import ops
 
     all_ids = np.arange(max_id + 2, dtype=np.int64)
-    tables = ops.get_sparse_feature(
-        graph, all_ids, list(feature_idxs), max_len,
-        default_values=list(default_values),
-    )
-    return [
-        {
-            "ids": jnp.asarray(t_ids.astype(np.int32)),
-            "mask": jnp.asarray(t_mask),
-        }
-        for t_ids, t_mask in tables
-    ]
+    with setup_span("setup_table_export") as export:
+        tables = [
+            (t_ids.astype(np.int32), t_mask)
+            for t_ids, t_mask in ops.get_sparse_feature(
+                graph, all_ids, list(feature_idxs), max_len,
+                default_values=list(default_values),
+            )
+        ]
+        export.nbytes = sum(i.nbytes + m.nbytes for i, m in tables)
+    with setup_span("setup_upload", export.nbytes):
+        return [
+            {"ids": jnp.asarray(t_ids), "mask": jnp.asarray(t_mask)}
+            for t_ids, t_mask in tables
+        ]
+
+
+def export_table(graph, ids, feature_idx: int, width: int, dtype=None):
+    """One whole-table ``get_dense_feature`` export ([len(ids), width]
+    float32 through numpy) and its way to the device, each under its
+    set-up span. The upload's span ends where ``jnp.asarray`` returns:
+    the host's part of it; what the runtime still copies after that runs
+    on under whatever comes next (no fence here: it would hold the next
+    table's export, and the step's trace and compile, behind the copy)."""
+    with setup_span("setup_table_export") as export:
+        host = graph.get_dense_feature(ids, [feature_idx], [width])
+        export.nbytes = host.nbytes
+    with setup_span("setup_upload", host.nbytes):
+        return jnp.asarray(host, dtype=dtype)
 
 
 # The minor dimension of the TPU's (8, 128) memory tile. The runtime
@@ -561,11 +579,8 @@ class Model:
             # the engine zero-fills a slot up to the width it is asked
             # for, so the lane padding costs no second host copy
             width = stored_width(self.feature_dim)
-            consts["features"] = jnp.asarray(
-                graph.get_dense_feature(
-                    ids, [self.feature_idx], [width]
-                ),
-                dtype=dt or None,
+            consts["features"] = export_table(
+                graph, ids, self.feature_idx, width, dtype=dt or None
             )
             devprof.record_feature_table(self.feature_dim, width)
             log.info(
@@ -574,10 +589,8 @@ class Model:
                 consts["features"].dtype, n, width,
             )
         if getattr(self, "label_idx", -1) >= 0:
-            consts["labels"] = jnp.asarray(
-                graph.get_dense_feature(
-                    ids, [self.label_idx], [self.label_dim]
-                )
+            consts["labels"] = export_table(
+                graph, ids, self.label_idx, self.label_dim
             )
         sparse_idx = getattr(self, "sparse_feature_idx", [])
         if sparse_idx:

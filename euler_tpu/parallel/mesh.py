@@ -25,6 +25,8 @@ import numpy as np
 from jax.experimental.layout import Format, Layout
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from euler_tpu.telemetry import setup_span
+
 # Top-level train-state keys holding [num_nodes, dim]-shaped tables that
 # row-shard over the 'model' axis.
 _TABLE_KEYS = ("consts", "stores", "grad_stores")
@@ -257,7 +259,8 @@ def pad_tables_for_mesh(state, mesh: Mesh):
                 )
         return x
 
-    return jax.tree_util.tree_map_with_path(pad, state)
+    with setup_span("setup_upload"):
+        return jax.tree_util.tree_map_with_path(pad, state)
 
 
 def _placed_as(x, s) -> bool:
@@ -296,14 +299,18 @@ def put_global(tree, shardings, consume: bool = False):
     pinned layout is then freed once its copy exists, so a [rows, dim]
     store is not held twice through the run by whoever built it."""
     multi = jax.process_count() > 1
+    moved = 0  # bytes of the leaves that came from the host
 
     def put(x, s):
+        nonlocal moved
         if _placed_as(x, s):
             # already placed (e.g. a checkpoint-restored global array) —
             # np.asarray on it would crash for model-axis-sharded leaves
             # (spans non-addressable devices) and needlessly round-trip
             # everything else
             return x
+        if not isinstance(x, jax.Array):
+            moved += getattr(x, "nbytes", 0)
         if multi:
             host = np.asarray(x)
             with compiles_keep_layouts(s):
@@ -324,7 +331,12 @@ def put_global(tree, shardings, consume: bool = False):
             x.delete()
         return y
 
-    return jax.tree.map(put, tree, shardings)
+    # the host's part of the placement, the re-lay into a pinned layout
+    # included; the copies may run on past the span's end (no fence)
+    with setup_span("setup_upload") as upload:
+        placed = jax.tree.map(put, tree, shardings)
+        upload.nbytes = moved
+    return placed
 
 
 def shard_batch(batch, mesh: Mesh):

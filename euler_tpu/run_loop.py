@@ -233,8 +233,10 @@ def define_flags(parser: Optional[argparse.ArgumentParser] = None):
         "suppresses postmortem dumps (counters/telemetry unaffected)"))
     p.add_argument("--trace_file", default="", help=(
         "write a merged Chrome-trace/Perfetto JSON here when training "
-        "ends: per-step phase slices (input_stall/sample/h2d/device/"
-        "host) + this client's slow-span journal + every live shard's "
+        "ends: the set-up lane (graph load, table export, slabs, "
+        "upload, trace/lower/compile), per-step phase slices "
+        "(input_stall/sample/h2d/device/host) + this client's "
+        "slow-span journal + every live shard's "
         "scraped journal, flow-linked by wire-v3 trace ids — open in "
         "ui.perfetto.dev (OBSERVABILITY.md 'Step phases')"))
     p.add_argument("--prefetch_depth", type=int, default=2)
@@ -714,7 +716,10 @@ def _num_steps(args) -> int:
     return per_epoch * args.num_epochs
 
 
-def run_train(model, graph, args, mesh):
+def run_train(model, graph, args, mesh, recorder=None):
+    """``recorder``: the ``--trace_file`` recorder, which ``main`` starts
+    before the graph is built so that the export carries the set-up
+    lane; stopped and written out here, however the run ends."""
     import jax
 
     batch = args.batch_size * getattr(model, "batch_size_ratio", 1)
@@ -748,11 +753,6 @@ def run_train(model, graph, args, mesh):
                 append_metrics_line(_path, step)
                 job_tick("metrics_every", end=True)
 
-    recorder = None
-    if args.trace_file:
-        from euler_tpu.trace import TraceRecorder
-
-        recorder = TraceRecorder().start()
     try:
         state, history = train_lib.train(
             model,
@@ -933,10 +933,19 @@ def main(argv=None) -> int:
         except Exception:
             log.exception("postmortem dump failed")
 
+    recorder = None
+    if args.trace_file and args.mode == "train":
+        # before the graph is built: the set-up spans (OBSERVABILITY.md
+        # "Set-up phases") reach the sink from Graph() on
+        from euler_tpu.trace import TraceRecorder
+
+        recorder = TraceRecorder().start()
     try:
         graph, services = build_graph(args)
     except Exception:
         _exception_postmortem()
+        if recorder is not None:
+            recorder.stop()
         raise
     try:
         mesh = make_mesh(args.num_devices, model_parallel=args.model_parallel)
@@ -954,7 +963,7 @@ def main(argv=None) -> int:
                     max_degree=args.max_degree, alias=args.alias_sampling
                 )
             if args.mode == "train":
-                run_train(model, graph, args, mesh)
+                run_train(model, graph, args, mesh, recorder)
                 if args.serve_after:
                     # train -> save -> immediately serve: the freshest
                     # checkpoint goes live without a second process or a
@@ -974,6 +983,8 @@ def main(argv=None) -> int:
         _exception_postmortem()
         raise
     finally:
+        if recorder is not None:
+            recorder.stop()  # (a raise before run_train took it over)
         # transport + server survivability ledger (eg_counters_* ABI):
         # in shared mode this process also served its shard, so the
         # snapshot covers both sides — busy_rejects/handler_timeouts/

@@ -17,9 +17,11 @@ plus the step-phase profiler surface (native eg_phase.{h,cc}): the
 training loop and prefetch pipeline record per-step phase timers
 (input_stall / sample / h2d / device / host / step, and the training
 thread's leaves of :data:`PHASE_PARENT`) and prefetch pipeline gauges
-through :func:`record_phase` / :func:`record_prefetch_gauges`, and
+through :func:`record_phase` / :func:`record_prefetch_gauges`,
 :class:`StallJournal` journals the steps that took several times their
-median; they land in the same native "hist" map
+median, and the set-up path records what comes before the first step
+through :class:`setup_span` (:data:`SETUP_PHASES`; OBSERVABILITY.md
+"Set-up phases"); they land in the same native "hist" map
 as the RPC latency histograms, so metrics_text(), snapshot(), the STATS
 scrape, and scripts/metrics_dump.py all report them with one renderer
 (OBSERVABILITY.md "Step phases"), and the percentile/bucket arithmetic
@@ -30,6 +32,7 @@ JSONL emitter used by run_loop.
 from __future__ import annotations
 
 import ctypes
+import functools
 import gc
 import json
 import resource
@@ -46,12 +49,18 @@ NUM_BUCKETS = 28
 
 # Step-phase order — MUST match eg_phase.h StepPhase (the profiler
 # records by index through the eg_phase_record ABI, pinned by tests).
-# "compile" is the device-plane add-on (euler_tpu/devprof.py): XLA
-# backend compile wall time, NOT part of the step-sum identity.
+# "compile", "trace" and "lower" are the device-plane add-on
+# (euler_tpu/devprof.py): what jax.monitoring times of a jit's way to an
+# executable, NOT part of the step-sum identity. The "setup_*" leaves are
+# recorded by :class:`setup_span` on the way to train()'s first step.
 PHASES = ("input_stall", "sample", "h2d", "device", "host", "step",
           "compile", "input_other", "dispatch", "fence", "hook",
-          "log_flush", "checkpoint", "host_other", "stall")
+          "log_flush", "checkpoint", "host_other", "stall",
+          "trace", "lower", "setup_graph_load", "setup_table_export",
+          "setup_adjacency", "setup_pack", "setup_upload",
+          "setup_state_place")
 _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
+SETUP_PHASES = tuple(p for p in PHASES if p.startswith("setup_"))
 
 # The training thread's LEAVES (input_stall, input_other, h2d when it
 # runs there, and the keys below) do not overlap and together cover one
@@ -62,6 +71,8 @@ PHASE_PARENT = {
     "dispatch": "device", "fence": "device",
     "hook": "host", "log_flush": "host", "checkpoint": "host",
     "host_other": "host",
+    # (`setup` is a name only: the sum of its leaves, no histogram)
+    **{leaf: "setup" for leaf in SETUP_PHASES},
 }
 
 # The program's periodic jobs — MUST match eg_phase.h PeriodicJob. Each
@@ -183,6 +194,7 @@ def telemetry_reset() -> None:
     """Zero every histogram and both-side span journals (the enabled
     flag and journal capacity survive)."""
     lib().eg_telemetry_reset()
+    _setup_bytes.clear()
 
 
 def set_slow_capacity(n: int) -> None:
@@ -208,6 +220,13 @@ def set_trace_sink(fn) -> None:
     _trace_sink = fn
 
 
+def now_us() -> int:
+    """CLOCK_MONOTONIC µs: the one clock of every span (``trace.now_us``
+    is this function), of the native spans' ``end_us`` stamps and of the
+    ``eg_align`` stamp."""
+    return time.monotonic_ns() // 1000
+
+
 def record_phase(phase: str, us: float, step: int | None = None,
                  end_us: int | None = None) -> None:
     """One step-phase µs sample (train loop / prefetch pipeline call
@@ -231,14 +250,151 @@ def record_phase_hist(phase: str, us: float) -> None:
 
 
 def record_phase_span(phase: str, start_us: int, end_us: int,
-                      step: int | None = None) -> None:
+                      step: int | dict | None = None) -> None:
     """A trace-sink-only span [start_us, end_us) on CLOCK_MONOTONIC: one
     piece of a leaf that another leaf interrupts (``input_other`` lies on
     both sides of ``input_stall``); its histogram sample is recorded once,
-    with :func:`record_phase_hist`."""
+    with :func:`record_phase_hist`. A span outside the loop (set-up, the
+    compile listener's) has no step: it hands over its ``args`` (what it
+    worked on: ``bytes``, ``fn``) in the step's place."""
     sink = _trace_sink
     if sink is not None and end_us > start_us:
         sink(phase, end_us - start_us, step, end_us)
+
+
+# ---------------------------------------------------------------------------
+# spans outside the loop: set-up, and the compile listener's
+# (OBSERVABILITY.md "Set-up phases")
+# ---------------------------------------------------------------------------
+
+# Each thread's open spans, innermost last. A span that holds another
+# records its SELF time: the histograms of SETUP_PHASES and of devprof's
+# trace / lower / compile then add up to the wall time they cover on a
+# thread, and the pieces that reach the trace sink do not overlap.
+_open_spans = threading.local()
+# bytes the spans of a phase said they moved (the first-step summary)
+_setup_bytes: dict = {}
+
+
+def _span_stack() -> list:
+    try:
+        return _open_spans.stack
+    except AttributeError:
+        _open_spans.stack = []
+        return _open_spans.stack
+
+
+class _Span:
+    __slots__ = ("phase", "args", "start", "cursor", "inside", "muted")
+
+    def __init__(self, phase, args, muted, start):
+        self.phase, self.args, self.muted = phase, args, muted
+        self.start = self.cursor = start
+        self.inside = 0  # µs of closed spans within this one
+
+
+def span_open(phase: str, args: dict | None = None,
+              muted: bool = False) -> _Span:
+    """Open a span of ``phase`` on this thread, now. ``muted``: it and
+    the set-up spans opened under it record nothing."""
+    stack = _span_stack()
+    span = _Span(phase, args, muted or bool(stack and stack[-1].muted),
+                 now_us())
+    stack.append(span)
+    return span
+
+
+def open_span(phase: str) -> _Span | None:
+    """This thread's innermost open span of ``phase`` (the compile
+    listener finds its own again: jax hands it a start and an end, no
+    handle)."""
+    for span in reversed(_span_stack()):
+        if span.phase == phase:
+            return span
+    return None
+
+
+def span_close(span: _Span, us: float | None = None,
+               record: bool = True) -> int:
+    """Close ``span`` (and whatever was left open inside it), now: its
+    self time goes to its phase's histogram (of ``us``, where the caller
+    has the span's length from another clock reading: jax's own), its
+    last piece to the trace sink, its whole length to the span around
+    it. Returns the self time in µs."""
+    end = now_us()
+    stack = _span_stack()
+    if span in stack:
+        del stack[stack.index(span):]
+    whole = end - span.start if us is None else int(us)
+    self_us = max(whole - span.inside, 0)
+    outer = stack[-1] if stack else None
+    if outer is not None:
+        before = outer.cursor  # the outer span's piece before this one
+        outer.cursor = end
+        outer.inside += end - span.start
+    if record:
+        record_phase_hist(span.phase, self_us)
+        if outer is not None and not outer.muted:
+            record_phase_span(outer.phase, before, span.start, outer.args)
+        record_phase_span(span.phase, span.cursor, end, span.args)
+    return self_us
+
+
+class setup_span:
+    """``with setup_span("setup_upload", nbytes=table.nbytes):`` times a
+    stage of set-up (a leaf of :data:`SETUP_PHASES`) where the work is
+    done, on :func:`now_us`'s clock. Follows the telemetry kill-switch;
+    ``on=False`` (``train(phase_profile=False)``) records nothing of it
+    nor of the set-up spans inside it. ``nbytes`` may be set on the way
+    (``as span: ... span.nbytes = n``)."""
+
+    __slots__ = ("phase", "nbytes", "on", "_span")
+
+    def __init__(self, phase: str, nbytes: int = 0, on: bool = True):
+        self.phase, self.nbytes, self.on = phase, nbytes, on
+        self._span = None
+
+    def __enter__(self):
+        if not self.on:
+            self._span = span_open(self.phase, muted=True)
+        elif telemetry_enabled():
+            self._span = span_open(self.phase)
+        return self
+
+    def __exit__(self, *exc):
+        span, self._span = self._span, None
+        if span is None:
+            return False
+        record = not span.muted
+        if record and self.nbytes:
+            span.args = {"bytes": int(self.nbytes)}
+            _setup_bytes[self.phase] = (
+                _setup_bytes.get(self.phase, 0) + int(self.nbytes))
+        span_close(span, record=record)
+        return False
+
+
+def setup_spanned(phase: str):
+    """Decorator: the whole call is one :class:`setup_span` of ``phase``."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*args, **kwargs):
+            with setup_span(phase):
+                return fn(*args, **kwargs)
+        return spanned
+    return wrap
+
+
+def setup_summary(data: dict | None = None) -> dict:
+    """{leaf: (seconds, bytes)} of the set-up leaves recorded so far in
+    this process (seconds from the histograms' sums; bytes 0 where the
+    spans named none)."""
+    hists = phase_hists(data)
+    return {
+        name: (hists[name]["sum_us"] / 1e6, _setup_bytes.get(name, 0))
+        for name in SETUP_PHASES
+        if name in hists and hists[name]["count"]
+    }
 
 
 def job_tick(job: str, end: bool = False) -> None:
@@ -481,7 +637,8 @@ _HIST_FAMILIES = {
     "phase": ("eg_step_phase_us",
               "Training step-phase wall time (input_stall/sample/h2d/"
               "device/host/step and the training thread's leaves, plus "
-              "XLA compile and journalled stall excess), microseconds",
+              "jit trace/lower/XLA compile, journalled stall excess and "
+              "the set-up leaves setup_*), microseconds",
               "phase"),
     "prefetch_depth": ("eg_prefetch_queue_depth",
                        "Ready batches in the prefetch queue at consumer "
@@ -547,6 +704,11 @@ _RESOURCE_FAMILIES = {
                                  "Lanes a stored row of a store takes in "
                                  "device memory; 0 = the device keeps the "
                                  "store column-major (rows not contiguous)"),
+    "step_temp_bytes": ("eg_step_temp_bytes",
+                        "Temporaries of the compiled train step beside its "
+                        "arguments and results (memory_analysis; set by a "
+                        "profiled run, 0 otherwise): memory_stats() does "
+                        "not count them"),
 }
 
 

@@ -36,7 +36,6 @@ import json
 import os
 import re
 import threading
-import time
 from collections import deque
 
 from euler_tpu import telemetry as _telemetry
@@ -84,10 +83,9 @@ STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
 STEP_HLO_FILE = "train_step.hlo.txt"
 
 
-def now_us() -> int:
-    """CLOCK_MONOTONIC µs — the exporter's one clock (matches the
-    native spans' steady_clock end_us stamps)."""
-    return time.monotonic_ns() // 1000
+# CLOCK_MONOTONIC µs — the exporter's one clock (matches the native
+# spans' steady_clock end_us stamps)
+now_us = _telemetry.now_us
 
 
 class TraceRecorder:
@@ -100,7 +98,9 @@ class TraceRecorder:
     the caller's ``end_us`` stamp (CLOCK_MONOTONIC µs, read where the
     span was measured), or now when the caller gave none. Events are
     (phase, start_us, dur_us, step, thread) tuples; a leaf's parent is
-    ``telemetry.PHASE_PARENT[phase]``. The buffer is a ring:
+    ``telemetry.PHASE_PARENT[phase]``; a span outside the loop (set-up,
+    the compile listener's) carries, in the step's place, a dict of what
+    it worked on or None. The buffer is a ring:
     beyond ``capacity`` events the oldest fall off (``dropped`` counts
     them) — a week-long run cannot OOM the trainer."""
 
@@ -147,7 +147,9 @@ def _phase_trace_events(phase_events: list) -> list:
             "name": phase, "cat": "phase", "ph": "X",
             "ts": ts, "dur": dur, "pid": PID_TRAIN, "tid": tid,
         }
-        if step is not None:
+        if isinstance(step, dict):
+            ev["args"] = step
+        elif step is not None:
             ev["args"] = {"step": step}
         out.append(ev)
     for thread_name, tid in tids.items():

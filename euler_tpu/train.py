@@ -97,14 +97,28 @@ def write_step_hlo(step_fn, state, batch, profile_dir: str) -> None:
     ``op_name`` it was traced under, which is how a reader of the capture
     gets from a device op (the capture names it by its instruction) to
     its ``trace.STEP_SCOPES`` scope. A compile-cache hit: the profiled
-    run's first step compiled this program under the same key."""
+    run's first step compiled this program under the same key. The same
+    compiled step's ``memory_analysis()`` sets the temporaries gauge and
+    one route-log line (an unprofiled run lowers and compiles nothing
+    for either)."""
+    from euler_tpu import devprof
     from euler_tpu.trace import STEP_HLO_FILE
 
     with _cache_keyed_with_metadata():
-        text = step_fn.lower(state, batch).compile().as_text()
+        compiled = step_fn.lower(state, batch).compile()
     os.makedirs(profile_dir, exist_ok=True)
     with open(os.path.join(profile_dir, STEP_HLO_FILE), "w") as f:
-        f.write(text)
+        f.write(compiled.as_text())
+    # what the step needs beside its arguments and results: the gauge
+    # `step_temp_bytes` (memory_stats() does not count it)
+    sizes = devprof.record_step_memory(compiled)
+    if sizes:
+        log.info(
+            "train step memory: temporaries %.3f GB, arguments %.3f GB "
+            "(%.3f GB of them aliased to results), results %.3f GB",
+            *(sizes[k] / 1e9
+              for k in ("temp", "argument", "alias", "output")),
+        )
 
 
 def _kernel_mesh_scoped(fn):
@@ -225,37 +239,48 @@ def train(
     # bounds queued-buffer memory.
     sync_every = 1 if cpu_virtual_mesh else 32
     opt = get_optimizer(optimizer, learning_rate)
-    if state is None:
-        state = model.init_state(
-            jax.random.PRNGKey(seed), graph, source_fn(0), opt
-        )
-    rep = replicated_sharding(mesh)
-    # Params/opt replicated; per-node tables row-sharded over the mesh's
-    # 'model' axis when present (pure DP: everything replicated); the
-    # Scalable* stores also carry their pinned rows-major device layout
-    # from here through the step's input and output. The state is the
-    # step's to donate, so a store that had to be re-laid is not kept.
-    state = pad_tables_for_mesh(state, mesh)
-    shardings = state_sharding(mesh, state)
-    state = put_global(state, shardings, consume=True)
-    model.describe_state(state)
+    from euler_tpu import devprof, telemetry
 
-    ckpt = None
-    start_step = 0
-    if checkpoint_dir:
-        from euler_tpu.checkpoint import Checkpointer
-
-        ckpt = Checkpointer(checkpoint_dir)
-        latest = ckpt.latest_step()
-        if latest is not None:
-            state = ckpt.restore(state, latest)
-            state = put_global(state, shardings, consume=True)
-            start_step = latest
-            (log_fn or log.info)(
-                f"resumed from {checkpoint_dir} at step {latest}"
+    if phase_profile is None:
+        try:
+            phase_profile = telemetry.telemetry_enabled()
+        except Exception:
+            phase_profile = False
+    # Set-up, up to the loop: what of it no span inside claims (the
+    # tables' export, slabs and upload where init_state builds them, the
+    # listener's trace / lower / compile) is this span's own time.
+    with telemetry.setup_span("setup_state_place", on=phase_profile):
+        if state is None:
+            state = model.init_state(
+                jax.random.PRNGKey(seed), graph, source_fn(0), opt
             )
-        if checkpoint_every <= 0:
-            checkpoint_every = max(num_steps // 10, 1)
+        rep = replicated_sharding(mesh)
+        # Params/opt replicated; per-node tables row-sharded over the mesh's
+        # 'model' axis when present (pure DP: everything replicated); the
+        # Scalable* stores also carry their pinned rows-major device layout
+        # from here through the step's input and output. The state is the
+        # step's to donate, so a store that had to be re-laid is not kept.
+        state = pad_tables_for_mesh(state, mesh)
+        shardings = state_sharding(mesh, state)
+        state = put_global(state, shardings, consume=True)
+        model.describe_state(state)
+
+        ckpt = None
+        start_step = 0
+        if checkpoint_dir:
+            from euler_tpu.checkpoint import Checkpointer
+
+            ckpt = Checkpointer(checkpoint_dir)
+            latest = ckpt.latest_step()
+            if latest is not None:
+                state = ckpt.restore(state, latest)
+                state = put_global(state, shardings, consume=True)
+                start_step = latest
+                (log_fn or log.info)(
+                    f"resumed from {checkpoint_dir} at step {latest}"
+                )
+            if checkpoint_every <= 0:
+                checkpoint_every = max(num_steps // 10, 1)
     step_fn = jax.jit(
         model.make_train_step(opt),
         in_shardings=(shardings, batch_sharding(mesh)),
@@ -263,15 +288,6 @@ def train(
         donate_argnums=(0,),
     )
 
-    from euler_tpu import devprof
-
-    if phase_profile is None:
-        from euler_tpu.telemetry import telemetry_enabled
-
-        try:
-            phase_profile = telemetry_enabled()
-        except Exception:
-            phase_profile = False
     stall_out = journal = None
     if phase_profile:
         from euler_tpu.telemetry import (
@@ -497,14 +513,10 @@ def train(
             else:
                 state, last_loss, metric = step_fn(state, batch)
             if cur == start_step and devprof.devprof_enabled():
-                # Relaunch-cost visibility: with the persistent compile
-                # cache warm this line drops to ~0 ms on the second launch.
-                cs = devprof.compile_summary()
+                # Relaunch-cost visibility: what set-up was made of, and
+                # the compiles (warm cache: ~0 ms on the second launch)
                 (log_fn or log.info)(
-                    f"first step dispatched: {cs['compile_events']} XLA "
-                    f"compile(s), {cs['compile_ms_total']:.0f} ms compile "
-                    "time"
-                )
+                    devprof.first_step_line(step_fn.__name__))
             if phase_profile:
                 mark = t_host = leaf("dispatch", mark, cur, leaves)
             # the window's values start for the host as they are produced,

@@ -72,6 +72,31 @@ def test_phase_names_pin_the_native_enum_order():
         assert hists[name]["sum_us"] == 10 * (i + 1), name
 
 
+def test_phase_names_are_the_native_table_letter_for_letter():
+    """PHASES against eg_phase.h itself: the names of ``kPhaseNames`` in
+    order, one per enumerator up to ``kPhaseCount``; the set-up leaves are
+    the ``setup_*`` of them, all under the parent ``setup``."""
+    import os
+    import re
+
+    header = os.path.join(
+        os.path.dirname(os.path.abspath(native.__file__)), "_native",
+        "eg_phase.h")
+    with open(header) as f:
+        text = f.read()
+    table = re.search(
+        r"kPhaseNames\[kPhaseCount\] = \{(.*?)\};", text, re.DOTALL)
+    assert tuple(re.findall(r'"([a-z0-9_]+)"', table.group(1))) == T.PHASES
+    enum = re.search(r"enum StepPhase : int \{(.*?)\};", text, re.DOTALL)
+    members = re.findall(r"^\s*(kPhase[A-Za-z0-9]+)", enum.group(1),
+                         re.MULTILINE)
+    assert members[-1] == "kPhaseCount"
+    assert len(members) - 1 == len(T.PHASES)
+    assert T.SETUP_PHASES == T.PHASES[-len(T.SETUP_PHASES):]
+    assert all(T.PHASE_PARENT[leaf] == "setup" for leaf in T.SETUP_PHASES)
+    assert {"trace", "lower", "compile"} <= set(T.PHASES)
+
+
 def test_record_phase_exact_bucket_and_reset():
     T.record_phase("input_stall", 25_000)
     h = T.phase_hists()["input_stall"]
