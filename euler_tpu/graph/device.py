@@ -973,9 +973,11 @@ def multi_hop_neighbor(adjs, roots, node_caps):
     truncation leaves inverse indices unspecified, so rank is computed
     explicitly), and emit the same padded COO the host path produces —
     {"nodes": [cap] (default-padded, sorted like np.unique),
-    "src"/"dst": [C*W] indices into the current/next hop arrays,
-    "mask": [C*W] 1.0 on real edges, "w": alias of mask (the sparse
-    aggregators use binary adjacency)}.
+    "src"/"dst": [C*W] indices into the current/next hop arrays ("src" is
+    repeat(arange(C), W): a numpy constant of the static shapes, by which
+    nn/sparse_aggregators.py knows the list for regular and sums its rows
+    in place of a segment sum), "mask": [C*W] 1.0 on real edges, "w":
+    alias of mask (the sparse aggregators use binary adjacency)}.
 
     Divergences from the host path, both graceful where the host raises:
     rows beyond the slab's max_degree were already truncated to their
@@ -1013,7 +1015,9 @@ def multi_hop_neighbor(adjs, roots, node_caps):
             .at[rank_sorted]
             .set(s.astype(jnp.int32), mode="drop")
         )
-        src = jnp.repeat(jnp.arange(C, dtype=jnp.int32), W)
+        # a constant of the static shapes, not a traced repeat: the
+        # sparse aggregators see a regular list in it and sum its rows
+        src = np.repeat(np.arange(C, dtype=np.int32), W)
         dst = jnp.clip(rank, 0, cap - 1).astype(jnp.int32)
         mask = (
             valid.reshape(-1)

@@ -6,7 +6,9 @@ ScalableGCN :47 + the session-run-hook store machinery) and encoders.py
 
 TPU adaptations:
 - Full-neighbor expansion pads to static per-hop node/edge caps
-  (ragged -> fixed shapes); aggregation is segment_sum.
+  (ragged -> fixed shapes); aggregation is a row sum over the device
+  expansion's regular edge list, segment_sum over any other
+  (nn/sparse_aggregators.py).
 - ScalableGCN's embedding/gradient stores are device arrays carried in the
   train state, and the reference's three session hooks (update_store,
   update_gradient, optimize_store) plus the auxiliary store Adam all fuse
@@ -463,7 +465,7 @@ class ScalableGCN(base.ScalableStoreModel):
     def _expand_batch(self, batch, consts):
         """Device full-neighbor expansion: the adjacency slab row IS the
         1-hop neighborhood (padded to W, masked by degree) — no host
-        dedup; duplicate neighbor slots scatter-add like duplicate edges.
+        dedup; duplicate neighbor slots add up like duplicate edges.
         """
         if "roots" not in batch:
             return batch
@@ -478,7 +480,9 @@ class ScalableGCN(base.ScalableStoreModel):
         ).astype(jnp.float32)
         flat = nbrs.reshape(-1)
         adj = {
-            "src": jnp.repeat(jnp.arange(B, dtype=jnp.int32), W),
+            # a constant of the static shapes: a regular list, whose rows
+            # the sparse aggregators sum (nn/sparse_aggregators.py)
+            "src": np.repeat(np.arange(B, dtype=np.int32), W),
             "dst": jnp.arange(B * W, dtype=jnp.int32),
             "mask": mask.reshape(-1),
         }

@@ -3,9 +3,19 @@
 Reference equivalent: tf_euler/python/sparse_aggregators.py:20-146, which
 uses tf.SparseTensor matmul/softmax. Here the adjacency is the padded COO
 from ops.get_multi_hop_neighbor (adj_src/adj_dst index the current/next hop
-node arrays) and aggregation is jax.ops.segment_sum with static segment
-counts — the XLA-native form of sparse x dense. Padding edges carry
-edge_mask 0 and contribute nothing.
+node arrays). Padding edges carry edge_mask 0 and contribute nothing.
+
+The degree and the sum of an edge list take one of two forms, chosen by
+what ``src`` is seen to be while tracing (``_row_width``). A REGULAR
+list, whose ``src`` is a constant equal to ``repeat(arange(n), W)`` (the
+device expansions': graph/device.py ``multi_hop_neighbor``, models/gcn.py
+``ScalableGCN._expand_batch``), is ``[n, W]`` rows as it lies, and is
+reduced along its row axis: ``mask.reshape(n, W).sum(1)``,
+``messages.reshape(n, W, F).sum(1)``. Every other list (a host-expanded
+batch's compacted COO, which arrives as a jit argument; any irregular
+``src``) keeps jax.ops.segment_sum with static segment counts, the
+XLA-native form of sparse x dense. The form taken is said while tracing,
+once a shape and outcome ("aggregate path: ...", OBSERVABILITY.md).
 
 The work over the edge list (the gather by ``dst``, the mask, the degree,
 the segment sum, the division) carries the ``segment_agg`` named scope
@@ -26,21 +36,67 @@ rows before its gather by ``dst`` and keeps the hop's rows.
 
 from __future__ import annotations
 
+import functools
+import logging
 from typing import Callable, NamedTuple, Optional
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from euler_tpu.nn.layers import Dense
 
+log = logging.getLogger("euler_tpu")
+
+
+@functools.lru_cache(maxsize=64)
+def _log_aggregate_route(slots: int, route: str) -> None:
+    """One line per distinct shape and outcome, said while tracing (as
+    models/gcn.py says its message path and graph/device.py its draw and
+    expand paths): the form an edge list's degree and sum take."""
+    log.info("aggregate path: %d slots -> %s", slots, route)
+
+
+def _row_width(adj_src, num_nodes):
+    """W where ``adj_src`` is a CONCRETE array (a constant of the trace,
+    not a tracer) of ``num_nodes * W`` entries equal to
+    ``repeat(arange(num_nodes), W)``: slot ``i`` then belongs to row
+    ``i // W`` and the list is ``[num_nodes, W]`` as it lies. Else None,
+    and the list keeps the segment sum."""
+    slots = adj_src.shape[0]
+    width = None
+    if isinstance(adj_src, jax.core.Tracer):
+        why = "traced src"
+    else:
+        why = "src not repeat(arange)"
+        if num_nodes and slots % num_nodes == 0:
+            rows = np.asarray(adj_src).reshape(
+                num_nodes, slots // num_nodes)
+            if (rows == np.arange(num_nodes)[:, None]).all():
+                width = rows.shape[1]
+    _log_aggregate_route(
+        slots,
+        f"segment sum ({why})" if width is None
+        else f"row sum over {width}",
+    )
+    return width
+
 
 def _degree(adj_src, edge_mask, num_nodes):
-    return jax.ops.segment_sum(edge_mask, adj_src, num_segments=num_nodes)
+    width = _row_width(adj_src, num_nodes)
+    if width is None:
+        return jax.ops.segment_sum(
+            edge_mask, adj_src, num_segments=num_nodes)
+    return edge_mask.reshape(num_nodes, width).sum(1)
 
 
 def _gather_sum(values, adj_src, num_nodes):
-    return jax.ops.segment_sum(values, adj_src, num_segments=num_nodes)
+    width = _row_width(adj_src, num_nodes)
+    if width is None:
+        return jax.ops.segment_sum(
+            values, adj_src, num_segments=num_nodes)
+    return values.reshape((num_nodes, width) + values.shape[1:]).sum(1)
 
 
 class SlotRows(NamedTuple):

@@ -190,16 +190,28 @@ def test_one_pass_messages_equal_the_plain_form_to_the_bit(
     (dict(use_residual=True), "use_residual"),
     (dict(aggregator="attention"), "attention aggregator"),
 ], ids=["host_rows", "host_expanded", "use_id", "use_residual", "attention"])
-def test_other_configurations_keep_the_hops_own_rows(graph, kw, why):
+def test_other_configurations_keep_the_hops_own_rows(graph, kw, why, caplog):
     """What must keep today's path does: it says why, its loss and
-    gradients are the plain form's to the bit, and a step trains."""
+    gradients are the plain form's to the bit, and a step trains. A
+    host-expanded batch's edge lists arrive as arguments of the jitted
+    step, so they keep the segment sum and say so."""
+    import logging
+
     m = _gcn(**kw)
     opt, state, batch = _state_batch(m, graph)
     assert why in m.module._hop_rows_why(batch, state.get("consts"))
     # a head's gate on an all-alike softmax has a gradient of nought
     attention = kw.get("aggregator") == "attention"
     _assert_same_to_the_bit(m, state, batch, every_leaf_moves=not attention)
-    new, loss, _ = jax.jit(m.make_train_step(opt))(state, batch)
+    sparse_aggregators._log_aggregate_route.cache_clear()
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        new, loss, _ = jax.jit(m.make_train_step(opt))(state, batch)
+    said = _routes(caplog)
+    if "hops" in batch:
+        assert said and all(
+            s.endswith("segment sum (traced src)") for s in said)
+    elif not attention:
+        assert said and all("row sum over" in s for s in said)
     assert np.isfinite(float(loss))
     moved = jax.tree.map(
         lambda a, b: bool(np.any(np.asarray(a) != np.asarray(b))),
@@ -211,7 +223,7 @@ def test_other_configurations_keep_the_hops_own_rows(graph, kw, why):
 def test_scalable_gcn_trains_a_step_on_its_own_path(graph, caplog):
     """ScalableGCN's module never sees a hop's set: its ``dst`` is
     ``arange`` and its neighbour rows are gathered by the slot's id
-    already, so it takes no route and says none."""
+    already, so it takes no message route and says none."""
     import logging
 
     from euler_tpu import train as train_lib
@@ -225,9 +237,12 @@ def test_scalable_gcn_trains_a_step_on_its_own_path(graph, caplog):
     opt = train_lib.get_optimizer("adam", 0.01)
     roots = graph.sample_node(8, -1)
     state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    sparse_aggregators._log_aggregate_route.cache_clear()
     with caplog.at_level(logging.INFO, logger="euler_tpu"):
         out = jax.jit(m.make_train_step(opt))(state, m.sample(graph, roots))
     new, loss = out[0], out[1]
+    # its device expansion is a regular list too: 8 roots x 4 slab slots
+    assert _routes(caplog) == ["aggregate path: 32 slots -> row sum over 4"]
     assert np.isfinite(float(loss))
     assert any(
         np.any(np.asarray(a) != np.asarray(b)) for a, b in zip(
@@ -295,3 +310,144 @@ def test_message_path_is_said_once_a_shape_in_both_forms(graph, caplog):
         "(use_residual: the rows are projected)"
         for h, n in ((1, 8), (2, 24))
     ]
+
+
+# ---------------------------------------------------------------------------
+# A regular edge list (a constant src = repeat(arange(n), W)) is summed
+# along its rows; every other list keeps the segment sum
+# (sparse_aggregators._row_width, OBSERVABILITY.md "aggregate path")
+# ---------------------------------------------------------------------------
+
+
+def _regular_list(n, W, m, F=6, seed=5):
+    """(self rows, the hop's rows, adjacency) of a regular list: n rows
+    of W slots into a hop of m nodes, some slots masked, row 1 masked
+    whole."""
+    rng = np.random.default_rng(seed)
+    mask = (rng.random((n, W)) < 0.7).astype(np.float32)
+    mask[1] = 0.0
+    mask = mask.reshape(-1)
+    adj = {
+        "src": np.repeat(np.arange(n, dtype=np.int32), W),
+        "dst": rng.integers(0, m, n * W).astype(np.int32),
+        "mask": mask, "w": mask,
+    }
+    self_emb = jnp.asarray(rng.normal(size=(n, F)), jnp.float32)
+    neigh_emb = jnp.asarray(rng.normal(size=(m, F)), jnp.float32)
+    return self_emb, neigh_emb, adj
+
+
+def _routes(caplog):
+    return [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("aggregate path:")]
+
+
+def _said_while(caplog, fn, *args):
+    """fn(*args), and the aggregate-path lines it said."""
+    import logging
+
+    sparse_aggregators._log_aggregate_route.cache_clear()
+    caplog.clear()
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        out = fn(*args)
+    return out, _routes(caplog)
+
+
+@pytest.mark.parametrize("jitted", [True, False], ids=["jit", "eager"])
+@pytest.mark.parametrize("W", [5, 56])
+@pytest.mark.parametrize("slot_rows", [True, False],
+                         ids=["SlotRows", "hop_rows"])
+@pytest.mark.parametrize("aggregator", ["mean", "gcn"])
+def test_row_sum_of_a_regular_list_equals_the_segment_sum(
+        aggregator, slot_rows, W, jitted, caplog):
+    """The same masked mean by both forms: the output, the weights'
+    gradients and (for the hop's rows) the neighbour input's are the
+    segment sum's within float32 rounding, masked slots and an all-masked
+    row among them; and each form says it was taken."""
+    n, m = 7, 11
+    self_emb, neigh_emb, adj = _regular_list(n, W, m)
+    agg = sparse_aggregators.get(aggregator)(dim=4)
+    params = agg.init(jax.random.PRNGKey(0), (self_emb, neigh_emb, adj))
+
+    def value(params, neigh_emb, src):
+        a = dict(adj, src=src)
+        neigh = neigh_emb
+        if slot_rows:
+            neigh = sparse_aggregators.SlotRows(neigh_emb[a["dst"]])
+        out = agg.apply(params, (self_emb, neigh, a))
+        return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(
+            out.shape))), out
+
+    grad = jax.value_and_grad(value, argnums=(0, 1), has_aux=True)
+    # the regular form: src stays the constant it is, closed over
+    row = lambda p, x: grad(p, x, adj["src"])
+    ((_, out), (g_w, g_x)), said = _said_while(
+        caplog, jax.jit(row) if jitted else row, params, neigh_emb)
+    assert said == [f"aggregate path: {n * W} slots -> row sum over {W}"]
+    # the segment form: src arrives as an argument of the jitted program
+    ((_, want), (w_w, w_x)), said = _said_while(
+        caplog, jax.jit(grad), params, neigh_emb, adj["src"])
+    assert said == [
+        f"aggregate path: {n * W} slots -> segment sum (traced src)"]
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    assert np.abs(np.asarray(out)).sum() > 0
+    for g, w in zip(jax.tree.leaves(g_w), jax.tree.leaves(w_w)):
+        assert np.abs(np.asarray(w)).sum() > 0
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(g_x, w_x, rtol=1e-5, atol=1e-5)
+
+
+def _permuted(src, n, W):
+    return np.random.default_rng(1).permutation(src)
+
+
+def _rows_interleaved(src, n, W):
+    # the right length and every row W times, slot i in row i % n
+    return np.tile(np.arange(n, dtype=np.int32), W)
+
+
+def _as_it_is(src, n, W):
+    return src
+
+
+def _permuted_on_device(src, n, W):
+    return jnp.asarray(_permuted(src, n, W))
+
+
+@pytest.mark.parametrize("src_of, traced, jitted", [
+    (_as_it_is, True, True),
+    (_permuted, False, True), (_permuted, False, False),
+    (_rows_interleaved, False, True), (_rows_interleaved, False, False),
+    (_permuted_on_device, False, True), (_permuted_on_device, False, False),
+], ids=["traced-jit", "permutation-jit", "permutation-eager",
+        "wrong_order-jit", "wrong_order-eager", "device_array-jit",
+        "device_array-eager"])
+def test_any_other_list_keeps_the_segment_sum(
+        src_of, traced, jitted, caplog):
+    """A src the trace cannot see (an argument of the jitted program; an
+    eager call has none), or sees to be irregular, is not summed by rows:
+    the route line says segment sum and why, and the mean is the list's
+    own (a row sum would have given another)."""
+    why = "traced src" if traced else "src not repeat(arange)"
+    n, W, m = 7, 5, 11
+    self_emb, neigh_emb, adj = _regular_list(n, W, m)
+    src = src_of(adj["src"], n, W)
+    agg = sparse_aggregators.MeanAggregator(dim=4, activation=None)
+    params = agg.init(jax.random.PRNGKey(0), (self_emb, neigh_emb, adj))
+    if traced:
+        fn = lambda p, s: agg.apply(
+            p, (self_emb, neigh_emb, dict(adj, src=s)))
+        args = (params, src)
+    else:
+        fn = lambda p: agg.apply(
+            p, (self_emb, neigh_emb, dict(adj, src=src)))
+        args = (params,)
+    out, said = _said_while(caplog, jax.jit(fn) if jitted else fn, *args)
+    assert said == [f"aggregate path: {n * W} slots -> segment sum ({why})"]
+    msgs = neigh_emb[adj["dst"]] * adj["mask"][:, None]
+    deg = jax.ops.segment_sum(adj["mask"], src, num_segments=n)
+    mean = jax.ops.segment_sum(msgs, src, num_segments=n) / (
+        jnp.maximum(deg, 1e-7)[:, None])
+    kernels = jax.tree.leaves(params)
+    want = self_emb @ kernels[0] + mean @ kernels[1]
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)

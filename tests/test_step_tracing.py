@@ -142,8 +142,10 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
     """The full-neighbourhood family's step: all of the device expansion
     (slab-row gathers, the sort, the scatters) under ``expand``, the
     sparse aggregator's work over the edge list (the composition of the
-    slots' ids ``nodes[dst]``, the mask, the segment sums, forward and
-    transposed; layer 1's gather by ``dst``) under ``segment_agg``, its
+    slots' ids ``nodes[dst]``, the mask, the degree and the sum as
+    reductions along the rows of a regular list; layer 1's gather by
+    ``dst``, whose transpose is the one scatter left) under
+    ``segment_agg``, its
     matmuls under ``dense``, and under ``gather_features`` the rows: the
     roots' and hop 1's sets, and layer 0's messages of both hops, read
     from the stored table one a slot."""
@@ -164,7 +166,19 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
     # the rank's scatter and the set's: every product of the expansion
     # stays a product of the step
     assert sum("/expand/scatter" in ln for ln in lines) >= 2
-    assert any("/segment_agg/" in ln and "scatter" in ln for ln in lines)
+    # the device expansion's list is regular: its degree and its sum are
+    # row reductions, forward (the embed program holds no scatter under
+    # segment_agg at all); the training step keeps one scatter there, the
+    # transposed gather by ``dst`` of layer 1
+    assert any("/segment_agg/reduce_sum" in ln for ln in lines)
+    scatters = [ln for ln in lines
+                if "/segment_agg/" in ln and "scatter" in ln]
+    assert scatters and all("transpose(" in ln for ln in scatters)
+    embed = jax.jit(m.make_embed_step()).lower(
+        state, m.sample(graph, roots)).as_text(debug_info=True)
+    assert "/segment_agg/reduce_sum" in embed
+    assert not any("/segment_agg/" in ln and "scatter" in ln
+                   for ln in embed.splitlines())
     assert any("/segment_agg/" in ln and "gather" in ln for ln in lines)
     assert any("/gather_features/" in ln and "gather" in ln for ln in lines)
     assert not any("/segment_agg/" in ln and "dot_general" in ln
