@@ -112,9 +112,9 @@ class _SupervisedGCNModule(nn.Module):
         """Why layer 0's messages have to be read out of the hops' own
         rows, or None where a hop's rows are nothing but rows of the
         device-resident feature table (a device expansion's ``gids``, a
-        node encoder that is the identity on the dense rows) and the
-        aggregator takes them a slot: then ``_forward`` gathers the
-        messages from the stored table in one pass."""
+        node encoder that is the identity on the dense rows): every
+        aggregator takes them a slot (``SlotRows``), and ``_forward``
+        gathers the messages from the stored table in one pass."""
         if "hops" in batch:
             return "host-expanded batch"
         if not consts or "features" not in consts:
@@ -125,9 +125,6 @@ class _SupervisedGCNModule(nn.Module):
             return "sparse features beside the rows"
         if self.use_residual:
             return "use_residual: the rows are projected"
-        if not sparse_aggregators.get(self.aggregator).reads_slot_rows:
-            return (f"{self.aggregator} aggregator: it projects the "
-                    "hop's rows before the gather")
         return None
 
     def _forward(self, batch, consts):

@@ -17,21 +17,30 @@ batch's compacted COO, which arrives as a jit argument; any irregular
 XLA-native form of sparse x dense. The form taken is said while tracing,
 once a shape and outcome ("aggregate path: ...", OBSERVABILITY.md).
 
+The attention aggregator's edge softmax takes the same two forms by the
+same test: the max and the sums of a regular list go along its rows
+(``_gather_max`` beside ``_gather_sum``; a node's own logit term is a
+broadcast along the row, ``_spread``), any other list keeps
+jax.ops.segment_max and segment_sum ("attention path: ...").
+
 The work over the edge list (the gather by ``dst``, the mask, the degree,
 the segment sum, the division) carries the ``segment_agg`` named scope
-(trace.STEP_SCOPES); the matmuls stay under ``dense`` (nn/layers.py).
+(trace.STEP_SCOPES); the attention's (logits, max, exp, sum, the
+weighting of the messages and the normalisation) the ``edge_softmax``
+scope; the matmuls, a head's gates among them, stay under ``dense``
+(nn/layers.py).
 
 An aggregator's neighbour input is the hop's rows ``[m, F]``, which it
 reads through ``dst``, or ``SlotRows``: rows that already lie one a slot
-of the edge list. ``GCNAggregator`` and ``MeanAggregator`` take either
-(``reads_slot_rows``). Layer 0 of the device-expanded full-neighbourhood
-step hands them ``SlotRows`` where a hop's rows are rows of the
-device-resident feature table (models/gcn.py ``_forward``): the slots'
-node ids ``nodes[dst]`` are composed here (``slot_ids``, under
-``segment_agg``), the rows are gathered from the stored table by those
-ids in one pass under ``gather_features``, and the hop's set rows are
-not gathered for the messages' sake at all. Attention projects the hop's
-rows before its gather by ``dst`` and keeps the hop's rows.
+of the edge list. Every aggregator takes either. Layer 0 of the
+device-expanded full-neighbourhood step hands them ``SlotRows`` where a
+hop's rows are rows of the device-resident feature table (models/gcn.py
+``_forward``): the slots' node ids ``nodes[dst]`` are composed here
+(``slot_ids``, under ``segment_agg``), the rows are gathered from the
+stored table by those ids in one pass under ``gather_features``, and the
+hop's set rows are not gathered for the messages' sake at all. Attention
+projects a slot's row where it lies (the same dot product as projecting
+the hop's set and gathering after).
 """
 
 from __future__ import annotations
@@ -136,7 +145,6 @@ class GCNAggregator(nn.Module):
     dim: int
     activation: Optional[Callable] = nn.relu
     renorm: bool = False
-    reads_slot_rows = True
 
     @nn.compact
     def __call__(self, inputs):
@@ -157,7 +165,6 @@ class MeanAggregator(nn.Module):
     dim: int
     activation: Optional[Callable] = nn.relu
     concat: bool = False
-    reads_slot_rows = True
 
     @nn.compact
     def __call__(self, inputs):
@@ -176,84 +183,207 @@ class MeanAggregator(nn.Module):
         return from_self + from_neigh
 
 
-@jax.named_scope("segment_agg")
+def _gather_max(values, adj_src, num_nodes):
+    width = _row_width(adj_src, num_nodes)
+    if width is None:
+        return jax.ops.segment_max(
+            values, adj_src, num_segments=num_nodes)
+    return values.reshape((num_nodes, width) + values.shape[1:]).max(1)
+
+
+def _spread(per_node, adj_src, num_nodes):
+    """``per_node[adj_src]``: on a regular list a broadcast along the
+    row, not a gather."""
+    width = _row_width(adj_src, num_nodes)
+    if width is None:
+        return per_node[adj_src]
+    return jnp.repeat(per_node, width, axis=0)
+
+
+@functools.lru_cache(maxsize=64)
+def _log_attention_route(slots: int, heads: int, route: str) -> None:
+    """One line per distinct shape and outcome, said while tracing: the
+    form the edge softmax of an edge list takes (the same choice as the
+    sum's, said beside its ``aggregate path:`` line)."""
+    log.info("attention path: %d slots x %d heads -> %s", slots, heads,
+             route)
+
+
+def _edge_weights(logits, segments, num_segments, mask, self_logits=None):
+    """(e, denom): the softmax of the edge logits within each src
+    segment, unnormalised. ``e`` [slots, ...] is exp(logit - the
+    segment's max), nought on a masked slot; ``denom`` [n, ...] its sum
+    over the segment. ``self_logits`` [n, ...] adds one virtual edge a
+    node to the softmax's support: returns its ``e`` as a third. The max
+    and the sum go along the rows of a regular list, by segment over any
+    other (``_gather_max``, ``_gather_sum``). The max is a constant of
+    the softmax (a shift of all of a node's logits changes nothing), so
+    no gradient is sent through it."""
+    heads = int(np.prod(logits.shape[1:], dtype=np.int64))
+    width = _row_width(segments, num_segments)
+    _log_attention_route(
+        logits.shape[0], heads,
+        "segment softmax" if width is None else f"row softmax over {width}")
+    live = mask.reshape(mask.shape + (1,) * (logits.ndim - 1)) > 0
+    masked = jnp.where(live, logits, jnp.finfo(logits.dtype).min)
+    top = _gather_max(masked, segments, num_segments)
+    if self_logits is not None:
+        top = jnp.maximum(top, self_logits)
+    # a segment no slot names has a max of -inf
+    top = jax.lax.stop_gradient(jnp.where(jnp.isfinite(top), top, 0.0))
+    e = jnp.where(
+        live, jnp.exp(masked - _spread(top, segments, num_segments)), 0.0)
+    denom = _gather_sum(e, segments, num_segments)
+    if self_logits is None:
+        return e, denom
+    e_self = jnp.exp(self_logits - top)
+    return e, denom + e_self, e_self
+
+
+@jax.named_scope("edge_softmax")
 def segment_softmax(logits, segments, num_segments, mask):
     """Numerically-stable softmax of edge logits within each src segment.
     Masked edges get zero probability."""
-    neg = jnp.finfo(logits.dtype).min
-    masked = jnp.where(mask > 0, logits, neg)
-    seg_max = jax.ops.segment_max(masked, segments, num_segments=num_segments)
-    seg_max = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
-    e = jnp.exp(masked - seg_max[segments]) * mask
-    denom = jax.ops.segment_sum(e, segments, num_segments=num_segments)
-    return e / jnp.maximum(denom[segments], 1e-16)
+    e, denom = _edge_weights(logits, segments, num_segments, mask)
+    return e / jnp.maximum(_spread(denom, segments, num_segments), 1e-16)
+
+
+class _Kernel(nn.Module):
+    """The kernel of a bias-free ``Dense(dim)`` as a value, at the tree
+    path that layer keeps it at (``<name>/Dense_0/kernel``) and with its
+    initialiser: the attention heads' parameters keep the tree they had
+    as four modules of three Dense layers each, while their kernels are
+    laid side by side into one projection."""
+
+    dim: int
+    leaf: bool = False
+
+    @nn.compact
+    def __call__(self, fan_in):
+        if not self.leaf:
+            return _Kernel(self.dim, leaf=True, name="Dense_0")(fan_in)
+        return self.param("kernel", nn.initializers.lecun_normal(),
+                          (fan_in, self.dim))
+
+
+# a dot whose operands are not rounded to bfloat16 on the way in
+_exact_dot = functools.partial(jnp.dot, precision="highest")
+
+
+def _head_blocks(per_head):
+    """[K, D] values a head -> the block-diagonal [K * D, K] matrix that
+    holds head ``k``'s values in rows ``k * D .. (k + 1) * D`` of column
+    ``k``: ``x [., K * D] @ blocks`` is every head's own dot product at
+    once, ``y [., K] @ blocks.T`` every head's value along its lanes."""
+    heads, dim = per_head.shape
+    eye = jnp.eye(heads, dtype=per_head.dtype)
+    return (per_head[:, :, None] * eye[:, None, :]).reshape(
+        heads * dim, heads)
+
+
+def _attend(kernels, inputs, activation, renorm):
+    """GAT-style heads over one edge list, side by side [n, K * D].
+    ``kernels``: a head's (W [F, D], self gate u [D], neighbour gate v
+    [D]). One projection [F, K * D] and one pass over the list for all
+    heads. The messages stay [slots, K * D] rows throughout (K * D lanes:
+    a [slots, K, D] view costs a copy of the whole product on the TPU):
+    the gates are matmuls with block-diagonal [K * D, K] kernels, the
+    logits and the softmax [slots, K], and a head's weight reaches its D
+    lanes by the transposed block of ones. Max and sums go along the
+    rows of a regular list, by segment over any other. With renorm, a
+    virtual self-edge is added to each row's softmax, its neighbour-side
+    logit the neighbour gate applied to the self projection (the
+    reference concatenates self rows into the ``all`` set,
+    sparse_aggregators.py:96-101), in place of the self term."""
+    self_emb, neigh_emb, adj = inputs
+    src, mask = adj["src"], adj["mask"]
+    n = self_emb.shape[0]
+    w = jnp.concatenate([k[0] for k in kernels], axis=1)   # [F, K * D]
+    gate_self = _head_blocks(jnp.stack([k[1] for k in kernels]))
+    gate_all = _head_blocks(jnp.stack([k[2] for k in kernels]))
+    slot_rows = isinstance(neigh_emb, SlotRows)
+    with jax.named_scope("dense"):
+        from_self = jnp.dot(self_emb, w)                   # [n, K * D]
+        # a slot's message is W applied to that slot's row: the same dot
+        # product as projecting the hop's set and gathering after
+        from_all = jnp.dot(neigh_emb.rows if slot_rows else neigh_emb, w)
+        # the gates feed a softmax: a 64-wide dot a head, in float32
+        # whatever the platform makes of a default-precision matmul
+        self_w = _exact_dot(from_self, gate_self)          # [n, K]
+        all_w = _exact_dot(from_all, gate_all)             # [slots or m, K]
+        if renorm:
+            own_w = _exact_dot(from_self, gate_all)
+    if not slot_rows:
+        with jax.named_scope("segment_agg"):
+            from_all, all_w = from_all[adj["dst"]], all_w[adj["dst"]]
+    with jax.named_scope("edge_softmax"):
+        ones = _head_blocks(
+            jnp.ones((len(kernels),) + kernels[0][1].shape, w.dtype)).T
+
+        def lanes(per_head):
+            """A head's [., K] value on each of its D lanes, to the bit."""
+            return _exact_dot(per_head, ones)
+
+        logits = nn.leaky_relu(_spread(self_w, src, n) + all_w)
+        if renorm:
+            e, denom, e_self = _edge_weights(
+                logits, src, n, mask, nn.leaky_relu(self_w + own_w))
+            out = _gather_sum(from_all * lanes(e), src, n) \
+                + from_self * lanes(e_self)
+        else:
+            e, denom = _edge_weights(logits, src, n, mask)
+            out = _gather_sum(from_all * lanes(e), src, n)
+        out = out / lanes(jnp.maximum(denom, 1e-16))
+        if not renorm:
+            out = from_self + out
+        if activation is not None:
+            out = activation(out)
+    return out
 
 
 class SingleAttentionAggregator(nn.Module):
     """GAT-style single head over COO adjacency
-    (reference sparse_aggregators.py:84-116). With renorm, a virtual
-    self-edge is added to each row's softmax."""
+    (reference sparse_aggregators.py:84-116): ``_attend`` with one head.
+    Its parameters are the three bias-free Dense kernels of a head (the
+    projection, the self gate, the neighbour gate)."""
 
     dim: int
     activation: Optional[Callable] = nn.relu
     renorm: bool = False
 
     @nn.compact
-    def __call__(self, inputs):
-        self_emb, neigh_emb, adj = inputs
-        src, dst, edge_mask = adj["src"], adj["dst"], adj["mask"]
-        n = self_emb.shape[0]
-        dense = Dense(self.dim, use_bias=False)
-        self_gate = Dense(1, use_bias=False)
-        all_gate = Dense(1, use_bias=False)
-        from_self = dense(self_emb)          # [n, dim]
-        from_all = dense(neigh_emb)          # [m, dim]
-        self_w = self_gate(from_self)[:, 0]  # [n]
-        all_w = all_gate(from_all)[:, 0]     # [m]
+    def kernels(self, fan_in):
+        return (
+            _Kernel(self.dim, name="Dense_0")(fan_in),
+            _Kernel(1, name="Dense_1")(self.dim)[:, 0],
+            _Kernel(1, name="Dense_2")(self.dim)[:, 0],
+        )
 
-        logits = nn.leaky_relu(self_w[src] + all_w[dst])
-        if self.renorm:
-            # Append one self-edge per node to the softmax support; its
-            # "context" logit is the all-gate applied to the self projection
-            # (the reference concatenates self rows into the `all` set,
-            # sparse_aggregators.py:96-101).
-            self_logits = nn.leaky_relu(self_w + all_gate(from_self)[:, 0])
-            ext_logits = jnp.concatenate([logits, self_logits])
-            ext_src = jnp.concatenate([src, jnp.arange(n, dtype=src.dtype)])
-            ext_mask = jnp.concatenate([edge_mask, jnp.ones(n)])
-            coef = segment_softmax(ext_logits, ext_src, n, ext_mask)
-            msgs = jnp.concatenate([from_all[dst], from_self]) * coef[:, None]
-            out = jax.ops.segment_sum(msgs, ext_src, num_segments=n)
-        else:
-            coef = segment_softmax(logits, src, n, edge_mask)
-            msgs = from_all[dst] * coef[:, None]
-            out = jax.ops.segment_sum(msgs, src, num_segments=n)
-            out = from_self + out
-        if self.activation is not None:
-            out = self.activation(out)
-        return out
+    def __call__(self, inputs):
+        return _attend([self.kernels(inputs[0].shape[-1])], inputs,
+                       self.activation, self.renorm)
 
 
 class AttentionAggregator(nn.Module):
-    """Multi-head concat (reference sparse_aggregators.py:119-133)."""
+    """Multi-head concat (reference sparse_aggregators.py:119-133): the
+    heads' kernels (each head's own sub-module's leaves) side by side in
+    one projection and one pass over the edge list (``_attend``)."""
 
     dim: int
     num_heads: int = 4
     activation: Optional[Callable] = nn.relu
     renorm: bool = False
-    # a head projects the hop's rows BEFORE its gather by ``dst``
-    reads_slot_rows = False
 
     @nn.compact
     def __call__(self, inputs):
         head_dim = self.dim // self.num_heads
-        outs = [
+        kernels = [
             SingleAttentionAggregator(
                 head_dim, self.activation, self.renorm
-            )(inputs)
+            ).kernels(inputs[0].shape[-1])
             for _ in range(self.num_heads)
         ]
-        return jnp.concatenate(outs, axis=1)
+        return _attend(kernels, inputs, self.activation, self.renorm)
 
 
 AGGREGATORS = {

@@ -72,10 +72,15 @@ ALIGN_PREFIX = "eg_align:"
 # graph/device.py multi_hop_neighbor (slab-row gathers, the sort, the
 # rank, the two scatters), and the sparse aggregators' work over the
 # padded edge list (nn/sparse_aggregators.py: the gather by ``dst``, the
-# mask, the degree, the segment sum, the division).
+# mask, the degree, the segment sum, the division). ``edge_softmax`` is
+# the attention aggregator's work over that list (the logits, the max,
+# the exp, the sum, the weighting of the messages and the normalisation,
+# forward and transposed; its projections and gates are matmuls under
+# ``dense``); innermost, so inside ``segment_agg`` it is its own.
 STEP_SCOPES = ("draw", "gather_features", "gather_labels", "aggregate",
                "dense", "loss", "optimizer", "stores_read", "stores_write",
-               "walk", "negatives", "pair_rows", "expand", "segment_agg")
+               "walk", "negatives", "pair_rows", "expand", "segment_agg",
+               "edge_softmax")
 
 # File ``train(profile_dir=)`` leaves the compiled step's HLO text in,
 # beside the capture: the map from a trace event's instruction name to

@@ -167,7 +167,11 @@ def train(
 
     step_hook(step) runs on the training thread after every dispatched
     step (run_loop's --metrics_every JSONL emitter rides here; the hook
-    gates itself, so the per-step cost is one call + one modulo).
+    gates itself, so the per-step cost is one call + one modulo). A hook
+    that takes the keywords is fed what the step produced,
+    step_hook(step, state=, batch=, loss=) (looked up once, before the
+    loop: the benchmark's hook reads its first steps there); a hook that
+    returns True ends the loop after that step.
 
     phase_profile records the step-phase histograms (OBSERVABILITY.md
     "Step phases"): input_stall + sample inside the prefetch pipeline,
@@ -459,6 +463,8 @@ def train(
             record_sample=False,  # make_batch above records sample/h2d
             stall_out=stall_out,
         )
+    hook_is_fed = step_hook is not None and _takes_keywords(
+        step_hook, ("state", "batch", "loss"))
     try:
         for batch in batches:
             # With phase_profile the leaves tile this thread's iteration,
@@ -531,9 +537,15 @@ def train(
                 last_loss.copy_to_host_async()
             steps_done += 1
             if step_hook is not None:
-                step_hook(steps_done)
+                if hook_is_fed:
+                    hook_ends = step_hook(
+                        steps_done, state=state, batch=batch, loss=last_loss)
+                else:
+                    hook_ends = step_hook(steps_done)
                 if phase_profile:
                     mark = leaf("hook", mark, cur, leaves)
+                if hook_ends is True:
+                    break
             if profile_dir and cur == start_step:
                 # after the first step's hook, which may read the compile
                 # ledger as of the first dispatch
@@ -588,6 +600,18 @@ def train(
             ckpt.save(steps_done, state, force=True)
         ckpt.close()
     return state, history
+
+
+def _takes_keywords(fn, names) -> bool:
+    """Whether ``fn`` can be called with every one of ``names`` as a
+    keyword."""
+    try:
+        params = inspect.signature(fn).parameters.values()
+    except (TypeError, ValueError):
+        return False
+    return any(p.kind is p.VAR_KEYWORD for p in params) or set(names) <= {
+        p.name for p in params
+        if p.kind in (p.POSITIONAL_OR_KEYWORD, p.KEYWORD_ONLY)}
 
 
 def make_scan_train(model, optimizer, inner_steps: int, batch_size: int):

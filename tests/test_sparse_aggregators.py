@@ -156,17 +156,23 @@ def _plain_loss(m, params, batch, consts):
     return base.supervised_decoder(logits, labels, mod.sigmoid_loss)[0]
 
 
-def _assert_same_to_the_bit(m, state, batch, every_leaf_moves=True):
+def _step_and_plain_form(m, state, batch):
+    """(loss, gradients) of the model's step and of ``_plain_loss``."""
     args = state["params"], batch, state.get("consts")
     got = jax.jit(jax.value_and_grad(
         lambda p, b, c: m._apply(p, b, c).loss))(*args)
     want = jax.jit(jax.value_and_grad(
         lambda p, b, c: _plain_loss(m, p, b, c)))(*args)
+    return got, want
+
+
+def _assert_same_to_the_bit(m, state, batch):
+    got, want = _step_and_plain_form(m, state, batch)
     assert np.asarray(got[0]) == np.asarray(want[0])
     leaves = jax.tree_util.tree_leaves_with_path(got[1])
     assert len(leaves) >= 4
     for (path, g), w in zip(leaves, jax.tree.leaves(want[1])):
-        assert not every_leaf_moves or np.abs(np.asarray(g)).sum() > 0, path
+        assert np.abs(np.asarray(g)).sum() > 0, path
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w), str(path))
 
 
@@ -183,13 +189,57 @@ def test_one_pass_messages_equal_the_plain_form_to_the_bit(
     _assert_same_to_the_bit(m, state, batch)
 
 
+def test_attention_takes_the_one_pass_and_equals_the_plain_form(
+        graph, caplog):
+    """The attention aggregator reads layer 0's messages a slot, from the
+    stored table, as the mean does: a slot's projection is ``W`` applied
+    to that slot's row, the same dot product as projecting the hop's set
+    and gathering by ``dst`` after, so loss and gradients are the plain
+    form's to float32 rounding; both the sum and the softmax of the
+    device expansion's lists go along their rows, and say so."""
+    import logging
+
+    m = _gcn("attention")
+    opt, state, batch = _state_batch(m, graph)
+    assert m.module._hop_rows_why(batch, state["consts"]) is None
+    got, want = _step_and_plain_form(m, state, batch)
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    leaves = jax.tree_util.tree_leaves_with_path(got[1])
+    # two layers of four heads of three kernels, the classifier's two
+    assert len(leaves) == 2 * 4 * 3 + 2
+    for (path, g), w in zip(leaves, jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-7,
+                                   err_msg=str(path))
+    from euler_tpu.models import gcn as gcn_models
+
+    gcn_models._log_message_route.cache_clear()
+    sparse_aggregators._log_aggregate_route.cache_clear()
+    sparse_aggregators._log_attention_route.cache_clear()
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        new, loss, _ = jax.jit(m.make_train_step(opt))(state, batch)
+    said = [r.getMessage() for r in caplog.records]
+    messages = [s for s in said if s.startswith("message path:")]
+    assert len(messages) == 2 and all(
+        "one pass from the stored table" in s for s in messages)
+    assert _routes(caplog) and all(
+        "row sum over" in s for s in _routes(caplog))
+    softmax = [s for s in said if s.startswith("attention path:")]
+    assert softmax and all(
+        "x 4 heads -> row softmax over" in s for s in softmax)
+    assert np.isfinite(float(loss))
+    # a step trains; a gate's gradient may be nought to rounding
+    moved = jax.tree.map(
+        lambda a, b: bool(np.any(np.asarray(a) != np.asarray(b))),
+        state["params"], new["params"])
+    assert any(jax.tree.leaves(moved))
+
+
 @pytest.mark.parametrize("kw, why", [
     (dict(device_features=False), "host-expanded batch"),
     (dict(device_sampling=False), "host-expanded batch"),
     (dict(use_id=True), "use_id"),
     (dict(use_residual=True), "use_residual"),
-    (dict(aggregator="attention"), "attention aggregator"),
-], ids=["host_rows", "host_expanded", "use_id", "use_residual", "attention"])
+], ids=["host_rows", "host_expanded", "use_id", "use_residual"])
 def test_other_configurations_keep_the_hops_own_rows(graph, kw, why, caplog):
     """What must keep today's path does: it says why, its loss and
     gradients are the plain form's to the bit, and a step trains. A
@@ -200,9 +250,7 @@ def test_other_configurations_keep_the_hops_own_rows(graph, kw, why, caplog):
     m = _gcn(**kw)
     opt, state, batch = _state_batch(m, graph)
     assert why in m.module._hop_rows_why(batch, state.get("consts"))
-    # a head's gate on an all-alike softmax has a gradient of nought
-    attention = kw.get("aggregator") == "attention"
-    _assert_same_to_the_bit(m, state, batch, every_leaf_moves=not attention)
+    _assert_same_to_the_bit(m, state, batch)
     sparse_aggregators._log_aggregate_route.cache_clear()
     with caplog.at_level(logging.INFO, logger="euler_tpu"):
         new, loss, _ = jax.jit(m.make_train_step(opt))(state, batch)
@@ -210,14 +258,13 @@ def test_other_configurations_keep_the_hops_own_rows(graph, kw, why, caplog):
     if "hops" in batch:
         assert said and all(
             s.endswith("segment sum (traced src)") for s in said)
-    elif not attention:
+    else:
         assert said and all("row sum over" in s for s in said)
     assert np.isfinite(float(loss))
     moved = jax.tree.map(
         lambda a, b: bool(np.any(np.asarray(a) != np.asarray(b))),
         state["params"], new["params"])
-    assert any(jax.tree.leaves(moved))
-    assert attention or all(jax.tree.leaves(moved))
+    assert all(jax.tree.leaves(moved))
 
 
 def test_scalable_gcn_trains_a_step_on_its_own_path(graph, caplog):
@@ -451,3 +498,186 @@ def test_any_other_list_keeps_the_segment_sum(
     kernels = jax.tree.leaves(params)
     want = self_emb @ kernels[0] + mean @ kernels[1]
     np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# The attention aggregator's edge softmax takes the same two forms: along
+# the rows of a regular list, by segment over any other
+# (sparse_aggregators._gather_max, OBSERVABILITY.md "attention path")
+# ---------------------------------------------------------------------------
+
+
+def _attention_list(n, W, m):
+    """``_regular_list`` with a row that lists one neighbour twice, both
+    slots live (two edges, two shares of the softmax)."""
+    self_emb, neigh_emb, adj = _regular_list(n, W, m)
+    adj["dst"][2 * W + 1] = adj["dst"][2 * W]
+    adj["mask"][2 * W:2 * W + 2] = 1.0
+    return self_emb, neigh_emb, adj
+
+
+def _attention_routes(caplog):
+    return [r.getMessage() for r in caplog.records
+            if r.getMessage().startswith("attention path:")]
+
+
+def _attention_said_while(caplog, fn, *args):
+    import logging
+
+    sparse_aggregators._log_attention_route.cache_clear()
+    caplog.clear()
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        out = fn(*args)
+    return out, _attention_routes(caplog)
+
+
+@pytest.mark.parametrize("heads", [None, 3], ids=["flat", "3_heads"])
+@pytest.mark.parametrize("W", [5, 56])
+def test_row_softmax_of_a_regular_list_equals_the_segment_softmax(
+        W, heads, caplog):
+    """``segment_softmax`` by both forms, value and gradient: masked
+    slots get nought, a row masked whole is nought throughout, every
+    other row sums to one, a neighbour listed twice has two shares."""
+    n = 7
+    _, _, adj = _attention_list(n, W, 11)
+    shape = (n * W,) if heads is None else (n * W, heads)
+    logits = jnp.asarray(
+        np.random.default_rng(0).normal(size=shape) * 3, jnp.float32)
+    mask = jnp.asarray(adj["mask"])
+
+    def value(logits, src):
+        p = sparse_aggregators.segment_softmax(logits, src, n, mask)
+        return jnp.sum(p * jnp.cos(jnp.arange(p.size).reshape(p.shape))), p
+
+    grad = jax.value_and_grad(value, has_aux=True)
+    ((_, p), g), said = _attention_said_while(
+        caplog, jax.jit(lambda x: grad(x, adj["src"])), logits)
+    k = heads or 1
+    assert said == [
+        f"attention path: {n * W} slots x {k} heads -> row softmax over {W}"]
+    ((_, want), w), said = _attention_said_while(
+        caplog, jax.jit(grad), logits, adj["src"])
+    assert said == [
+        f"attention path: {n * W} slots x {k} heads -> segment softmax"]
+    np.testing.assert_allclose(p, want, rtol=1e-6, atol=1e-7)
+    np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    rows = np.asarray(p).reshape((n, W) + shape[1:])
+    live = adj["mask"].reshape(n, W) > 0
+    assert (rows[~live] == 0).all() and (rows[1] == 0).all()
+    np.testing.assert_allclose(
+        rows.sum(1)[live.any(1)], 1.0, rtol=1e-6)
+    assert np.abs(np.asarray(g)).sum() > 0
+
+
+@pytest.mark.parametrize("jitted", [True, False], ids=["jit", "eager"])
+@pytest.mark.parametrize("renorm", [False, True], ids=["plain", "renorm"])
+@pytest.mark.parametrize("W", [5, 56])
+@pytest.mark.parametrize("slot_rows", [True, False],
+                         ids=["SlotRows", "hop_rows"])
+def test_row_form_of_the_attention_equals_the_segment_form(
+        slot_rows, W, renorm, jitted, caplog):
+    """The four heads by both forms on the same seeded weights: output,
+    the weights' gradients and the neighbour input's are the segment
+    form's within float32 rounding, with masked slots, an all-masked row
+    and a neighbour listed twice; and each form says it was taken."""
+    n, m = 7, 11
+    self_emb, neigh_emb, adj = _attention_list(n, W, m)
+    agg = sparse_aggregators.AttentionAggregator(dim=8, renorm=renorm)
+    params = agg.init(jax.random.PRNGKey(0), (self_emb, neigh_emb, adj))
+    assert sorted(params["params"]) == [
+        "SingleAttentionAggregator_%d" % k for k in range(4)]
+
+    def value(params, neigh_emb, src):
+        a = dict(adj, src=src)
+        neigh = neigh_emb
+        if slot_rows:
+            neigh = sparse_aggregators.SlotRows(neigh_emb[a["dst"]])
+        out = agg.apply(params, (self_emb, neigh, a))
+        return jnp.sum(out * jnp.cos(jnp.arange(out.size).reshape(
+            out.shape))), out
+
+    grad = jax.value_and_grad(value, argnums=(0, 1), has_aux=True)
+    row = lambda p, x: grad(p, x, adj["src"])
+    ((_, out), (g_w, g_x)), said = _attention_said_while(
+        caplog, jax.jit(row) if jitted else row, params, neigh_emb)
+    assert said == [
+        f"attention path: {n * W} slots x 4 heads -> row softmax over {W}"]
+    ((_, want), (w_w, w_x)), said = _attention_said_while(
+        caplog, jax.jit(grad), params, neigh_emb, adj["src"])
+    assert said == [
+        f"attention path: {n * W} slots x 4 heads -> segment softmax"]
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
+    assert out.shape == (n, 8) and np.abs(np.asarray(out)).sum() > 0
+    for g, w in zip(jax.tree.leaves(g_w), jax.tree.leaves(w_w)):
+        assert np.abs(np.asarray(w)).sum() > 0
+        np.testing.assert_allclose(g, w, rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(g_x, w_x, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("renorm", [False, True], ids=["plain", "renorm"])
+def test_attention_reads_rows_by_dst_or_as_they_lie(renorm):
+    """``SlotRows`` into the attention aggregator against the hop's set
+    rows through ``dst``, and both against the softmax written out dense
+    [n, m] here, head by head: to float32 rounding."""
+    n, W, m = 7, 5, 11
+    self_emb, neigh_emb, adj = _attention_list(n, W, m)
+    agg = sparse_aggregators.AttentionAggregator(dim=8, renorm=renorm)
+    params = agg.init(jax.random.PRNGKey(0), (self_emb, neigh_emb, adj))
+    by_dst = agg.apply(params, (self_emb, neigh_emb, adj))
+    as_they_lie = agg.apply(params, (
+        self_emb, sparse_aggregators.SlotRows(neigh_emb[adj["dst"]]), adj))
+    np.testing.assert_allclose(as_they_lie, by_dst, rtol=1e-5, atol=1e-6)
+    # dense: count[i, j] edges from i to j, each with its own share
+    count = np.zeros((n, m))
+    np.add.at(count, (adj["src"], adj["dst"]), adj["mask"])
+    heads = []
+    for k in range(4):
+        leaves = params["params"]["SingleAttentionAggregator_%d" % k]
+        w, u, v = (np.asarray(leaves["Dense_%d" % i]["Dense_0"]["kernel"],
+                              np.float64) for i in range(3))
+        p_s = np.asarray(self_emb, np.float64) @ w
+        p_a = np.asarray(neigh_emb, np.float64) @ w
+        logit = p_s @ u + (p_a @ v).T                     # [n, m]
+        e = count * np.exp(np.where(logit > 0, logit, 0.01 * logit))
+        if renorm:
+            own = (p_s @ u + p_s @ v)[:, 0]
+            e_own = np.exp(np.where(own > 0, own, 0.01 * own))
+            denom = e.sum(1) + e_own
+            h = (e @ p_a + e_own[:, None] * p_s) / denom[:, None]
+        else:
+            h = p_s + e @ p_a / np.maximum(e.sum(1), 1e-30)[:, None]
+        heads.append(np.maximum(h, 0))
+    np.testing.assert_allclose(
+        by_dst, np.concatenate(heads, 1), rtol=1e-4, atol=1e-5)
+
+
+def test_kernel_is_a_bias_free_denses_kernel_at_its_path():
+    """``_Kernel`` declares by hand what ``layers.Dense(dim,
+    use_bias=False)`` keeps (the attention heads' tree, which checkpoints
+    and the benchmark's seeded weights name): the same tree, the same
+    initial values from the same key. A change to ``Dense``'s tree or
+    initialiser has to be made in both, and this says so."""
+    from flax import linen as nn
+
+    from euler_tpu.nn.layers import Dense
+
+    class ByDense(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return (Dense(8, use_bias=False)(x),
+                    Dense(1, use_bias=False)(x))
+
+    class ByKernel(nn.Module):
+        @nn.compact
+        def __call__(self, x):
+            return (sparse_aggregators._Kernel(8, name="Dense_0")(x.shape[-1]),
+                    sparse_aggregators._Kernel(1, name="Dense_1")(x.shape[-1]))
+
+    x = jnp.ones((3, 5))
+    want = ByDense().init(jax.random.PRNGKey(7), x)
+    got = ByKernel().init(jax.random.PRNGKey(7), x)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert path[-1].key == "kernel"
+        np.testing.assert_array_equal(g, w)

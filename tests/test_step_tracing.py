@@ -28,7 +28,11 @@ WALK_SCOPES = {"walk", "negatives", "pair_rows"}
 # the scopes of the full-neighbourhood family's step alone (models/gcn.py:
 # graph/device.py multi_hop_neighbor and nn/sparse_aggregators.py)
 EXPAND_SCOPES = {"expand", "segment_agg"}
-FAMILY_SCOPES = STORE_SCOPES | WALK_SCOPES | EXPAND_SCOPES
+# the scope of that family's attention aggregator alone
+# (nn/sparse_aggregators.py _attend: --aggregator attention)
+ATTENTION_SCOPES = {"edge_softmax"}
+FAMILY_SCOPES = (STORE_SCOPES | WALK_SCOPES | EXPAND_SCOPES
+                 | ATTENTION_SCOPES)
 TRAIN_THREAD_LEAVES = {
     "input_stall", "input_other", "h2d",
     *(leaf for leaf, parent in T.PHASE_PARENT.items() if parent != "setup"),
@@ -117,7 +121,8 @@ def test_lowered_store_step_holds_every_step_scope(graph):
     state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
     text = jax.jit(m.make_train_step(opt)).lower(
         state, m.sample(graph, roots)).as_text(debug_info=True)
-    for scope in set(TR.STEP_SCOPES) - WALK_SCOPES - EXPAND_SCOPES:
+    for scope in (set(TR.STEP_SCOPES) - WALK_SCOPES - EXPAND_SCOPES
+                  - ATTENTION_SCOPES):
         assert f"/{scope}/" in text, scope
     lines = text.splitlines()
     gathers = [ln for ln in lines if "stores_read" in ln and "gather" in ln]
@@ -130,10 +135,11 @@ def test_lowered_store_step_holds_every_step_scope(graph):
 def _gcn_model(**kw):
     from euler_tpu.models import SupervisedGCN
 
+    kw.setdefault("aggregator", "mean")
     return SupervisedGCN(
         label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]], dim=16,
         max_nodes_per_hop=[32, 64], max_edges_per_hop=[64, 256],
-        aggregator="mean", feature_idx=0, feature_dim=2, max_id=MAX_ID,
+        feature_idx=0, feature_dim=2, max_id=MAX_ID,
         device_features=True, device_sampling=True, **kw,
     )
 
@@ -262,6 +268,29 @@ def test_lowered_shallow_step_holds_the_walk_scopes(graph, walk_len):
                    for ln in lines)
 
 
+def test_lowered_attention_step_holds_the_edge_softmax_scope(graph):
+    """``--aggregator attention`` on the same step: the softmax's max,
+    exp and sums under ``edge_softmax`` (row reductions of the device
+    expansion's regular list: no scatter there, forward or transposed),
+    the projections and the gates under ``dense``."""
+    m = _gcn_model(aggregator="attention")
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = graph.sample_node(8, -1)
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    text = jax.jit(m.make_train_step(opt)).lower(
+        state, m.sample(graph, roots)).as_text(debug_info=True)
+    here = EXPAND_SCOPES | ATTENTION_SCOPES | {
+        "gather_features", "gather_labels", "dense", "loss", "optimizer"}
+    for scope in TR.STEP_SCOPES:
+        assert (f"/{scope}/" in text) == (scope in here), scope
+    lines = text.splitlines()
+    for op in ("reduce_max", "exp", "reduce_sum"):
+        assert any(f"/edge_softmax/{op}" in ln for ln in lines), op
+    assert not any("/edge_softmax/" in ln and "scatter" in ln for ln in lines)
+    assert any("transpose(jvp(" in ln and "/edge_softmax/" in ln
+               for ln in lines)
+
+
 def test_benchmark_keeps_the_same_scope_names():
     from benchmark import scopes
 
@@ -302,6 +331,51 @@ def test_recorder_stop_takes_itself_out_and_leaves_another_in():
     second.stop()
     assert [e[3] for e in second.events()] == [3]
     assert T._trace_sink is None
+
+
+def _keyword_hook(seen, end_at):
+    def hook(step, state=None, batch=None, loss=None):
+        seen.append((step, sorted(state), sorted(batch), float(loss)))
+        return step == end_at
+
+    return hook
+
+
+def _step_hook(seen, end_at):
+    def hook(step):
+        seen.append((step,))
+        return step == end_at
+
+    return hook
+
+
+@pytest.mark.parametrize("make, end_at, steps", [
+    (_keyword_hook, 5, 5), (_keyword_hook, None, 12),
+    (_step_hook, 5, 5), (lambda seen, _: lambda step: seen.append((step,)),
+                         None, 12),
+], ids=["keywords-ends", "keywords", "step_alone-ends", "lambda_step_none"])
+def test_step_hook_is_fed_where_it_takes_keywords_and_may_end_the_loop(
+        graph, make, end_at, steps):
+    """A hook that takes ``state=``, ``batch=`` and ``loss=`` is handed
+    what each step produced; one that takes the step alone (run_loop's
+    metrics emitter, ``lambda step: None``) is called as before; either
+    ends the loop, through its ``finally``, by returning True: the
+    journal's collector callback is gone, the last partial log window is
+    flushed, and the state of the last step comes back."""
+    import gc
+
+    seen = []
+    callbacks = list(gc.callbacks)
+    state, history = _train(graph, 12, step_hook=make(seen, end_at))
+    assert [s[0] for s in seen] == list(range(1, steps + 1))
+    assert gc.callbacks == callbacks
+    # log_every 4: whole windows and the last partial one
+    assert len(history) == -(-steps // 4)
+    assert int(state["opt_state"][0].count) == steps    # Adam's own
+    if make is _keyword_hook:
+        for _, state_keys, batch_keys, loss in seen:
+            assert "params" in state_keys and "opt_state" in state_keys
+            assert batch_keys and np.isfinite(loss)
 
 
 def test_leaves_tile_every_step_and_parents_are_their_sums(graph, tmp_path):
