@@ -952,13 +952,19 @@ def sample_node_with_src(tsampler: dict, src, key, count: int):
 
 
 @functools.lru_cache(maxsize=64)
-def _log_expand_route(sizes: tuple) -> None:
+def _log_expand_route(sizes: tuple, ranked: tuple) -> None:
     """One line per distinct expansion shape, said while tracing (as the
     draw paths say theirs): the padded slots every hop of the full-
-    neighbourhood expansion works on."""
+    neighbourhood expansion works on, and each hop whose cap can bind
+    (``ranked``: ``(hop, cap, slots)``), whose mask reads the rank. A
+    line that names no hop so has no rank in its masks."""
+    binds = "".join(
+        f"; hop {h}: cap {cap} < {slots} slots, ranked"
+        for h, cap, slots in ranked
+    )
     log.info(
-        "expand path: full neighbourhood %s slots (sort dedup, XLA)",
-        " -> ".join(map(str, sizes)),
+        "expand path: full neighbourhood %s slots (sort dedup, XLA%s)",
+        " -> ".join(map(str, sizes)), binds,
     )
 
 
@@ -977,7 +983,19 @@ def multi_hop_neighbor(adjs, roots, node_caps):
     repeat(arange(C), W): a numpy constant of the static shapes, by which
     nn/sparse_aggregators.py knows the list for regular and sums its rows
     in place of a segment sum), "mask": [C*W] 1.0 on real edges, "w":
-    alias of mask (the sparse aggregators use binary adjacency)}.
+    alias of mask (the sparse aggregators use binary adjacency)} — and
+    beside it "ids": [C*W] int32, every slot's own neighbour id (the
+    default id on a padded slot), which is ``nodes[dst]`` on every slot
+    whose rank fits under the cap: a reader of the slots' ids takes them
+    from here and leaves the set, the sort and the rank dead where
+    nothing else reads them (models/gcn.py ``_slot_rows``).
+
+    Whether a hop's cap can bind is decided from the static shapes: with
+    ``cap >= C*W`` no rank reaches the cap (a hop has at most C*W unique
+    ids), so the mask is ``id != default`` with no rank term and
+    "overflow" is a constant 0; only where ``cap < C*W`` does the mask
+    read the rank and the overflow count it. The route log says which
+    hops are ranked (``expand path: ...``).
 
     Divergences from the host path, both graceful where the host raises:
     rows beyond the slab's max_degree were already truncated to their
@@ -991,8 +1009,9 @@ def multi_hop_neighbor(adjs, roots, node_caps):
     """
     cur = jnp.asarray(roots, dtype=jnp.int32).reshape(-1)
     sizes = [cur.shape[0]]
+    ranked = []
     hops = []
-    for adj, cap in zip(adjs, node_caps):
+    for h, (adj, cap) in enumerate(zip(adjs, node_caps), 1):
         default = adj["nbr"].shape[0] - 1
         W = adj["nbr"].shape[1]
         C = cur.shape[0]
@@ -1019,28 +1038,33 @@ def multi_hop_neighbor(adjs, roots, node_caps):
         # sparse aggregators see a regular list in it and sum its rows
         src = np.repeat(np.arange(C, dtype=np.int32), W)
         dst = jnp.clip(rank, 0, cap - 1).astype(jnp.int32)
-        mask = (
-            valid.reshape(-1)
-            & (rank < cap)
-            & (flat != default)
-        ).astype(jnp.float32)
-        # unique real ids of this hop (padding entries all hold the
-        # default id) against the room the cap gives them
-        unique = jnp.sum(first & (s != default), dtype=jnp.int32)
+        live = flat != default  # a padded slot holds the default id
+        if cap >= C * W:
+            # at most C*W unique ids: no rank reaches the cap
+            overflow = jnp.int32(0)
+        else:
+            live = live & (rank < cap)
+            # unique real ids of this hop (padding entries all hold the
+            # default id) against the room the cap gives them
+            unique = jnp.sum(first & (s != default), dtype=jnp.int32)
+            overflow = jnp.maximum(unique - cap, 0)
+            ranked.append((h, cap, C * W))
+        mask = live.astype(jnp.float32)
         hops.append(
             {
                 "nodes": nodes,
                 "src": src,
                 "dst": dst,
+                "ids": flat,
                 "mask": mask,
                 "w": mask,
                 "edges": jnp.sum(mask),
-                "overflow": jnp.maximum(unique - cap, 0),
+                "overflow": overflow,
             }
         )
         sizes.append(C * W)
         cur = nodes
-    _log_expand_route(tuple(sizes))
+    _log_expand_route(tuple(sizes), tuple(ranked))
     return hops
 
 

@@ -150,21 +150,23 @@ class _SupervisedGCNModule(nn.Module):
         if one_pass:
             hidden.append(None)
             first_neigh = [
-                self._slot_rows(hop["gids"], adj, consts["features"])
-                for hop, adj in zip(hops[1:], adjs)
+                self._slot_rows(adj["ids"], consts["features"])
+                for adj in adjs
             ]
         embedding = self.encoder(hidden, adjs, first_neigh)
         return embedding, hops, self._expand_counters(adjs)
 
-    def _slot_rows(self, nodes, adj, table):
+    def _slot_rows(self, ids, table):
         """Layer 0's messages of one hop's edge list, before the mask:
-        the stored table's row of every slot's own node, ``nodes[dst]``,
-        in one pass, the pad lanes cut after that gather (never between
-        two gathers: a 50-wide intermediate is laid column-major and a
-        row gather out of it reads a row across 50 separated columns,
+        the stored table's row of every slot's own node (the expansion's
+        ``ids``, which is ``nodes[dst]`` on every unmasked slot) in one
+        pass, the pad lanes cut after that gather (never between two
+        gathers: a 50-wide intermediate is laid column-major and a row
+        gather out of it reads a row across 50 separated columns,
         PERF.md section 6, PR 38). ``table[nodes][..., :F][dst]`` to the
-        bit."""
-        ids = sparse_aggregators.slot_ids(nodes, adj)
+        bit on every unmasked slot. Nothing else of the step reads the
+        outer hop's set, so where its cap cannot bind its sort and rank
+        are dead code (graph/device.py ``multi_hop_neighbor``)."""
         with jax.named_scope("gather_features"):
             rows = base.gather_rows(table, ids, self.feature_dim)
         return sparse_aggregators.SlotRows(rows)

@@ -35,12 +35,14 @@ reads through ``dst``, or ``SlotRows``: rows that already lie one a slot
 of the edge list. Every aggregator takes either. Layer 0 of the
 device-expanded full-neighbourhood step hands them ``SlotRows`` where a
 hop's rows are rows of the device-resident feature table (models/gcn.py
-``_forward``): the slots' node ids ``nodes[dst]`` are composed here
-(``slot_ids``, under ``segment_agg``), the rows are gathered from the
-stored table by those ids in one pass under ``gather_features``, and the
-hop's set rows are not gathered for the messages' sake at all. Attention
-projects a slot's row where it lies (the same dot product as projecting
-the hop's set and gathering after).
+``_forward``): the rows are gathered from the stored table in one pass
+under ``gather_features``, by the slots' own neighbour ids, which the
+expansion hands out beside its COO (graph/device.py
+``multi_hop_neighbor``, ``ids``: ``nodes[dst]`` on every unmasked slot,
+so nothing composes them here), and the hop's set rows are not gathered
+for the messages' sake at all. Attention projects a slot's row where it
+lies (the same dot product as projecting the hop's set and gathering
+after).
 """
 
 from __future__ import annotations
@@ -113,18 +115,10 @@ class SlotRows(NamedTuple):
     ([slots, F], in the adjacency's own order) in place of one a node of
     the hop's set: the aggregator reads them as they lie, with no gather
     by ``dst``. Where a hop's rows are nothing but rows of a stored table
-    the caller gathers them by ``slot_ids`` in one pass."""
+    the caller gathers them by the slots' own ids in one pass; a masked
+    slot's row is multiplied by the mask's nought."""
 
     rows: jax.Array
-
-
-def slot_ids(nodes, adj):
-    """The id of every slot's neighbour, ``nodes[dst]``: two chained row
-    gathers (a table by ``nodes``, the result by ``dst``) are one gather
-    by these ids. A masked slot names whatever node its clipped ``dst``
-    points at; its row is multiplied by the mask's nought as before."""
-    with jax.named_scope("segment_agg"):
-        return nodes[adj["dst"]]
 
 
 def _messages(neigh_emb, adj):

@@ -876,6 +876,41 @@ def test_multi_hop_neighbor_matches_host_exactly(graph, adj01):
     assert np.array_equal(kept, np.sort(full)[:2].astype(np.int32))
 
 
+@pytest.mark.parametrize("caps", [[15, 75], [15, 40], [8, 12], [2, 3]],
+                         ids=["caps_hold", "hop2_binds", "both_bind",
+                              "tight"])
+def test_multi_hop_neighbor_slot_ids_mask_and_overflow(graph, adj01, caps):
+    """Each hop's ``ids`` are the slots' own neighbour ids, ``nodes[dst]``
+    on every slot where the cap holds (3 roots x 5 slots: hop 1 holds at
+    15, hop 2 at 75) and on every unmasked slot where it binds; the mask
+    is the ranked form ``valid & (rank < cap) & (id != default)`` whether
+    or not the step computes a rank for it, and ``overflow`` the unique
+    real ids past the cap. Computed anew here with ``np.unique``."""
+    roots = np.array([10, 11, 16], dtype=np.int64)
+    nbr_all, deg_all = np.asarray(adj01["nbr"]), np.asarray(adj01["deg"])
+    default = nbr_all.shape[0] - 1
+    hops = device.multi_hop_neighbor([adj01, adj01], roots, caps)
+    cur = roots
+    for h, cap in zip(hops, caps):
+        nbr = nbr_all[cur]
+        valid = np.arange(nbr.shape[1])[None, :] < deg_all[cur][:, None]
+        flat = np.where(valid, nbr, default).reshape(-1)
+        uniq, rank = np.unique(flat, return_inverse=True)
+        holds = cap >= flat.shape[0]
+        ids = np.asarray(h["ids"])
+        nodes, dst = np.asarray(h["nodes"]), np.asarray(h["dst"])
+        mask = np.asarray(h["mask"]) > 0
+        assert ids.dtype == np.int32 and np.array_equal(ids, flat)
+        assert np.array_equal(
+            mask, valid.reshape(-1) & (rank < cap) & (flat != default))
+        on = slice(None) if holds else mask
+        assert np.array_equal(ids[on], nodes[dst][on])
+        assert int(h["overflow"]) == max(int((uniq != default).sum()) - cap,
+                                         0)
+        assert float(h["edges"]) == mask.sum()
+        cur = nodes
+
+
 def test_supervised_gcn_device_matches_host_loss(graph):
     """Same params, same roots: the device-expanded SupervisedGCN step
     must produce the host path's loss (full-neighbor GCN has no sampling

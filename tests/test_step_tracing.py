@@ -136,9 +136,10 @@ def _gcn_model(**kw):
     from euler_tpu.models import SupervisedGCN
 
     kw.setdefault("aggregator", "mean")
+    kw.setdefault("max_nodes_per_hop", [32, 64])
     return SupervisedGCN(
         label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]], dim=16,
-        max_nodes_per_hop=[32, 64], max_edges_per_hop=[64, 256],
+        max_edges_per_hop=[64, 256],
         feature_idx=0, feature_dim=2, max_id=MAX_ID,
         device_features=True, device_sampling=True, **kw,
     )
@@ -147,12 +148,11 @@ def _gcn_model(**kw):
 def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
     """The full-neighbourhood family's step: all of the device expansion
     (slab-row gathers, the sort, the scatters) under ``expand``, the
-    sparse aggregator's work over the edge list (the composition of the
-    slots' ids ``nodes[dst]``, the mask, the degree and the sum as
-    reductions along the rows of a regular list; layer 1's gather by
-    ``dst``, whose transpose is the one scatter left) under
-    ``segment_agg``, its
-    matmuls under ``dense``, and under ``gather_features`` the rows: the
+    sparse aggregator's work over the edge list (the mask, the degree
+    and the sum as reductions along the rows of a regular list; layer
+    1's gather by ``dst``, whose transpose is the one scatter left) under
+    ``segment_agg``, its matmuls under ``dense``, and under
+    ``gather_features`` the rows: the
     roots' and hop 1's sets, and layer 0's messages of both hops, read
     from the stored table one a slot."""
     m = _gcn_model()
@@ -169,8 +169,8 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
         assert (f"/{scope}/" in text) == (scope in here), scope
     lines = text.splitlines()
     assert any("/expand/" in ln and "sort" in ln for ln in lines)
-    # the rank's scatter and the set's: every product of the expansion
-    # stays a product of the step
+    # the rank's scatter and the set's: this model's caps bind (32 < 8 x
+    # 5 slots, 64 < 32 x 5), so its masks read the rank
     assert sum("/expand/scatter" in ln for ln in lines) >= 2
     # the device expansion's list is regular: its degree and its sum are
     # row reductions, forward (the embed program holds no scatter under
@@ -228,15 +228,61 @@ def test_lowered_gcn_step_gathers_the_outer_hops_rows_once(graph, one_pass):
     row_gathers = [g for g in gathers if g[1].startswith(f"tensor<{cap}x")]
     assert bool(set_rows) == (not one_pass)
     assert bool(row_gathers) == (not one_pass)
-    # the messages' rows, one a slot, under gather_features, by ids
-    # composed under segment_agg
+    # the messages' rows, one a slot, under gather_features, by the ids
+    # the expansion hands out: no gather composes them (``nodes[dst]``)
     assert any(
         "/gather_features/" in name and out == f"tensor<{slots}x128xf32>"
         for name, out in gathers) == one_pass
-    assert any(
+    assert not any(
         name.endswith("/segment_agg/gather")
         and out == f"tensor<{slots}xi32>"
-        for name, out in gathers) == one_pass
+        for name, out in gathers)
+
+
+@pytest.mark.parametrize("hop2_cap", [200, 64], ids=["caps_hold",
+                                                     "hop2_binds"])
+def test_compiled_one_pass_gcn_step_drops_the_outer_hops_dedup(
+        graph, caplog, hop2_cap):
+    """The one-pass step reads the outer hop's slot ids and, where its
+    cap cannot bind, a mask with no rank term: nothing then reads that
+    hop's set, rank or sort, and the compiler's dead-code pass takes
+    them out. 8 roots x 5 slots = 40 (hop 1 at cap 40, whose dedup layer
+    1 reads through ``dst``), 40 x 5 = 200 slots in hop 2: at cap 200 the
+    compiled step holds no sort and no scatter over ``s32[200]``; at cap
+    64 both are there and the route log says the hop is ranked. Either
+    step runs, and counts no id past a cap: the fixture's 17 ids fit
+    both."""
+    import logging
+    import re
+
+    from euler_tpu.graph import device as device_graph
+
+    m = _gcn_model(max_nodes_per_hop=[40, hop2_cap])
+    opt = train_lib.get_optimizer("adam", 0.01)
+    roots = np.asarray(graph.sample_node(8, -1))
+    state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
+    assert m.module._hop_rows_why(
+        m.sample(graph, roots), state["consts"]) is None
+    device_graph._log_expand_route.cache_clear()
+    with caplog.at_level(logging.INFO, logger="euler_tpu"):
+        step = jax.jit(m.make_train_step(opt))
+        text = step.lower(state, m.sample(graph, roots)).compile().as_text()
+    route = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("expand path:")]
+    assert route and "8 -> 40 -> 200 slots" in route[0]
+    outer = re.compile(r"s32\[200\]")
+    sorts = [ln for ln in text.splitlines()
+             if " sort(" in ln and outer.search(ln)]
+    scatters = [ln for ln in text.splitlines()
+                if " scatter(" in ln and outer.search(ln)]
+    # hop 1's dedup stays: layer 1 reads its set through ``dst``
+    assert any(" sort(" in ln and "s32[40]" in ln
+               for ln in text.splitlines())
+    binds = hop2_cap < 200
+    assert bool(sorts) == binds and bool(scatters) == binds
+    assert ("hop 2: cap 64 < 200 slots, ranked" in route[0]) == binds
+    _, _, (_, counts) = step(state, m.sample(graph, roots))
+    assert np.asarray(counts)[2] == 0
 
 
 @pytest.mark.parametrize("walk_len", [5, 0], ids=["node2vec", "line"])
