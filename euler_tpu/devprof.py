@@ -20,6 +20,7 @@ scripts/metrics_dump.py report the device plane with zero new plumbing:
     devprof.sample_device_mem()      one-shot HBM/buffer gauge refresh
     devprof.record_feature_table(w, stored)   feature-table width gauges
     devprof.record_store_table(w, stored)     per-node store width gauges
+    devprof.record_alias_tables(e, d, b)      alias-table size gauges
     devprof.record_step_memory(compiled)      the step's temporaries gauge
     devprof.count_h2d(batch)         transfer-byte bracketing
     devprof.set_devprof(False)       process-global kill-switch
@@ -408,6 +409,17 @@ def record_store_table(width: int, stored_width: int) -> None:
     ``train()``)."""
     if _enabled:
         lib().eg_devprof_set_store_table(int(width), int(stored_width))
+
+
+def record_alias_tables(edges: int, max_degree: int, nbytes: int) -> None:
+    """The gauges ``alias_edges`` / ``alias_max_degree`` /
+    ``alias_table_bytes`` of the resource section: the edges, the longest
+    row and the bytes of the flat-CSR alias tables of the exact device
+    draw (graph/device.py build_alias_adjacency calls this once per set
+    of tables it builds; the last set built is what they say)."""
+    if _enabled:
+        lib().eg_devprof_set_alias_tables(
+            int(edges), int(max_degree), int(nbytes))
 
 
 def record_step_memory(compiled) -> dict | None:

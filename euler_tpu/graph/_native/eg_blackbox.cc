@@ -627,6 +627,15 @@ void Blackbox::ResourceJsonBody(std::string* out) {
   AppendKey(out, "step_temp_bytes");
   AppendI64(out, Devprof::Global().step_temp_bytes());
   out->push_back(',');
+  AppendKey(out, "alias_edges");
+  AppendI64(out, Devprof::Global().alias_edges());
+  out->push_back(',');
+  AppendKey(out, "alias_max_degree");
+  AppendI64(out, Devprof::Global().alias_max_degree());
+  out->push_back(',');
+  AppendKey(out, "alias_table_bytes");
+  AppendI64(out, Devprof::Global().alias_table_bytes());
+  out->push_back(',');
   AppendKey(out, "history_depth");
   uint64_t hh = hist_head_.load(std::memory_order_acquire);
   AppendU64(out, hh > kBbHistorySlots ? kBbHistorySlots : hh);

@@ -953,6 +953,16 @@ void eg_devprof_set_store_table(int64_t width, int64_t stored_width) {
   EG_API_GUARD()
 }
 
+// The alias tables' edges, longest row and bytes (graph/device.py
+// build_alias_adjacency sets them once per set of tables it builds).
+void eg_devprof_set_alias_tables(int64_t edges, int64_t max_degree,
+                                 int64_t bytes) {
+  try {
+    eg::Devprof::Global().SetAliasTables(edges, max_degree, bytes);
+  }
+  EG_API_GUARD()
+}
+
 // The compiled train step's temporaries in bytes (train.write_step_hlo
 // sets it once, in a profiled run, from the step it compiles anyway).
 void eg_devprof_set_step_temp(int64_t bytes) {

@@ -47,6 +47,13 @@ void Devprof::SetStoreTable(int64_t width, int64_t stored_width) {
   store_stored_width_.store(stored_width, std::memory_order_relaxed);
 }
 
+void Devprof::SetAliasTables(int64_t edges, int64_t max_degree,
+                             int64_t bytes) {
+  alias_edges_.store(edges, std::memory_order_relaxed);
+  alias_max_degree_.store(max_degree, std::memory_order_relaxed);
+  alias_bytes_.store(bytes, std::memory_order_relaxed);
+}
+
 void Devprof::SetServeSlo(uint64_t p50_us, uint64_t p99_us,
                           uint64_t violations, uint64_t count) {
   slo_p50_us_.store(p50_us, std::memory_order_relaxed);
@@ -77,9 +84,10 @@ void Devprof::Reset() {
   mem_bytes_.store(0, std::memory_order_relaxed);
   mem_peak_bytes_.store(0, std::memory_order_relaxed);
   buffers_.store(0, std::memory_order_relaxed);
-  // the feature-table and store-table widths and the step's temporaries
-  // stay: they are set once, when the tables are built and the step is
-  // compiled, and both outlive a reset of the measurements
+  // the feature-table and store-table widths, the alias tables' sizes
+  // and the step's temporaries stay: they are set once, when the tables
+  // are built and the step is compiled, and outlive a reset of the
+  // measurements
   slo_p50_us_.store(0, std::memory_order_relaxed);
   slo_p99_us_.store(0, std::memory_order_relaxed);
   slo_violations_.store(0, std::memory_order_relaxed);

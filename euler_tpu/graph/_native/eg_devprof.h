@@ -55,6 +55,11 @@ class Devprof {
   // with stores is handed to train().
   void SetStoreTable(int64_t width, int64_t stored_width);
 
+  // The flat-CSR alias tables of the exact device draw
+  // (graph/device.py build_alias_adjacency): the edges, the longest row
+  // and the bytes of the last set built. 0/0/0 until one is built.
+  void SetAliasTables(int64_t edges, int64_t max_degree, int64_t bytes);
+
   // What the compiled train step needs beside its arguments and
   // results: memory_analysis().temp_size_in_bytes (train.write_step_hlo,
   // profiled runs). device.memory_stats() does not count it.
@@ -83,6 +88,15 @@ class Devprof {
   int64_t store_table_stored_width() const {
     return store_stored_width_.load(std::memory_order_relaxed);
   }
+  int64_t alias_edges() const {
+    return alias_edges_.load(std::memory_order_relaxed);
+  }
+  int64_t alias_max_degree() const {
+    return alias_max_degree_.load(std::memory_order_relaxed);
+  }
+  int64_t alias_table_bytes() const {
+    return alias_bytes_.load(std::memory_order_relaxed);
+  }
   int64_t step_temp_bytes() const {
     return step_temp_bytes_.load(std::memory_order_relaxed);
   }
@@ -104,6 +118,9 @@ class Devprof {
   std::atomic<int64_t> feature_stored_width_{0};
   std::atomic<int64_t> store_width_{0};
   std::atomic<int64_t> store_stored_width_{0};
+  std::atomic<int64_t> alias_edges_{0};
+  std::atomic<int64_t> alias_max_degree_{0};
+  std::atomic<int64_t> alias_bytes_{0};
   std::atomic<int64_t> step_temp_bytes_{0};
   std::atomic<uint64_t> slo_p50_us_{0};
   std::atomic<uint64_t> slo_p99_us_{0};

@@ -40,11 +40,13 @@ from __future__ import annotations
 import contextlib
 import functools
 import logging
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from euler_tpu import devprof
 from euler_tpu.telemetry import setup_spanned
 
 log = logging.getLogger("euler_tpu")
@@ -114,6 +116,18 @@ def build_adjacency(
         W = max_degree
         truncated = np.flatnonzero(counts_all > W)
     W = max(W, 1)
+    slab_bytes = n_rows * W * 8  # nbr int32 + cum float32
+    budget = device_memory_bytes()
+    if slab_bytes > budget:
+        raise ValueError(
+            f"build_adjacency: the padded slab of edge types "
+            f"{list(edge_types)} is {n_rows} rows x {W} (the graph's "
+            f"largest degree{'' if max_degree is None else ', capped'}): "
+            f"{slab_bytes / 1e9:.1f} GB of nbr and cum, over the "
+            f"{budget / 1e9:.1f} GB of the device it is built for; sample "
+            "from exact flat-CSR alias tables (--alias_sampling true, 12 B "
+            "an edge) or cap the width (--max_degree)"
+        )
 
     # vectorized scatter into the padded slabs (no per-row Python loop:
     # real graphs have hundreds of thousands of rows)
@@ -190,6 +204,20 @@ def build_adjacency(
         "sampleable": sampleable,
         "truncated_rows": int(len(truncated)),
     }
+
+
+def device_memory_bytes() -> int:
+    """What one device of the default backend holds: the limit its
+    ``memory_stats()`` reports (a TPU's HBM), else the host's physical
+    memory, where the CPU backend keeps its arrays."""
+    try:
+        limit = int((jax.devices()[0].memory_stats() or {}).get(
+            "bytes_limit", 0))
+    except Exception:  # noqa: BLE001 - a backend without memory_stats
+        limit = 0
+    if limit > 0:
+        return limit
+    return os.sysconf("SC_PAGE_SIZE") * os.sysconf("SC_PHYS_PAGES")
 
 
 def _build_alias_rows(offsets: np.ndarray, w_flat: np.ndarray):
@@ -282,7 +310,7 @@ def build_alias_adjacency(
     sampleable = (counts_all > 0) & (sums > 0)
     sampleable[default] = False
     max_deg = int(counts_all.max()) if len(counts_all) else 0
-    return {
+    tables = {
         "off": offsets[:-1].astype(np.int32),
         "deg": counts_all.astype(np.int32),
         "nbr": nbr_flat.astype(np.int32),
@@ -291,6 +319,13 @@ def build_alias_adjacency(
         "sampleable": sampleable,
         "bisect_steps": np.zeros(max(max_deg.bit_length(), 1), np.int8),
     }
+    nbytes = sum(a.nbytes for a in tables.values())
+    devprof.record_alias_tables(e, max_deg, nbytes)
+    log.info(
+        "alias tables for edge types %s: %d edges, longest row %d, "
+        "%.3f GB", list(edge_types), e, max_deg, nbytes / 1e9,
+    )
+    return tables
 
 
 def _alias_sample_neighbor(adj: dict, nodes, key, count: int):

@@ -109,6 +109,8 @@ def _load() -> ctypes.CDLL:
     _sig(L.eg_devprof_set_mem, None, [c.c_int64, c.c_int64])
     _sig(L.eg_devprof_set_feature_table, None, [c.c_int64, c.c_int64])
     _sig(L.eg_devprof_set_store_table, None, [c.c_int64, c.c_int64])
+    _sig(L.eg_devprof_set_alias_tables, None,
+         [c.c_int64, c.c_int64, c.c_int64])
     _sig(L.eg_devprof_set_step_temp, None, [c.c_int64])
     _sig(L.eg_serve_slo_set, None,
          [c.c_uint64, c.c_uint64, c.c_uint64, c.c_uint64])

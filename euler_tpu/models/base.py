@@ -342,7 +342,11 @@ class Model:
         (no truncation, O(edges) memory) — the recommended form for
         power-law graphs. Biased (p/q) walk adjacencies build the alias
         form with id-sorted rows and route through the exact
-        rejection-sampled walk (device.alias_biased_random_walk)."""
+        rejection-sampled walk (device.alias_biased_random_walk). The
+        flags ``--max_degree`` / ``--alias_sampling`` reach this in
+        ``run_loop.build_model``, so every caller of it (``main()``,
+        ``serve.py``, the benchmark's harness) gets the form the flags
+        ask for; a model that cannot take it raises there."""
         if alias and max_degree is not None:
             raise ValueError(
                 "alias sampling is exact: max_degree does not apply"
@@ -384,7 +388,9 @@ class Model:
         builds id-sorted rows (under their own keys) for
         device_graph.biased_random_walk. ``max_degree`` defaults to the
         model's set_sampling_options value; so does the slab-vs-alias
-        choice (alias = exact flat-CSR tables, never sorted)."""
+        choice (alias = exact flat-CSR tables, never sorted), which
+        ``run_loop.build_model`` sets from ``--max_degree`` /
+        ``--alias_sampling`` before any consts are built."""
         from euler_tpu.graph import device as device_graph
 
         from euler_tpu.graph import pallas_sampling

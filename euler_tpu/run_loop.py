@@ -537,7 +537,23 @@ class SavedEmbedding(models.Model):
 
 def build_model(args, graph):
     """Model dispatch with the reference's model names
-    (reference run_loop.py:222-354)."""
+    (reference run_loop.py:222-354), in the device-sampling form the
+    flags ask for: ``--max_degree`` / ``--alias_sampling`` go to the
+    model's ``set_sampling_options`` here, where one is given, so that
+    ``main()``, ``serve.py`` and the benchmark's harness build the same
+    model from the same flags. A model that refuses the form (the
+    full-neighbourhood families refuse ``--alias_sampling``) raises."""
+    model = _dispatch_model(args, graph)
+    if (args.max_degree is not None or args.alias_sampling) and hasattr(
+        model, "set_sampling_options"
+    ):
+        model.set_sampling_options(
+            max_degree=args.max_degree, alias=args.alias_sampling
+        )
+    return model
+
+
+def _dispatch_model(args, graph):
     fanouts = _int_list(args.fanouts)
     train_edge = _int_list(args.train_edge_type)
     all_edge = _int_list(args.all_edge_type)
@@ -956,12 +972,6 @@ def main(argv=None) -> int:
         # every jitted step trace inside the scope.
         with device_graph.kernel_mesh_scope(mesh):
             model = build_model(args, graph)
-            if (
-                args.max_degree is not None or args.alias_sampling
-            ) and hasattr(model, "set_sampling_options"):
-                model.set_sampling_options(
-                    max_degree=args.max_degree, alias=args.alias_sampling
-                )
             if args.mode == "train":
                 run_train(model, graph, args, mesh, recorder)
                 if args.serve_after:

@@ -704,6 +704,16 @@ _RESOURCE_FAMILIES = {
                                  "Lanes a stored row of a store takes in "
                                  "device memory; 0 = the device keeps the "
                                  "store column-major (rows not contiguous)"),
+    "alias_edges": ("eg_alias_edges",
+                    "Edges of the last flat-CSR alias tables built for the "
+                    "exact device draw (--alias_sampling); 0 = none built"),
+    "alias_max_degree": ("eg_alias_max_degree",
+                         "Longest row of those alias tables: the width a "
+                         "padded slab of the same graph would need"),
+    "alias_table_bytes": ("eg_alias_table_bytes",
+                          "Bytes of those alias tables as uploaded: 12 an "
+                          "edge (nbr, alias, prob), 9 a row (off, deg, "
+                          "sampleable)"),
     "step_temp_bytes": ("eg_step_temp_bytes",
                         "Temporaries of the compiled train step beside its "
                         "arguments and results (memory_analysis; set by a "
