@@ -70,6 +70,10 @@ enum StepPhase : int {
   // another's trace is counted once, so the three sums are a union.
   kPhaseTrace,           // jaxpr trace of a jitted function
   kPhaseLower,           // jaxpr -> MLIR module
+  // A value, not µs: the steps one dispatch of the training thread ran
+  // (train(): a chunk of device-sampled steps, or one), so sum over
+  // count is the steps a dispatch.
+  kPhaseDispatchSteps,
   // Set-up (OBSERVABILITY.md "Set-up phases"): once or a few times a
   // process, before train()'s first iteration. The leaves are self
   // times on one thread (a span that holds another records what is left
@@ -89,9 +93,9 @@ const char* const kPhaseNames[kPhaseCount] = {
     "host",        "step",     "compile",    "input_other",
     "dispatch",    "fence",    "hook",       "log_flush",
     "checkpoint",  "host_other", "stall",    "trace",
-    "lower",       "setup_graph_load",       "setup_table_export",
-    "setup_adjacency",         "setup_pack", "setup_upload",
-    "setup_state_place",
+    "lower",       "dispatch_steps",         "setup_graph_load",
+    "setup_table_export",      "setup_adjacency",
+    "setup_pack",  "setup_upload",           "setup_state_place",
 };
 
 // The program's periodic jobs. Each stamps the begin and the end of its

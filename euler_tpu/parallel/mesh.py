@@ -130,9 +130,11 @@ def make_mesh(
     )
 
 
-def batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Shard the leading (batch) dim over 'data' (replicated over 'model')."""
-    return NamedSharding(mesh, P("data"))
+def batch_sharding(mesh: Mesh, stacked: bool = False) -> NamedSharding:
+    """Shard the batch dim over 'data' (replicated over 'model'): the
+    leading dim, or, of batches ``stacked`` along a leading step axis,
+    the second."""
+    return NamedSharding(mesh, P(None, "data") if stacked else P("data"))
 
 
 def replicated_sharding(mesh: Mesh) -> NamedSharding:
@@ -339,15 +341,17 @@ def put_global(tree, shardings, consume: bool = False):
     return placed
 
 
-def shard_batch(batch, mesh: Mesh):
-    """Place a host batch pytree onto the mesh, leading dim sharded
-    (scalars — e.g. a device-sampling seed — are replicated).
+def shard_batch(batch, mesh: Mesh, stacked: bool = False):
+    """Place a host batch pytree onto the mesh, batch dim sharded
+    (scalars — e.g. a device-sampling seed — are replicated). A chunk of
+    batches ``stacked`` along a leading step axis shards its second dim
+    (``batch_sharding(mesh, stacked=True)``).
 
     Multi-process (jax.distributed): ``batch`` is this process's LOCAL
-    shard — leading dims concatenate across processes in process order,
+    shard — batch dims concatenate across processes in process order,
     so the global batch is num_processes x the local size. Scalars must
     be identical on every process (they replicate)."""
-    sharding = batch_sharding(mesh)
+    sharding = batch_sharding(mesh, stacked)
     rep = replicated_sharding(mesh)
     if jax.process_count() > 1:
         def put(x):

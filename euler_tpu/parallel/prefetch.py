@@ -69,6 +69,7 @@ def prefetch(
     profile: bool | None = None,
     record_sample: bool = True,
     stall_out: list | None = None,
+    step_of: Callable[[int], int] | None = None,
 ) -> Iterator[dict]:
     """Yield num_steps batches for steps start..start+num_steps, produced
     ahead of time by worker threads.
@@ -84,8 +85,13 @@ def prefetch(
     CLOCK_MONOTONIC µs at which each step's queue wait began and ended
     into ``stall_out[0:2]`` (train() places its ``input_other`` leaf on
     both sides of that wait).
+
+    ``step_of(item)`` is the step the spans of item ``item`` (0-based)
+    are labelled with, where items are not steps (train()'s chunks of
+    steps); default ``item + start``.
     """
     prof = _profiler() if profile in (None, True) else None
+    label = step_of or (lambda item: item + start)
     if start:
         base_make = make_batch
         make_batch = lambda step: base_make(step + start)  # noqa: E731
@@ -102,8 +108,8 @@ def prefetch(
                 t1 = _now_us()
                 record, gauges, count = prof
                 if record_sample:
-                    record("sample", t1 - t0, step=step + start, end_us=t1)
-                record("input_stall", t1 - t0, step=step + start,
+                    record("sample", t1 - t0, step=label(step), end_us=t1)
+                record("input_stall", t1 - t0, step=label(step),
                        end_us=t1)
                 if stall_out is not None:
                     stall_out[0], stall_out[1] = t0, t1
@@ -171,7 +177,7 @@ def prefetch(
             if prof is not None:
                 if record_sample:
                     t1 = _now_us()
-                    prof[0]("sample", t1 - t0, step=step + start,
+                    prof[0]("sample", t1 - t0, step=label(step),
                             end_us=t1)
                 prof[2]("prefetch_produced")
             with cv:
@@ -196,7 +202,7 @@ def prefetch(
             if prof is not None:
                 record, gauges, _ = prof
                 t_got = _now_us()
-                record("input_stall", t_got - t_wait, step=want + start,
+                record("input_stall", t_got - t_wait, step=label(want),
                        end_us=t_got)
                 if stall_out is not None:
                     stall_out[0], stall_out[1] = t_wait, t_got

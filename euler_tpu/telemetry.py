@@ -51,14 +51,16 @@ NUM_BUCKETS = 28
 # records by index through the eg_phase_record ABI, pinned by tests).
 # "compile", "trace" and "lower" are the device-plane add-on
 # (euler_tpu/devprof.py): what jax.monitoring times of a jit's way to an
-# executable, NOT part of the step-sum identity. The "setup_*" leaves are
-# recorded by :class:`setup_span` on the way to train()'s first step.
+# executable, NOT part of the step-sum identity. "dispatch_steps" holds a
+# value, not µs: the steps of each of train()'s dispatches (sum over
+# count is the steps a dispatch). The "setup_*" leaves are recorded by
+# :class:`setup_span` on the way to train()'s first step.
 PHASES = ("input_stall", "sample", "h2d", "device", "host", "step",
           "compile", "input_other", "dispatch", "fence", "hook",
           "log_flush", "checkpoint", "host_other", "stall",
-          "trace", "lower", "setup_graph_load", "setup_table_export",
-          "setup_adjacency", "setup_pack", "setup_upload",
-          "setup_state_place")
+          "trace", "lower", "dispatch_steps", "setup_graph_load",
+          "setup_table_export", "setup_adjacency", "setup_pack",
+          "setup_upload", "setup_state_place")
 _PHASE_INDEX = {name: i for i, name in enumerate(PHASES)}
 SETUP_PHASES = tuple(p for p in PHASES if p.startswith("setup_"))
 

@@ -14,6 +14,7 @@ import functools
 import inspect
 import logging
 import os
+import threading
 import time
 from typing import Callable, Optional
 
@@ -37,6 +38,14 @@ from euler_tpu.parallel import (
 )
 
 log = logging.getLogger("euler_tpu")
+
+# The most steps train() runs in one dispatch where the model draws its
+# batch on the device: enough that the host's ~1 ms a dispatch hides
+# under the device's steps (a 0.6 ms GraphSAGE step on a v5e).
+CHUNK_STEPS = 10
+# Under a step_hook the first steps are a dispatch each: the benchmark's
+# hook compares each of them with its reference.
+HOOK_SINGLE_STEPS = 3
 
 OPTIMIZERS = {
     "sgd": optax.sgd,
@@ -91,7 +100,7 @@ def _cache_keyed_with_metadata():
         jax.config.update(flag, before)
 
 
-def write_step_hlo(step_fn, state, batch, profile_dir: str) -> None:
+def write_step_hlo(step_fn, args, profile_dir: str) -> None:
     """Leave the compiled train step's HLO text in
     ``<profile_dir>/trace.STEP_HLO_FILE``: every instruction with the
     ``op_name`` it was traced under, which is how a reader of the capture
@@ -105,7 +114,7 @@ def write_step_hlo(step_fn, state, batch, profile_dir: str) -> None:
     from euler_tpu.trace import STEP_HLO_FILE
 
     with _cache_keyed_with_metadata():
-        compiled = step_fn.lower(state, batch).compile()
+        compiled = step_fn.lower(*args).compile()
     os.makedirs(profile_dir, exist_ok=True)
     with open(os.path.join(profile_dir, STEP_HLO_FILE), "w") as f:
         f.write(compiled.as_text())
@@ -119,6 +128,53 @@ def write_step_hlo(step_fn, state, batch, profile_dir: str) -> None:
             *(sizes[k] / 1e9
               for k in ("temp", "argument", "alias", "output")),
         )
+
+
+def _chunk_program(train_step, mesh):
+    """``chunk(state, batches, n) -> (state, loss, metrics, batch)``:
+    ``train_step`` over the first ``n`` of the CHUNK_STEPS batches
+    stacked along ``batches``' leading axis, in one program. ``loss`` is
+    the last step's, ``metrics`` the steps' metrics in CHUNK_STEPS rows
+    (rows from ``n`` on hold zeros) and ``batch`` the last step's batch.
+    Step k of a chunk computes what a dispatch of step k alone does: the
+    same batch, sharded as one, into the same step. The step is traced
+    once: a jit of its own, whose trace the result shapes and the loop
+    body share, so a chunk of one step and a chunk of ten are one
+    program."""
+    import jax.numpy as jnp
+
+    step = jax.jit(train_step)
+    one = batch_sharding(mesh)
+
+    def batch_at(batches, i):
+        return jax.tree.map(
+            lambda x: jax.lax.with_sharding_constraint(
+                jax.lax.dynamic_index_in_dim(x, i, keepdims=False), one),
+            batches)
+
+    def chunk(state, batches, n):
+        # (of the loop body's own types: the shapes' trace is its trace)
+        _, loss, metric = jax.eval_shape(step, state, batch_at(batches, 0))
+
+        def body(i, carry):
+            state, _, metrics = carry
+            state, loss, metric = step(state, batch_at(batches, i))
+            metrics = jax.tree.map(
+                lambda rows, m: rows.at[i].set(m), metrics, metric)
+            return state, loss, metrics
+
+        state, loss, metrics = jax.lax.fori_loop(0, n, body, (
+            state, jnp.zeros(loss.shape, loss.dtype),
+            jax.tree.map(
+                lambda m: jnp.zeros((CHUNK_STEPS, *m.shape), m.dtype),
+                metric)))
+        return state, loss, metrics, batch_at(batches, n - 1)
+
+    # the program, its HLO module and its compile events keep the step's
+    # name
+    chunk.__name__ = chunk.__qualname__ = getattr(
+        train_step, "__name__", "train_step")
+    return chunk
 
 
 def _kernel_mesh_scoped(fn):
@@ -165,33 +221,46 @@ def train(
 ):
     """Train and return (state, history).
 
-    step_hook(step) runs on the training thread after every dispatched
-    step (run_loop's --metrics_every JSONL emitter rides here; the hook
-    gates itself, so the per-step cost is one call + one modulo). A hook
-    that takes the keywords is fed what the step produced,
-    step_hook(step, state=, batch=, loss=) (looked up once, before the
-    loop: the benchmark's hook reads its first steps there); a hook that
-    returns True ends the loop after that step.
+    Where the model draws its batch on the device (``model.device_sampling``
+    and no remote pipeline), up to CHUNK_STEPS steps go up in one jitted
+    call (``_chunk_program``: one program, its step count an argument).
+    A dispatch ends early where the loop acts between two steps: a
+    hook's first HOOK_SINGLE_STEPS steps, the profiler's start and stop,
+    a log window's end, a checkpoint, the last step. Everywhere else a
+    dispatch is one step.
+
+    step_hook(step) runs on the training thread after every dispatch,
+    once for each of its steps (run_loop's --metrics_every JSONL emitter
+    rides here; the hook gates itself, so the per-step cost is one call
+    + one modulo). A hook that takes the keywords is fed what the
+    dispatch's last step produced, once a dispatch, step_hook(step,
+    state=, batch=, loss=) (looked up once, before the loop: the
+    benchmark's hook reads its first steps there); a hook that returns
+    True ends the loop after that dispatch.
 
     phase_profile records the step-phase histograms (OBSERVABILITY.md
     "Step phases"): input_stall + sample inside the prefetch pipeline,
     h2d (host->device transfer), device (the jitted call plus the fence
-    of the steps that have one: host wall, not device time — the
+    of the dispatches that have one: host wall, not device time — the
     device's share of a step is read from a capture's scopes), host
-    (optimizer/bookkeeping tail), and the whole-step wall. On this
+    (optimizer/bookkeeping tail), and the whole-iteration wall. On this
     thread the leaves input_stall, input_other, h2d, dispatch, hook,
-    fence, log_flush, checkpoint and host_other tile every iteration on
-    one clock (device = dispatch + fence and host = the other four are
-    kept as histograms), and a StallJournal journals the steps that
-    took several times the running median. Every leaf is a clock
+    fence, log_flush, checkpoint and host_other tile every iteration
+    (one dispatch) on one clock (device = dispatch + fence and host =
+    the other four are kept as histograms), ``dispatch_steps`` counts
+    each dispatch's steps, and a StallJournal journals the iterations
+    that took several times the running median. Every leaf is a clock
     reading, so recording changes nothing about when the loop
     synchronises. None (default) follows the telemetry kill-switch:
     profiling on when telemetry is on, none of it with `telemetry=0`.
 
-    The thread waits for the device once every 32 steps (`fence`: bounds
-    what is queued; every step on a virtual CPU mesh) and once a log
-    window (`log_flush`: one device-to-host pull of the window's
-    metrics and its last loss), with phase_profile on or off.
+    The thread waits for the device once every 32 steps (`fence`, after
+    the dispatch that passes a multiple, for the dispatch before it:
+    bounds what is queued; every dispatch, for itself, on a virtual CPU
+    mesh) and once a log window (`log_flush`: one device-to-host pull of
+    the window's metrics and its last loss, after the next dispatch),
+    with phase_profile on or off: the chip has the last dispatch's work
+    queued while the thread waits.
 
     source_fn(step) -> int64 root-node batch (fixed size, divisible by the
     mesh size). All sampling runs in the prefetch workers.
@@ -285,12 +354,69 @@ def train(
                 )
             if checkpoint_every <= 0:
                 checkpoint_every = max(num_steps // 10, 1)
-    step_fn = jax.jit(
-        model.make_train_step(opt),
-        in_shardings=(shardings, batch_sharding(mesh)),
-        out_shardings=(shardings, rep, rep),
-        donate_argnums=(0,),
+    # remote graphs: the native async pipeline (start_batch, below)
+    use_pipeline = (
+        sampler_depth > 0 and getattr(graph, "mode", None) == "remote"
     )
+    # A model that draws its fan-out on the device takes only roots and a
+    # seed from the host a step: its steps go up CHUNK_STEPS at a time,
+    # one dispatch each. Every other model samples on the host, a step a
+    # dispatch.
+    chunked = not use_pipeline and bool(getattr(model, "device_sampling",
+                                                False))
+    hook_is_fed = step_hook is not None and _takes_keywords(
+        step_hook, ("state", "batch", "loss"))
+    train_step = model.make_train_step(opt)
+    if chunked:
+        step_fn = jax.jit(
+            _chunk_program(train_step, mesh),
+            in_shardings=(shardings, batch_sharding(mesh, stacked=True),
+                          rep),
+            out_shardings=(shardings, rep, rep, batch_sharding(mesh)),
+            donate_argnums=(0,),
+        )
+        # every step count a dispatch can have, on the mesh once
+        step_counts = [shard_batch(np.int32(n), mesh)
+                       for n in range(CHUNK_STEPS + 1)]
+    else:
+        step_fn = jax.jit(
+            train_step,
+            in_shardings=(shardings, batch_sharding(mesh)),
+            out_shardings=(shardings, rep, rep),
+            donate_argnums=(0,),
+        )
+
+    def chunk_end(done: int) -> int:
+        """The step at which the dispatch that starts after ``done``
+        steps ends: CHUNK_STEPS on, or sooner where the loop has to act
+        between two steps (a hook's first steps, the profiler's start
+        and stop, a log window's end, a checkpoint, the last step)."""
+        ends = [done + CHUNK_STEPS, num_steps]
+        if log_every > 0:
+            ends.append(done + log_every - (done - start_step) % log_every)
+        if ckpt:
+            ends.append((done // checkpoint_every + 1) * checkpoint_every)
+        if profile_dir:
+            ends += [start_step + p for p in profile_steps
+                     if start_step + p > done]
+        if step_hook is not None:
+            ends += range(done + 1, HOOK_SINGLE_STEPS + 1)
+        return min(ends)
+
+    # chunk j's first step by j, filled in order as the workers claim
+    # them; the prefetch queue keeps the claims within its depth of one
+    # another, so the entries far behind the newest are dropped
+    chunk_starts = {0: start_step}
+    chunk_lock = threading.Lock()
+
+    def chunk_span(j: int) -> tuple:
+        with chunk_lock:
+            last = max(chunk_starts)
+            while last <= j:
+                chunk_starts[last + 1] = chunk_end(chunk_starts[last])
+                last += 1
+                chunk_starts.pop(last - 1024, None)
+            return chunk_starts[j], chunk_starts[j + 1]
 
     stall_out = journal = None
     if phase_profile:
@@ -327,32 +453,40 @@ def train(
     # on a virtual CPU mesh, transfer on the consumer thread.
     device_prefetch = not cpu_virtual_mesh
 
-    def make_batch(step):
-        # With device_prefetch, device_put runs here inside the prefetch
-        # worker, so the host->device copy of batch k+1 overlaps device
-        # compute of step k (the copy releases the GIL).
-        if not phase_profile:
-            batch = model.sample(graph, source_fn(step))
-            if device_prefetch:
-                batch = shard_batch(batch, mesh)
-                devprof.count_h2d(batch)
-            return batch
-        # prefetch applies the start offset before calling: step is
-        # already the absolute step index here
-        t0 = clock()
-        batch = model.sample(graph, source_fn(step))
-        return staged(batch, step, t0)
+    def make_batch(item):
+        """(batch, steps) of one dispatch: step ``item``'s batch, or,
+        chunked, chunk ``item``'s batches stacked (None past the last
+        step). With device_prefetch, device_put runs here inside the
+        prefetch worker, so the host->device copy of dispatch k+1
+        overlaps device compute of dispatch k (the copy releases the
+        GIL)."""
+        t0 = clock() if phase_profile else None
+        if not chunked:
+            # prefetch applies the start offset before calling: item is
+            # already the absolute step index here
+            return staged(model.sample(graph, source_fn(item)), item, t0), 1
+        first, end = chunk_span(item)
+        if first >= num_steps:
+            return None
+        batches = [model.sample(graph, source_fn(s))
+                   for s in range(first, end)]
+        # the slots past the chunk's steps are never run
+        batches += batches[-1:] * (CHUNK_STEPS - len(batches))
+        stacked = jax.tree.map(lambda *xs: np.stack(xs), *batches)
+        return staged(stacked, first, t0), end - first
 
     def staged(batch, step, t0):
         """A worker's spans of one produced batch: sample from t0, then
         (with device_prefetch) its h2d."""
-        t1 = clock()
-        record_phase("sample", t1 - t0, step=step, end_us=t1)
+        if phase_profile:
+            t1 = clock()
+            record_phase("sample", t1 - t0, step=step, end_us=t1)
         if device_prefetch:
-            batch = shard_batch(batch, mesh)
+            batch = shard_batch(batch, mesh, stacked=chunked)
             devprof.count_h2d(batch)
-            t2 = clock()
-            record_phase("h2d", t2 - t1, step=step, end_us=t2)
+            if phase_profile:
+                t2 = clock()
+                record_phase("h2d", t2 - t1, step=step, end_us=t2)
         return batch
 
     # Native async pipeline (remote graphs only): start_batch submits the
@@ -361,31 +495,31 @@ def train(
     # batch. The split rides the same phase-recording contract as
     # make_batch — "sample" here is the time spent WAITING on the handle,
     # so a fully-hidden pipeline reads as sample ~ 0 in the phase table.
-    use_pipeline = (
-        sampler_depth > 0 and getattr(graph, "mode", None) == "remote"
-    )
-
     def start_batch(step):
         return model.sample_start(graph, source_fn(step))
 
     def finish_batch(step, pending):
-        if not phase_profile:
-            batch = model.sample_finish(graph, pending)
-            if device_prefetch:
-                batch = shard_batch(batch, mesh)
-                devprof.count_h2d(batch)
-            return batch
-        t0 = clock()
-        batch = model.sample_finish(graph, pending)
-        return staged(batch, step, t0)
+        t0 = clock() if phase_profile else None
+        return staged(model.sample_finish(graph, pending), step, t0), 1
 
     name = model.metric_name
     history = []
     t0 = time.time()
     # Metrics stay on device inside the logging window — forcing them to
     # host every step would sync the pipeline and stall the prefetch overlap
-    # (JAX dispatch is async; only materialize at the log boundary).
+    # (JAX dispatch is async; only materialize at the log boundary). One
+    # entry a dispatch: a step's metric, or a chunk's stacked buffers of
+    # which its first `steps` rows were written.
     window_metrics = []
+    window_steps = []
+    window_start = start_step  # the step the window began after
+    # A closed window is pulled after the next dispatch, and the fence
+    # waits for the dispatch before the last (prev_loss): either way the
+    # last one's work is still queued while the thread waits, so the
+    # chip does not idle while it issues the next. (A virtual CPU mesh
+    # fences the last dispatch: see sync_every.)
+    closed = None
+    prev_loss = None
     # a model that counts events inside its step (Model.step_counters)
     # returns (metric, counts) for its metric: the counts ride the
     # window's one pull and are added into the native ledger there
@@ -395,13 +529,18 @@ def train(
     last_loss = None
     steps_done = start_step
 
-    def flush():
-        nonlocal window_metrics, t0
+    def flush(dispatched, steps, loss, end):
+        """Log and keep one window: its dispatches' metrics, their steps,
+        its last loss and the step it ended at."""
+        nonlocal t0
         # Metric/loss materialization is the training loop's d2h point:
         # one pull of the whole window, whose copies the loop started,
         # accumulated on the host.
-        devprof.count_d2h((window_metrics, last_loss))
-        metrics, loss = jax.device_get((window_metrics, last_loss))
+        devprof.count_d2h((dispatched, loss))
+        metrics, loss = jax.device_get((dispatched, loss))
+        if chunked:  # a step's metrics are a row of its chunk's
+            metrics = [jax.tree.map(lambda x, i=i: x[i], m)
+                       for m, n in zip(metrics, steps) for i in range(n)]
         if counter_names:
             metrics, counts = zip(*metrics)
             for cname, total in zip(
@@ -413,13 +552,12 @@ def train(
         loss_v = float(loss)
         mv = _metric_value(name, acc)
         dt = time.time() - t0
-        sps = len(window_metrics) / dt
+        sps = sum(steps) / dt
         history.append({"loss": loss_v, name: mv, "steps_per_sec": sps})
         (log_fn or log.info)(
-            f"step={steps_done} loss={loss_v:.4f} "
+            f"step={end} loss={loss_v:.4f} "
             f"{name}={mv:.4f} steps/s={sps:.2f}"
         )
-        window_metrics = []
         t0 = time.time()
 
     def seed_worker(widx: int):
@@ -454,26 +592,28 @@ def train(
     else:
         batches = prefetch(
             make_batch,
+            # chunked: as many chunks as there can be (one step each);
+            # the loop ends at the last step, before the items past it
             num_steps - start_step,
             prefetch_depth,
             prefetch_threads,
-            start=start_step,
+            start=0 if chunked else start_step,
             worker_init=seed_worker,
             profile=phase_profile,
             record_sample=False,  # make_batch above records sample/h2d
             stall_out=stall_out,
+            step_of=(lambda j: chunk_span(j)[0]) if chunked else None,
         )
-    hook_is_fed = step_hook is not None and _takes_keywords(
-        step_hook, ("state", "batch", "loss"))
     try:
-        for batch in batches:
+        for batch, n in batches:
             # With phase_profile the leaves tile this thread's iteration,
-            # each ending where the next begins (`mark`): input_other
-            # (since the last body's end, around the queue wait that
-            # prefetch recorded as input_stall) | h2d | dispatch | hook |
-            # fence (every sync_every-th step) | log_flush | checkpoint |
-            # host_other. `step` spans body end to body end.
-            cur = steps_done  # 0-based step index, matches prefetch labels
+            # one dispatch of n steps, each ending where the next begins
+            # (`mark`): input_other (since the last body's end, around
+            # the queue wait that prefetch recorded as input_stall) | h2d
+            # | dispatch | hook | fence (where a multiple of sync_every
+            # steps was passed) | log_flush | checkpoint | host_other.
+            # `step` spans body end to body end.
+            cur = steps_done  # 0-based first step, matches prefetch labels
             if profile_dir and steps_done - start_step == profile_steps[0]:
                 # the device lane of the capture holds the ops of the
                 # profiled steps and no others: nothing is still queued
@@ -506,18 +646,24 @@ def train(
                 record_phase_span("input_other", t_step, w0, cur)
                 record_phase_span("input_other", w1, mark, cur)
             if not device_prefetch:
-                batch = shard_batch(batch, mesh)
+                batch = shard_batch(batch, mesh, stacked=chunked)
                 devprof.count_h2d(batch)
                 if phase_profile:
                     mark = leaf("h2d", mark, cur, leaves)
+            args = (state, batch, step_counts[n]) if chunked else (
+                state, batch)
             if cur == start_step:  # this call compiles
                 with contextlib.ExitStack() as compiling:
                     if profile_dir:
                         compiling.enter_context(_cache_keyed_with_metadata())
                     compiling.enter_context(compiles_keep_layouts(shardings))
-                    state, last_loss, metric = step_fn(state, batch)
+                    out = step_fn(*args)
             else:
-                state, last_loss, metric = step_fn(state, batch)
+                out = step_fn(*args)
+            if chunked:  # and the batch its last step ran on, for the hook
+                state, last_loss, metric, batch = out
+            else:
+                state, last_loss, metric = out
             if cur == start_step and devprof.devprof_enabled():
                 # Relaunch-cost visibility: what set-up was made of, and
                 # the compiles (warm cache: ~0 ms on the second launch)
@@ -525,23 +671,27 @@ def train(
                     devprof.first_step_line(step_fn.__name__))
             if phase_profile:
                 mark = t_host = leaf("dispatch", mark, cur, leaves)
+                record_phase_hist("dispatch_steps", n)
             # the window's values start for the host as they are produced,
-            # so the flush finds all but the last step's there already
-            if counter_names:  # (metric, the step's counts)
-                metric[1].copy_to_host_async()
-                metric[0].copy_to_host_async()
-            else:
-                metric.copy_to_host_async()
+            # so the flush finds all but the last dispatch's there already
+            for x in jax.tree.leaves(metric):
+                x.copy_to_host_async()
             window_metrics.append(metric)
-            if len(window_metrics) == log_every:
+            window_steps.append(n)
+            steps_done += n
+            window_full = steps_done - window_start == log_every
+            if window_full:
                 last_loss.copy_to_host_async()
-            steps_done += 1
             if step_hook is not None:
                 if hook_is_fed:
                     hook_ends = step_hook(
                         steps_done, state=state, batch=batch, loss=last_loss)
                 else:
-                    hook_ends = step_hook(steps_done)
+                    # a hook of the step alone hears every step
+                    for done in range(cur + 1, steps_done + 1):
+                        hook_ends = step_hook(done)
+                        if hook_ends is True:
+                            break
                 if phase_profile:
                     mark = leaf("hook", mark, cur, leaves)
                 if hook_ends is True:
@@ -550,23 +700,30 @@ def train(
                 # after the first step's hook, which may read the compile
                 # ledger as of the first dispatch
                 with compiles_keep_layouts(shardings):
-                    write_step_hlo(step_fn, state, batch, profile_dir)
-            if steps_done % sync_every == 0:
-                jax.block_until_ready(last_loss)
+                    write_step_hlo(step_fn, (state, *args[1:]), profile_dir)
+            if steps_done // sync_every > cur // sync_every:
+                jax.block_until_ready(
+                    last_loss if cpu_virtual_mesh else prev_loss)
                 if phase_profile:
                     mark = leaf("fence", mark, cur, leaves)
-            if len(window_metrics) == log_every:
-                flush()
+            prev_loss = last_loss
+            if closed is not None:
+                flush(*closed)
+                closed = None
                 if phase_profile:
                     mark = leaf("log_flush", mark, cur, leaves)
+            if window_full:
+                closed = (window_metrics, window_steps, last_loss, steps_done)
+                window_metrics, window_steps = [], []
+                window_start = steps_done
             if ckpt and steps_done % checkpoint_every == 0:
                 ckpt.save(steps_done, state)
                 if phase_profile:
                     mark = leaf("checkpoint", mark, cur, leaves)
             if phase_profile:
                 now = leaf("host_other", mark, cur, leaves)
-                # the parents, one sample a step, cut from their leaves'
-                # own clock readings: device = dispatch + this step's
+                # the parents, one sample a dispatch, cut from their
+                # leaves' own clock readings: device = dispatch + its
                 # fence, if it has one; host = the rest since the dispatch
                 fence_us = leaves.get("fence", 0)
                 record_phase_hist("device", leaves["dispatch"] + fence_us)
@@ -583,11 +740,15 @@ def train(
                     f"profiler trace written to {profile_dir}")
                 if phase_profile:
                     t_step = clock()
+            if steps_done >= num_steps:
+                break
     finally:
         if journal is not None:
             journal.close()
+    if closed is not None:
+        flush(*closed)
     if window_metrics:  # final partial window
-        flush()
+        flush(window_metrics, window_steps, last_loss, steps_done)
     if profiling:
         jax.block_until_ready(last_loss)
         jax.profiler.stop_trace()

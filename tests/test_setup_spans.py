@@ -217,20 +217,39 @@ def test_an_unprofiled_run_lowers_and_compiles_its_step_once(
         graph, tmp_path, monkeypatch):
     """The temporaries gauge rides the compile a profiled run makes for
     the step's text: without ``profile_dir`` the step is traced, lowered
-    and compiled once, telemetry on or off."""
+    and compiled once, telemetry on or off. (A device-sampled step is
+    traced as a jit of its own inside the program that runs a chunk of
+    them, and jax stamps each call of it there: the step's own function
+    runs under a trace once.)"""
     model = run_loop.build_model(_args("unused"), graph)
-    written = []
+    written, traced = [], []
     real = train_lib.write_step_hlo
     monkeypatch.setattr(
         train_lib, "write_step_hlo",
         lambda *a, **k: (written.append(1), real(*a, **k)))
+    make = model.make_train_step
+
+    def counting(opt):
+        step = make(opt)
+
+        def train_step(state, batch):
+            traced.append(1)
+            return step(state, batch)
+
+        return train_step
+
+    monkeypatch.setattr(model, "make_train_step", counting)
     for on in (True, False):
         T.set_telemetry(on)
+        traced.clear()
         with _CompileCounter() as counted:
             _train(model, graph)
-        assert sorted(counted.seen) == [
+        assert traced == [1], on
+        assert sorted(set(counted.seen)) == [
             "backend_compile_duration", "jaxpr_to_mlir_module_duration",
             "jaxpr_trace_duration"], (on, counted.seen)
+        assert counted.seen.count("jaxpr_to_mlir_module_duration") == 1
+        assert counted.seen.count("backend_compile_duration") == 1
     assert written == []
     T.set_telemetry(True)
     assert T.telemetry_json()["resource"]["step_temp_bytes"] == 0

@@ -57,26 +57,30 @@ def _clean_slate():
     T.set_trace_sink(None)
 
 
-def _model():
+def _model(device_sampling=True):
     return SupervisedGraphSage(
         label_idx=2, label_dim=3, metapath=[[0, 1], [0, 1]],
         fanouts=[3, 2], dim=16, feature_idx=0, feature_dim=2,
-        max_id=MAX_ID, device_features=True, device_sampling=True,
+        max_id=MAX_ID, device_features=True,
+        device_sampling=device_sampling,
     )
 
 
-def _train(graph, steps, source_fn=None, **kw):
+def _train(graph, steps, source_fn=None, device_sampling=True, **kw):
+    """Device-sampled (a chunk of steps a dispatch) unless told not."""
     kw.setdefault("log_every", 4)
     return train_lib.train(
-        _model(), graph, source_fn or (lambda s: graph.sample_node(8, -1)),
+        _model(device_sampling), graph,
+        source_fn or (lambda s: graph.sample_node(8, -1)),
         num_steps=steps, learning_rate=0.01, optimizer="adam", **kw)
 
 
-def _assert_leaves_tile(main, num_steps):
-    """Of the training thread's spans: every step has its ``step`` span,
-    and its leaves lie inside it, overlap nowhere and cover it."""
+def _assert_leaves_tile(main, firsts):
+    """Of the training thread's spans: every dispatch (labelled by its
+    first step, ``firsts``) has its ``step`` span, and its leaves lie
+    inside it, overlap nowhere and cover it."""
     steps = {e[3]: e for e in main if e[0] == "step"}
-    assert sorted(steps) == list(range(num_steps))
+    assert sorted(steps) == list(firsts)
     for k, (_, s0, dur, _, _) in steps.items():
         leaves = sorted(
             (ts, ts + d) for name, ts, d, step, _ in main
@@ -395,26 +399,32 @@ def _step_hook(seen, end_at):
     return hook
 
 
-@pytest.mark.parametrize("make, end_at, steps", [
-    (_keyword_hook, 5, 5), (_keyword_hook, None, 12),
-    (_step_hook, 5, 5), (lambda seen, _: lambda step: seen.append((step,)),
-                         None, 12),
+@pytest.mark.parametrize("make, end_at, seen_steps", [
+    (_keyword_hook, 8, [1, 2, 3, 4, 8]),
+    (_keyword_hook, None, [1, 2, 3, 4, 8, 12]),
+    (_step_hook, 8, list(range(1, 9))),
+    (lambda seen, _: lambda step: seen.append((step,)), None,
+     list(range(1, 13))),
 ], ids=["keywords-ends", "keywords", "step_alone-ends", "lambda_step_none"])
 def test_step_hook_is_fed_where_it_takes_keywords_and_may_end_the_loop(
-        graph, make, end_at, steps):
+        graph, make, end_at, seen_steps):
     """A hook that takes ``state=``, ``batch=`` and ``loss=`` is handed
-    what each step produced; one that takes the step alone (run_loop's
-    metrics emitter, ``lambda step: None``) is called as before; either
-    ends the loop, through its ``finally``, by returning True: the
-    journal's collector callback is gone, the last partial log window is
-    flushed, and the state of the last step comes back."""
+    what each dispatch produced, its first three steps one dispatch
+    each (a device-sampled chunk ends at each log window's end here);
+    one that takes the step alone (run_loop's metrics emitter,
+    ``lambda step: None``) is called for every step, as before; either
+    ends the loop after that dispatch, through its ``finally``, by
+    returning True: the journal's collector callback is gone, the last
+    partial log window is flushed, and the state of the last step comes
+    back."""
     import gc
 
     seen = []
     callbacks = list(gc.callbacks)
     state, history = _train(graph, 12, step_hook=make(seen, end_at))
-    assert [s[0] for s in seen] == list(range(1, steps + 1))
+    assert [s[0] for s in seen] == seen_steps
     assert gc.callbacks == callbacks
+    steps = seen_steps[-1]
     # log_every 4: whole windows and the last partial one
     assert len(history) == -(-steps // 4)
     assert int(state["opt_state"][0].count) == steps    # Adam's own
@@ -424,11 +434,19 @@ def test_step_hook_is_fed_where_it_takes_keywords_and_may_end_the_loop(
             assert batch_keys and np.isfinite(loss)
 
 
-def test_leaves_tile_every_step_and_parents_are_their_sums(graph, tmp_path):
+@pytest.mark.parametrize("device_sampling, firsts", [
+    (False, range(24)),       # host-sampled: a step a dispatch
+    # chunks: the hook's first three steps, then cut at each log
+    # window's end
+    (True, [0, 1, 2, 3, 4, 8, 12, 16, 20]),
+], ids=["host_sampled", "device_sampled"])
+def test_leaves_tile_every_step_and_parents_are_their_sums(
+        graph, tmp_path, device_sampling, firsts):
     rec = TR.TraceRecorder().start()
     try:
         _train(graph, 24, step_hook=lambda step: None,
-               checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=8)
+               checkpoint_dir=str(tmp_path / "ckpt"), checkpoint_every=8,
+               device_sampling=device_sampling)
     finally:
         rec.stop()
     main = _loop_events(rec)
@@ -438,13 +456,19 @@ def test_leaves_tile_every_step_and_parents_are_their_sums(graph, tmp_path):
     # (h2d is this thread's on the tests' virtual CPU mesh, where train()
     # takes the copy out of the prefetch workers)
     assert names == {"step", *TRAIN_THREAD_LEAVES}
-    _assert_leaves_tile(main, 24)
+    _assert_leaves_tile(main, firsts)
 
     h = T.phase_hists()
+    # the leaves and parents are recorded once a dispatch
     for name in ("h2d", "dispatch", "fence", "hook", "host_other",
                  "input_other", "device", "host", "step"):
-        assert h[name]["count"] == 24, name
-    assert h["log_flush"]["count"] == 6 and h["checkpoint"]["count"] == 3
+        assert h[name]["count"] == len(firsts), name
+    # (a window is pulled after the next dispatch: the last one's, at 24,
+    # after the loop)
+    assert h["log_flush"]["count"] == 5 and h["checkpoint"]["count"] == 3
+    # the steps of each dispatch: a value histogram beside the leaves
+    assert h["dispatch_steps"]["count"] == len(firsts)
+    assert h["dispatch_steps"]["sum_us"] == 24
     for parent in ("device", "host"):
         kids = sum(h[c]["sum_us"] for c, p in T.PHASE_PARENT.items()
                    if p == parent)
@@ -482,6 +506,9 @@ def test_recorder_places_a_span_at_its_end_stamp():
 
 SYNC_STEPS = 70
 SYNC_LOG_EVERY = 20
+# the first steps of the device-sampled dispatches there: the hook's
+# first three steps, then ten steps on or to a log window's end
+CHUNK_FIRSTS = [0, 1, 2, 3, 13, 20, 30, 40, 50, 60]
 
 
 def _train_sync(graph, devices=1, **kw):
@@ -505,28 +532,32 @@ def _train_sync(graph, devices=1, **kw):
         blackbox.set_blackbox(recording)
 
 
-@pytest.mark.parametrize("devices, fenced", [
-    # a real device, or one CPU device: run-ahead bounded at 32 steps
-    (1, [31, 63]),
+@pytest.mark.parametrize("devices, device_sampling, firsts, fenced", [
+    # a real device, or one CPU device: run-ahead bounded at 32 steps,
+    # fenced by the dispatch that passes a multiple of 32
+    (1, False, range(SYNC_STEPS), [31, 63]),
+    (1, True, CHUNK_FIRSTS, [30, 60]),
     # a virtual CPU mesh: a queued step can starve a collective's
-    # rendezvous, so every step is fenced
-    (2, list(range(SYNC_STEPS))),
-])
+    # rendezvous, so every dispatch is fenced
+    (2, False, range(SYNC_STEPS), list(range(SYNC_STEPS))),
+    (2, True, CHUNK_FIRSTS, CHUNK_FIRSTS),
+], ids=["1-host_sampled", "1-device_sampled", "2-host_sampled",
+        "2-device_sampled"])
 def test_fence_spans_lie_on_the_sync_steps_and_leaves_tile(
-        graph, devices, fenced):
+        graph, devices, device_sampling, firsts, fenced):
     rec = TR.TraceRecorder().start()
     try:
         _train_sync(graph, devices, step_hook=lambda step: None,
-                    phase_profile=True)
+                    phase_profile=True, device_sampling=device_sampling)
     finally:
         rec.stop()
     main = _loop_events(rec)
     assert sorted(e[3] for e in main if e[0] == "fence") == fenced
     assert {e[0] for e in main} <= {"step", *TRAIN_THREAD_LEAVES}
-    _assert_leaves_tile(main, SYNC_STEPS)
+    _assert_leaves_tile(main, firsts)
     h = T.phase_hists()
     for name in ("dispatch", "device", "host", "step", "hook"):
-        assert h[name]["count"] == SYNC_STEPS, name
+        assert h[name]["count"] == len(firsts), name
     assert h["fence"]["count"] == len(fenced)
     assert h["log_flush"]["count"] == SYNC_STEPS // SYNC_LOG_EVERY
     # a parent is the sum of its leaves: of the same clock readings
@@ -568,8 +599,9 @@ def test_flush_pulls_a_window_from_the_device_once(graph, monkeypatch):
     _, history = _train_sync(graph, log_fn=lines.append, phase_profile=True)
     windows = [SYNC_LOG_EVERY] * (SYNC_STEPS // SYNC_LOG_EVERY) + [
         SYNC_STEPS % SYNC_LOG_EVERY]
-    # one pull a window: the window's metrics and its last loss together
-    assert [len(metrics) for metrics, _loss in pulls] == windows
+    # one pull a window: the window's metrics (a chunk's stacked rows a
+    # dispatch, ten steps each here) and its last loss together
+    assert [10 * len(metrics) for metrics, _loss in pulls] == windows
     assert all(isinstance(loss, jax.Array) for _metrics, loss in pulls)
     # nothing reaches the host-side accumulation as a device array
     assert len(on_host) == SYNC_STEPS and all(on_host)
@@ -646,7 +678,8 @@ def test_a_stalled_step_is_journalled_with_its_cause(graph):
             gc.collect(0)  # a young collection: listed, and cheap
 
     callbacks = list(gc.callbacks)
-    _train(graph, 40, step_hook=hook, log_every=100)
+    # host-sampled: the journal reads a step a dispatch
+    _train(graph, 40, step_hook=hook, log_every=100, device_sampling=False)
     assert gc.callbacks == callbacks  # the collector's callback is gone
     entries = T.stall_journal()
     # (a host loaded enough to stall another step by itself adds entries)
