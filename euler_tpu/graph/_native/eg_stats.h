@@ -221,6 +221,10 @@ enum CounterId : int {
   kCtrExpandSlots,
   kCtrExpandEdges,
   kCtrExpandOverflowNodes,
+  // The slots of those whose stored-table rows layer 0's messages read
+  // (models/gcn.py _slot_rows): the blocks of default parent rows are
+  // skipped, so its ratio to expand_slots is how often that engages.
+  kCtrExpandGatheredSlots,
   kCtrCount,
 };
 
@@ -243,6 +247,7 @@ const char* const kCounterNames[kCtrCount] = {
     "epoch_flips",        "epoch_drains",
     "epoch_stale_hits_evicted", "delta_loads_failed",
     "expand_slots",       "expand_edges",     "expand_overflow_nodes",
+    "expand_gathered_slots",
 };
 
 class Counters {

@@ -1023,7 +1023,12 @@ def multi_hop_neighbor(adjs, roots, node_caps):
     default id on a padded slot), which is ``nodes[dst]`` on every slot
     whose rank fits under the cap: a reader of the slots' ids takes them
     from here and leaves the set, the sort and the rank dead where
-    nothing else reads them (models/gcn.py ``_slot_rows``).
+    nothing else reads them (models/gcn.py ``_slot_rows``). "real":
+    int32, the non-default entries of "nodes", which are its prefix (the
+    default id sorts last); the next hop's slots are laid ``[cap, W]``
+    row-major by parent, so the slots of its real parent rows are the
+    same prefix of its ``ids``, and a default parent row's slots all hold
+    the default id (models/gcn.py ``_slot_rows`` reads only that prefix).
 
     Whether a hop's cap can bind is decided from the static shapes: with
     ``cap >= C*W`` no rank reaches the cap (a hop has at most C*W unique
@@ -1095,6 +1100,7 @@ def multi_hop_neighbor(adjs, roots, node_caps):
                 "w": mask,
                 "edges": jnp.sum(mask),
                 "overflow": overflow,
+                "real": jnp.sum(nodes != default, dtype=jnp.int32),
             }
         )
         sizes.append(C * W)

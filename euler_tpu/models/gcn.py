@@ -33,6 +33,11 @@ from euler_tpu.nn.encoders import GCNEncoder, ShallowEncoder
 
 log = logging.getLogger("euler_tpu")
 
+# The most slots one block of layer 0's blocked message gather reads
+# (``_SupervisedGCNModule._slot_rows``): a block is as many whole parent
+# rows as fit under it.
+SLOT_BLOCK = 65536
+
 
 @functools.lru_cache(maxsize=64)
 def _log_message_route(hop: int, slots: int, route: str) -> None:
@@ -40,6 +45,14 @@ def _log_message_route(hop: int, slots: int, route: str) -> None:
     graph/device.py says its draw and expand paths): where layer 0's
     messages of a hop's edge list are gathered from."""
     log.info("message path: hop %d %d slots -> %s", hop, slots, route)
+
+
+@functools.lru_cache(maxsize=64)
+def _log_slot_gather(hop: int, slots: int, blocks: int, rows: int) -> None:
+    """One line per distinct shape, said while tracing: a hop whose
+    stored-table messages are gathered a block of parent rows at a time."""
+    log.info("slot gather: hop %d %d slots -> %d blocks of %d rows, while "
+             "the set's real rows last", hop, slots, blocks, rows)
 
 
 class _SupervisedGCNModule(nn.Module):
@@ -96,16 +109,20 @@ class _SupervisedGCNModule(nn.Module):
         return feats, hops
 
     @staticmethod
-    def _expand_counters(adjs):
-        """[slots, true edges, unique nodes past a cap] of a device
-        expansion (SupervisedGCN.step_counters); None for host-built
-        adjacencies, whose expansion raises where a cap does not hold."""
+    def _expand_counters(adjs, gathered):
+        """[slots, true edges, unique nodes past a cap, slots whose rows
+        layer 0's messages read] of a device expansion
+        (SupervisedGCN.step_counters); None for host-built adjacencies,
+        whose expansion raises where a cap does not hold. ``gathered``:
+        the last of these, or None where every slot's row is read."""
         if not all("overflow" in a for a in adjs):
             return None
+        slots = jnp.float32(sum(a["mask"].shape[0] for a in adjs))
         return jnp.stack([
-            jnp.float32(sum(a["mask"].shape[0] for a in adjs)),
+            slots,
             sum(a["edges"] for a in adjs),
             sum(a["overflow"] for a in adjs).astype(jnp.float32),
+            slots if gathered is None else gathered,
         ])
 
     def _hop_rows_why(self, batch, consts):
@@ -146,30 +163,76 @@ class _SupervisedGCNModule(nn.Module):
             )
             for f in (hops[:-1] if one_pass else hops)
         ]
-        first_neigh = None
+        first_neigh = gathered = None
         if one_pass:
             hidden.append(None)
-            first_neigh = [
-                self._slot_rows(adj["ids"], consts["features"])
-                for adj in adjs
-            ]
+            first_neigh, gathered = [], jnp.float32(0)
+            for h, adj in enumerate(adjs):
+                rows, read = self._slot_rows(
+                    h + 1, adj["ids"], consts["features"],
+                    adjs[h - 1] if h else None)
+                first_neigh.append(rows)
+                gathered = gathered + read
         embedding = self.encoder(hidden, adjs, first_neigh)
-        return embedding, hops, self._expand_counters(adjs)
+        return embedding, hops, self._expand_counters(adjs, gathered)
 
-    def _slot_rows(self, ids, table):
+    def _slot_rows(self, hop, ids, table, parents=None):
         """Layer 0's messages of one hop's edge list, before the mask:
         the stored table's row of every slot's own node (the expansion's
-        ``ids``, which is ``nodes[dst]`` on every unmasked slot) in one
-        pass, the pad lanes cut after that gather (never between two
-        gathers: a 50-wide intermediate is laid column-major and a row
-        gather out of it reads a row across 50 separated columns,
-        PERF.md section 6, PR 38). ``table[nodes][..., :F][dst]`` to the
-        bit on every unmasked slot. Nothing else of the step reads the
-        outer hop's set, so where its cap cannot bind its sort and rank
-        are dead code (graph/device.py ``multi_hop_neighbor``)."""
+        ``ids``, which is ``nodes[dst]`` on every unmasked slot), the pad
+        lanes cut after that gather (never between two gathers: a 50-wide
+        intermediate is laid column-major and a row gather out of it
+        reads a row across 50 separated columns, PERF.md section 6).
+        ``table[nodes][..., :F][dst]`` to the bit on every unmasked slot.
+        Nothing else of the step reads the outer hop's set, so where its
+        cap cannot bind its sort and rank are dead code (graph/device.py
+        ``multi_hop_neighbor``). Returns the rows and the number of slots
+        whose rows were read.
+
+        Where the hop's parents are a previous hop's padded set
+        (``parents``) of more than one block, only the blocks that hold
+        a real parent row are gathered, in a ``while`` loop: the set's
+        real rows are its prefix (``parents["real"]`` of them) and the
+        slots are laid ``[C, W]`` by parent, so every slot after them
+        holds the default id, and keeps the default row the buffer was
+        filled with, which is what one pass reads there: the result is
+        the one-pass gather's to the bit. Elsewhere (hop 1, whose parents
+        are the roots, or a set of one block) one pass over every slot.
+        """
+        slots = ids.shape[0]
+        if parents is not None:
+            C = parents["nodes"].shape[0]
+            W = slots // C
+            # parent rows a block: the largest divisor of C whose slots
+            # fit under SLOT_BLOCK, so the blocks tile the set
+            B = next(b for b in range(min(C, max(1, SLOT_BLOCK // W)), 0, -1)
+                     if C % b == 0)
+        if parents is None or B == C:
+            with jax.named_scope("gather_features"):
+                rows = base.gather_rows(table, ids, self.feature_dim)
+            return sparse_aggregators.SlotRows(rows), jnp.float32(slots)
+        _log_slot_gather(hop, slots, C // B, B)
+        blocks = (parents["real"] + B - 1) // B
+
+        def gather_block(i, buf):
+            # scoped inside the body: the ``while`` itself carries no
+            # scope, or its event would count its body's time again
+            with jax.named_scope("gather_features"):
+                start = i * (B * W)
+                block = jax.lax.dynamic_slice(ids, (start,), (B * W,))
+                return jax.lax.dynamic_update_slice(
+                    buf, table[block], (start, 0))
+
         with jax.named_scope("gather_features"):
-            rows = base.gather_rows(table, ids, self.feature_dim)
-        return sparse_aggregators.SlotRows(rows)
+            # the default row: where a default parent row is left unread,
+            # the last slot is one of its slots and holds the default id
+            buf = jnp.broadcast_to(table[ids[-1]], (slots, table.shape[1]))
+        buf = jax.lax.fori_loop(0, blocks, gather_block, buf)
+        with jax.named_scope("gather_features"):
+            # cut and cast after the loop, as base.gather_rows does
+            rows = buf[:, :self.feature_dim].astype(jnp.float32)
+        read = (blocks * (B * W)).astype(jnp.float32)
+        return sparse_aggregators.SlotRows(rows), read
 
     def embed(self, batch, consts=None):
         return self._forward(batch, consts)[0]
@@ -232,9 +295,12 @@ class SupervisedGCN(base.Model):
         )
         self.init_device_sampling(device_sampling)
         if self.device_sampling:
-            # a hop past its static cap drops nodes: counted in the step
+            # a hop past its static cap drops nodes, and layer 0's
+            # messages skip the blocks of default parent rows: counted
+            # in the step
             self.step_counters = (
-                "expand_slots", "expand_edges", "expand_overflow_nodes"
+                "expand_slots", "expand_edges", "expand_overflow_nodes",
+                "expand_gathered_slots",
             )
         self.label_idx = label_idx
         self.label_dim = label_dim

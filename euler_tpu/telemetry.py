@@ -738,6 +738,12 @@ _COUNTER_FAMILIES = {
                               "Unique neighbours a hop's static cap had "
                               "no room for, dropped with their edges; 0 "
                               "where the whole neighbourhood is kept"),
+    "expand_gathered_slots": ("eg_expand_gathered_slots",
+                              "Slots of eg_expand_slots whose stored-table "
+                              "rows layer 0's messages read (models/gcn.py "
+                              "_slot_rows skips the blocks of default "
+                              "parent rows): their ratio is how often the "
+                              "skip engages"),
 }
 
 

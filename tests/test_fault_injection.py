@@ -62,6 +62,8 @@ COUNTER_NAMES = {
     # full-neighbourhood expansion ledger (PR 37): padded slots, true
     # edges and unique neighbours past a hop's cap, counted in the step
     "expand_slots", "expand_edges", "expand_overflow_nodes",
+    # the slots of those whose rows layer 0's messages read
+    "expand_gathered_slots",
 }
 FAULT_NAMES = {
     "dial", "send_frame", "recv_frame", "service_reply", "registry_reply",

@@ -221,6 +221,9 @@ def test_an_unprofiled_run_lowers_and_compiles_its_step_once(
     traced as a jit of its own inside the program that runs a chunk of
     them, and jax stamps each call of it there: the step's own function
     runs under a trace once.)"""
+    # the gauge outlives a telemetry reset (it is set once, at a compile):
+    # a profiled run earlier in this process would have left it set
+    devprof.lib().eg_devprof_set_step_temp(0)
     model = run_loop.build_model(_args("unused"), graph)
     written, traced = [], []
     real = train_lib.write_step_hlo

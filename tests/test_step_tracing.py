@@ -161,7 +161,8 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
     from the stored table one a slot."""
     m = _gcn_model()
     assert m.step_counters == (
-        "expand_slots", "expand_edges", "expand_overflow_nodes")
+        "expand_slots", "expand_edges", "expand_overflow_nodes",
+        "expand_gathered_slots")
     opt = train_lib.get_optimizer("adam", 0.01)
     roots = graph.sample_node(8, -1)
     state = m.init_state(jax.random.PRNGKey(0), graph, roots, opt)
@@ -194,12 +195,14 @@ def test_lowered_gcn_step_holds_the_expansion_scopes(graph):
     assert not any("/segment_agg/" in ln and "dot_general" in ln
                    for ln in lines)
     assert not any("/expand/" in ln and "/segment_agg/" in ln for ln in lines)
-    # the step's counts leave beside the metric: slots, true edges, and
-    # the unique neighbours past a cap (none: the caps hold)
+    # the step's counts leave beside the metric: slots, true edges, the
+    # unique neighbours past a cap (none: the caps hold), and the slots
+    # whose rows layer 0's messages read (all: hop 1's set of 32 rows
+    # fits in one block, so both hops take one pass)
     _, _, (metric, counts) = step(state, m.sample(graph, roots))
-    slots, edges, overflow = np.asarray(counts)
+    slots, edges, overflow, gathered = np.asarray(counts)
     assert metric.shape == (3,) and overflow == 0
-    assert 0 < edges <= slots
+    assert 0 < edges <= slots and gathered == slots
 
 
 @pytest.mark.parametrize("one_pass", [True, False],
