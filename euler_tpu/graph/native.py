@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import os
 import subprocess
 import threading
@@ -11,10 +12,7 @@ _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_native"
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libeuler_graph.so")
 
 _lib = None
-# The first caller builds and loads; a second thread that arrives
-# meanwhile (devprof's sampler ticks a second after start-up) must wait
-# for it, not start a second make over the same objects: the loser of
-# that race dlopen()ed a half-written library ("file too short").
+# one load a process: a thread that arrives while another loads waits
 _lib_lock = threading.Lock()
 
 
@@ -42,10 +40,20 @@ def build_native(force: bool = False) -> str:
         # run — keep the instrumented library (a plain make here would
         # rebuild normal and make the run pass vacuously)
         return _LIB_PATH
-    subprocess.run(
-        ["make", "-s", "-j"] + (["-B"] if force else []),
-        cwd=_NATIVE_DIR, check=True, capture_output=True, text=True,
-    )
+    # One make at a time over these objects, from every process that
+    # imports the package (test workers, benchmark phases, their
+    # subprocesses): each call holds an exclusive lock on the Makefile.
+    # Two makes at once over a stale tree compiled the same objects
+    # twice, and the later link rewrote the library while another
+    # process dlopen()ed it ("file too short"). The Makefile links to a
+    # temporary name and renames it over the library, so a process that
+    # has it mapped keeps the file it loaded.
+    with open(os.path.join(_NATIVE_DIR, "Makefile")) as makefile:
+        fcntl.flock(makefile, fcntl.LOCK_EX)
+        subprocess.run(
+            ["make", "-s", "-j"] + (["-B"] if force else []),
+            cwd=_NATIVE_DIR, check=True, capture_output=True, text=True,
+        )
     return _LIB_PATH
 
 

@@ -376,13 +376,12 @@ class Model:
         graph,
         edge_type_sets,
         negs_type: Optional[int] = None,
-        roots_type: Optional[int] = None,
         max_degree: Optional[int] = None,
         sorted: bool = False,
     ) -> dict:
         """Upload the device-sampling structures: one adjacency slab per
-        DISTINCT edge-type set plus optional typed node samplers for
-        negatives and scan-loop roots (aliased when the types match).
+        DISTINCT edge-type set plus an optional typed node sampler for
+        negatives.
         ``max_degree`` caps the slab width on heavy-tailed graphs
         (heaviest neighbors kept, build_adjacency warns); ``sorted``
         builds id-sorted rows (under their own keys) for
@@ -489,13 +488,6 @@ class Model:
             consts["negs"] = device_graph.build_node_sampler(
                 graph, negs_type, self.max_id
             )
-        if roots_type is not None:
-            if negs_type == roots_type and "negs" in consts:
-                consts["roots"] = consts["negs"]
-            else:
-                consts["roots"] = device_graph.build_node_sampler(
-                    graph, roots_type, self.max_id
-                )
         return consts
 
     def device_sample_batch(self, inputs) -> dict:

@@ -131,10 +131,7 @@ class GAT(base.Model):
     def build_consts(self, graph) -> dict:
         consts = super().build_consts(graph)
         if self.device_sampling:
-            self.add_sampling_consts(
-                consts, graph, [self.edge_type],
-                roots_type=self.train_node_type,
-            )
+            self.add_sampling_consts(consts, graph, [self.edge_type])
         return consts
 
     def sample(self, graph, inputs) -> dict:

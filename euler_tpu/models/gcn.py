@@ -522,7 +522,6 @@ class ScalableGCN(base.ScalableStoreModel):
             # batch to B x global-max-degree
             self.add_sampling_consts(
                 consts, graph, [self.edge_type],
-                roots_type=self.train_node_type,
                 max_degree=self.max_neighbors,
             )
         return consts

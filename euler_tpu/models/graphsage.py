@@ -168,10 +168,7 @@ class SupervisedGraphSage(base.Model):
     def build_consts(self, graph) -> dict:
         consts = super().build_consts(graph)
         if self.device_sampling:
-            self.add_sampling_consts(
-                consts, graph, self.metapath,
-                roots_type=self.train_node_type,
-            )
+            self.add_sampling_consts(consts, graph, self.metapath)
         return consts
 
     def _batch_from_hops(self, graph, inputs, ids_per_hop) -> dict:
@@ -357,10 +354,7 @@ class ScalableSage(base.ScalableStoreModel):
     def build_consts(self, graph) -> dict:
         consts = super().build_consts(graph)
         if self.device_sampling:
-            self.add_sampling_consts(
-                consts, graph, [self.edge_type],
-                roots_type=self.train_node_type,
-            )
+            self.add_sampling_consts(consts, graph, [self.edge_type])
         return consts
 
     def _expand_batch(self, batch, consts):
@@ -585,11 +579,10 @@ class GraphSage(base.Model):
     def build_consts(self, graph) -> dict:
         consts = super().build_consts(graph)
         if self.device_sampling:
-            # typed negatives (reference: global sample_node(node_type));
-            # scan-loop roots alias the same typed sampler
+            # typed negatives (reference: global sample_node(node_type))
             self.add_sampling_consts(
                 consts, graph, self.metapath + [self.edge_type],
-                negs_type=self.node_type, roots_type=self.node_type,
+                negs_type=self.node_type,
             )
         return consts
 

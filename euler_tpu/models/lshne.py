@@ -322,9 +322,7 @@ class LsHNE(base.Model):
             for pattern in patterns
             for step in pattern
         ]
-        self.add_sampling_consts(
-            consts, graph, step_sets, roots_type=self.node_type
-        )
+        self.add_sampling_consts(consts, graph, step_sets)
         consts["tsampler"] = device_graph.build_typed_node_sampler(
             graph, self.src_type_num, self.max_id
         )

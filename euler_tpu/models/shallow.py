@@ -224,7 +224,7 @@ class _ShallowUnsupervised(base.Model):
         if self.device_sampling:
             self.add_sampling_consts(
                 consts, graph, [self.edge_type],
-                negs_type=self.node_type, roots_type=self.node_type,
+                negs_type=self.node_type,
                 sorted=self.adj_sorted,
             )
         return consts

@@ -775,48 +775,6 @@ def _takes_keywords(fn, names) -> bool:
         if p.kind in (p.POSITIONAL_OR_KEYWORD, p.KEYWORD_ONLY)}
 
 
-def make_scan_train(model, optimizer, inner_steps: int, batch_size: int):
-    """Fully-device training: ``inner_steps`` train steps per dispatch.
-
-    Requires a device-sampling model (consts carry the adjacency slabs and
-    the ``roots`` node sampler): roots are drawn on device, the fanout is
-    sampled on device, and `lax.scan` chains the steps, so ONE host
-    dispatch runs a whole chunk — host work and dispatch latency amortize
-    to ~zero. This is the TPU-native training loop shape (the reference
-    pays a host round trip per op per step through its AsyncOpKernels).
-
-    Returns ``scan_fn(state, seed) -> (state, losses[inner_steps])`` to be
-    jitted by the caller (donate state for buffer reuse). Note: roots are
-    drawn from the replicated sampler identically on every device, so use
-    this on a single chip or shard the scan externally; the per-step
-    (host-rooted) path covers data-parallel meshes.
-    """
-    import jax.numpy as jnp
-
-    step = model.make_train_step(optimizer)
-
-    def scan_fn(state, seed):
-        base_key = jax.random.PRNGKey(seed)
-
-        def body(state, i):
-            key = jax.random.fold_in(base_key, i)
-            roots = device_graph.sample_node(
-                state["consts"]["roots"], key, batch_size
-            )
-            batch = {
-                "roots": roots,
-                "seed": jnp.full(
-                    (batch_size,), seed * inner_steps + i, jnp.int32
-                ),
-            }
-            state, loss, _ = step(state, batch)
-            return state, loss
-
-        return jax.lax.scan(body, state, jnp.arange(inner_steps))
-
-    return scan_fn
-
-
 @_kernel_mesh_scoped
 def evaluate(
     model,

@@ -274,12 +274,10 @@ def test_sampling_alias_option_builds_sorted_alias(powerlaw_graph):
     assert np.isfinite(float(loss))
 
 
-def test_node2vec_scan_train(powerlaw_graph):
-    """Whole-chunk device training (make_scan_train) composes with the
-    rejection-sampled walk: roots drawn on device, walks + pairs +
-    negatives inside one lax.scan dispatch."""
-    import jax
-
+def test_node2vec_chunked_train(powerlaw_graph):
+    """train()'s chunked dispatch composes with the rejection-sampled
+    walk: walks, pairs and negatives drawn on the device inside one
+    program of up to CHUNK_STEPS steps."""
     from euler_tpu import train as train_lib
     from euler_tpu.models import Node2Vec
 
@@ -290,15 +288,13 @@ def test_node2vec_scan_train(powerlaw_graph):
         device_features=True, feature_idx=-1,
     )
     model.set_sampling_options(alias=True)
-    opt = train_lib.get_optimizer("adam", 0.01)
-    state = model.init_state(
-        jax.random.PRNGKey(0), g, g.sample_node(16, -1), opt
+    losses = []
+    train_lib.train(
+        model, g, lambda step: g.sample_node(16, -1), 10,
+        log_every=10,
+        step_hook=lambda step, state=None, batch=None, loss=None:
+            losses.append(float(loss)),
     )
-    scan = jax.jit(
-        train_lib.make_scan_train(model, opt, 5, 16), donate_argnums=(0,)
-    )
-    state, l1 = scan(state, 1)
-    state, l2 = scan(state, 2)
-    l2 = np.asarray(jax.device_get(l2))
-    assert l2.shape == (5,)
-    assert np.isfinite(l2).all() and (l2 > 0).all()
+    # the hook's three single first steps, then one chunk of seven
+    assert len(losses) == 4, losses
+    assert np.isfinite(losses).all() and (np.asarray(losses) > 0).all()

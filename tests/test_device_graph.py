@@ -477,9 +477,11 @@ def test_supervised_sage_device_sampling_trains(graph):
     assert np.isfinite(res["loss"])
 
 
-def test_scan_train_runs_fully_on_device(graph):
-    """make_scan_train: K steps per dispatch, roots sampled on device;
-    losses must be finite and the state must advance."""
+def test_chunked_train_runs_fully_on_device(graph):
+    """train() on a device-sampled model: up to CHUNK_STEPS steps a
+    dispatch, the fan-out drawn on the device; losses must be finite,
+    the params must move, and there must be fewer dispatches than steps
+    (a hook that takes the keywords is called once a dispatch)."""
     import jax
 
     from euler_tpu import train as train_lib
@@ -494,26 +496,27 @@ def test_scan_train_runs_fully_on_device(graph):
     state = m.init_state(
         jax.random.PRNGKey(0), graph, graph.sample_node(8, -1), opt
     )
-    scan = jax.jit(
-        train_lib.make_scan_train(m, opt, inner_steps=5, batch_size=8),
-        donate_argnums=(0,),
-    )
     p0 = np.asarray(
         jax.tree_util.tree_leaves(state["params"])[0]
     ).copy()
-    state, losses = scan(state, 0)
-    state, losses = scan(state, 1)
-    losses = np.asarray(losses)
-    assert losses.shape == (5,)
+    losses = []
+    state, _ = train_lib.train(
+        m, graph, lambda s: graph.sample_node(8, -1), 10,
+        learning_rate=0.01, optimizer="adam", log_every=10, state=state,
+        step_hook=lambda step, state=None, batch=None, loss=None:
+            losses.append(float(loss)),
+    )
+    # the hook's three single first steps, then one chunk of seven
+    assert len(losses) == 4, losses
     assert np.isfinite(losses).all()
     p1 = np.asarray(jax.tree_util.tree_leaves(state["params"])[0])
     assert not np.allclose(p0, p1)  # training actually moved the params
 
 
 def test_device_sampling_model_parallel_mesh(graph):
-    """The sampler consts must survive a (data x model) mesh: adjacency /
-    root-sampler arrays replicate (never padded/row-sharded), tables
-    shard — regression for the searchsorted-corruption hazard."""
+    """The sampler consts must survive a (data x model) mesh: adjacency
+    arrays replicate (never padded/row-sharded), tables shard —
+    regression for the searchsorted-corruption hazard."""
     import jax
 
     from euler_tpu import train as train_lib
@@ -532,10 +535,12 @@ def test_device_sampling_model_parallel_mesh(graph):
     state = m.init_state(
         jax.random.PRNGKey(0), graph, graph.sample_node(8, -1), opt
     )
-    roots_len = state["consts"]["roots"]["prob"].shape[0]
+    adj_rows = {k: a["cum"].shape[0]
+                for k, a in state["consts"]["adj"].items()}
     state = pad_tables_for_mesh(state, mesh)
     # sampler arrays unpadded, feature table padded to the model axis
-    assert state["consts"]["roots"]["prob"].shape[0] == roots_len
+    assert {k: a["cum"].shape[0]
+            for k, a in state["consts"]["adj"].items()} == adj_rows
     assert state["consts"]["features"].shape[0] % 2 == 0
     shardings = state_sharding(mesh, state)
     state = jax.device_put(state, shardings)
@@ -638,9 +643,7 @@ def test_device_sampling_model_families(graph, family):
     (device positives + typed negatives), GAT (device attention
     neighborhood), ScalableSage (device 1-hop + store scatter), LINE
     (device positives), Node2Vec (device walks -> skip-gram pairs). Each
-    trains via the standard loop AND the fully-device scanned loop."""
-    import jax
-
+    trains through train(), up to CHUNK_STEPS steps a dispatch."""
     from euler_tpu import train as train_lib
     from euler_tpu import models
 
@@ -700,18 +703,6 @@ def test_device_sampling_model_families(graph, family):
     )
     res = train_lib.evaluate(m, graph, [np.arange(16)], state)
     assert np.isfinite(res["loss"])
-
-    # fully-device scanned loop
-    opt = train_lib.get_optimizer("adam", 0.01)
-    state = m.init_state(
-        jax.random.PRNGKey(0), graph, graph.sample_node(8, -1), opt
-    )
-    scan = jax.jit(
-        train_lib.make_scan_train(m, opt, inner_steps=4, batch_size=8),
-        donate_argnums=(0,),
-    )
-    state, losses = scan(state, 0)
-    assert np.isfinite(np.asarray(losses)).all()
 
 
 def _analytic_biased_joint(adj, root, p, q):
@@ -794,8 +785,6 @@ def test_biased_walk_rows_must_be_sorted(graph):
 def test_node2vec_biased_device_sampling_trains(graph):
     """Node2Vec with p/q != 1 runs the biased walk on device end-to-end
     (this configuration raised before)."""
-    import jax
-
     from euler_tpu import models
     from euler_tpu import train as train_lib
 
@@ -811,18 +800,6 @@ def test_node2vec_biased_device_sampling_trains(graph):
         num_steps=6, learning_rate=0.01, log_every=3,
     )
     assert np.isfinite(hist[-1]["loss"])
-
-    # fully-device scanned loop
-    opt = train_lib.get_optimizer("adam", 0.01)
-    state = m.init_state(
-        jax.random.PRNGKey(0), graph, graph.sample_node(8, -1), opt
-    )
-    scan = jax.jit(
-        train_lib.make_scan_train(m, opt, inner_steps=4, batch_size=8),
-        donate_argnums=(0,),
-    )
-    state, losses = scan(state, 0)
-    assert np.isfinite(np.asarray(losses)).all()
 
 
 def _assert_hops_match_host(h_hops, d_hops, roots):

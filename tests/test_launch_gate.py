@@ -3,7 +3,8 @@ and nothing downgrades it. The launchers that start a chip job have no
 path to the CPU, chip_smoke.py refuses to run without a TPU, one place
 decides the compile cache, the Pallas backend checks do not swallow
 errors, and the native build rebuilds when forced or when its objects
-came from another host. chip_smoke.py and scripts/ are not part of the
+came from another host, one make at a time, never rewriting a library a
+process has loaded. chip_smoke.py and scripts/ are not part of the
 package, so their contracts get tests here.
 """
 
@@ -247,3 +248,63 @@ def test_native_objects_from_another_host_are_rebuilt(tmp_path):
     assert not os.path.exists(os.path.join(copy, "libeuler_graph.so"))
     with open(os.path.join(copy, ".flavor")) as f:
         assert f.read().split() == [flavor, host]
+
+
+def _native_copy(tmp_path, touch):
+    """A built copy of the native tree whose ``touch`` source is newer
+    than its object: the next make compiles it and links again."""
+    from euler_tpu.graph import native
+
+    native.lib()
+    copy = str(tmp_path / "_native")
+    shutil.copytree(native._NATIVE_DIR, copy)
+    later = time.time() + 5
+    os.utime(os.path.join(copy, touch), (later, later))
+    return copy
+
+
+def test_a_relink_leaves_the_loaded_library_in_place(tmp_path):
+    """ld writes its output in place: a process that has the library
+    mapped would run code that changes under it. The link goes to
+    another name and is renamed over the library, so the file a process
+    loaded is never written again."""
+    copy = _native_copy(tmp_path, "eg_capi.cc")
+    so = os.path.join(copy, "libeuler_graph.so")
+    before = os.stat(so)
+    subprocess.run(["make", "-s", "-j"], cwd=copy, check=True,
+                   capture_output=True)
+    after = os.stat(so)
+    assert after.st_mtime > before.st_mtime  # it was linked again
+    assert after.st_ino != before.st_ino
+    assert not os.path.exists(os.path.join(copy, "tmp-libeuler_graph.so"))
+
+
+def test_processes_that_build_at_once_all_load_the_library(tmp_path):
+    """Six processes that import the package together over a stale tree
+    (test workers, a benchmark's phases): each brings the library up to
+    date and loads it. One make at a time; without the lock a second
+    make relinked the library while another process dlopen()ed it
+    ("file too short") or ran it."""
+    copy = _native_copy(tmp_path, "eg_capi.cc")
+    child = (
+        "import ctypes, os, sys\n"
+        "from euler_tpu.graph import native\n"
+        "native._NATIVE_DIR = sys.argv[1]\n"
+        "native._LIB_PATH = os.path.join(sys.argv[1], 'libeuler_graph.so')\n"
+        "L = ctypes.CDLL(native.build_native())\n"
+        "assert L.eg_stat_count() > 0\n"
+    )
+    env = dict(os.environ, PYTHONPATH=_REPO)
+    env.pop("EG_NATIVE_LIB", None)
+    procs = [
+        subprocess.Popen([sys.executable, "-c", child, copy], env=env,
+                         stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                         text=True)
+        for _ in range(6)
+    ]
+    errors = []
+    for p in procs:
+        _, err = p.communicate(timeout=300)
+        if p.returncode:
+            errors.append(err.strip().splitlines()[-1:])
+    assert errors == []
